@@ -25,6 +25,7 @@ from .exceptions import (  # noqa: F401
     ImagePullError,
     ResourceNotAvailableError,
     TpuSliceUnavailableError,
+    AcceleratorUnavailableError,
     ServiceHealthError,
     ServiceTimeoutError,
     PodContainerError,

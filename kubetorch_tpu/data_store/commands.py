@@ -249,7 +249,7 @@ def _kv_diff(url: str, hashes: Dict[str, str]) -> set:
             return set()
         body = r.content
         resp_coding = (r.headers.get("Content-Encoding") or "").lower()
-        if resp_coding in ("zstd", "zlib"):
+        if resp_coding in netpool.CODINGS:
             body = netpool.decompress_body(body, resp_coding)
         return set(hashes) - set(json.loads(body)["missing"])
     except (_requests.RequestException, ValueError, KeyError,

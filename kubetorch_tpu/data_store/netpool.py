@@ -42,11 +42,15 @@ DEFAULT_TIMEOUT_S = 600.0
 # /kv/diff bodies are pure hash tables ({key: blake2b} in, {missing} out):
 # thousands of hex strings compress 2-3x, and at fleet scale the diff probe
 # runs before EVERY put. Negotiated via Accept-Encoding/Content-Encoding with
-# deliberately non-transport tokens — "zstd" when the optional zstandard
-# module exists, stdlib "zlib" otherwise — so urllib3/aiohttp transport
-# layers never auto-decode behind our back and both sides stay symmetric.
+# tokens no transport layer interprets — "kt-zstd" when the optional
+# zstandard module exists, stdlib "zlib" otherwise — so urllib3/aiohttp never
+# decode behind our back and both sides stay symmetric. The registered "zstd"
+# token is NOT one of them: aiohttp and urllib3 treat it as a transport
+# coding and decode (or reject) the body before this code sees it.
 
 COMPRESS_MIN_BYTES = 1024
+ZSTD = "kt-zstd"
+CODINGS = (ZSTD, "zlib")
 
 
 def _zstd():
@@ -59,22 +63,22 @@ def _zstd():
 
 def offered_codings() -> str:
     """The ``Accept-Encoding`` value this client offers."""
-    return "zstd, zlib" if _zstd() is not None else "zlib"
+    return f"{ZSTD}, zlib" if _zstd() is not None else "zlib"
 
 
 def best_coding(accept: Optional[str]) -> Optional[str]:
     """Pick the best body coding both sides speak, or None."""
     tokens = {t.split(";")[0].strip().lower()
               for t in (accept or "").split(",")}
-    if "zstd" in tokens and _zstd() is not None:
-        return "zstd"
+    if ZSTD in tokens and _zstd() is not None:
+        return ZSTD
     if "zlib" in tokens:
         return "zlib"
     return None
 
 
 def compress_body(data: bytes, coding: str) -> bytes:
-    if coding == "zstd":
+    if coding == ZSTD:
         return _zstd().ZstdCompressor().compress(data)
     if coding == "zlib":
         import zlib
@@ -85,7 +89,7 @@ def compress_body(data: bytes, coding: str) -> bytes:
 def decompress_body(data: bytes, coding: Optional[str]) -> bytes:
     if not coding:
         return data
-    if coding == "zstd":
+    if coding == ZSTD:
         z = _zstd()
         if z is None:
             raise ValueError("zstd body but no zstandard module")

@@ -863,15 +863,15 @@ async def kv_diff(request: web.Request) -> web.Response:
 
     Delta bodies compress (ISSUE 10): both directions are pure hash
     tables that shrink 2-3x, negotiated via ``Content-Encoding`` (request)
-    and ``Accept-Encoding`` (response) with the ``zstd``/``zlib`` tokens
-    from :mod:`..data_store.netpool` — an old client that sends neither
+    and ``Accept-Encoding`` (response) with the private ``kt-zstd``/``zlib``
+    tokens from :mod:`..data_store.netpool` — an old client that sends neither
     header gets the exact pre-compression wire behavior."""
     from . import netpool
 
     st = _state(request)
     raw = await request.read()
     coding = (request.headers.get("Content-Encoding") or "").lower() or None
-    if coding in ("zstd", "zlib"):
+    if coding in netpool.CODINGS:
         try:
             raw = netpool.decompress_body(raw, coding)
         except Exception as e:  # noqa: BLE001 — any codec error is a 400

@@ -68,6 +68,21 @@ class ServiceTimeoutError(StartupError):
     """Service did not become ready within the launch timeout."""
 
 
+class AcceleratorUnavailableError(StartupError):
+    """A rank that was given the TPU could not open it.
+
+    The pod's environment names the ``tpu`` platform first in
+    ``JAX_PLATFORMS`` (the local backend sets that for every pod whose
+    ``Compute`` asks for a TPU), but jax in the rank came up on another
+    backend or failed to initialize. The rank fails its load with this
+    instead of serving from the CPU. ``backend`` is what jax reported
+    (None when initialization itself raised)."""
+
+    def __init__(self, message: str, backend: Optional[str] = None):
+        super().__init__(message)
+        self.backend = backend
+
+
 class SecretNotFound(KubetorchError):
     """Named Secret does not exist in the cluster (reference
     ``compute/utils.py`` SecretNotFound)."""
@@ -548,6 +563,7 @@ EXCEPTION_REGISTRY: Dict[str, type] = {
     for cls in (
         KubetorchError,
         StartupError,
+        AcceleratorUnavailableError,
         SecretNotFound,
         KubernetesCredentialsError,
         ImagePullError,
@@ -585,6 +601,7 @@ EXCEPTION_REGISTRY: Dict[str, type] = {
 # round-trip structured fields through :func:`package_exception`.
 _STRUCTURED_ATTRS: Dict[str, List[str]] = {
     "TpuSliceUnavailableError": ["accelerator", "topology"],
+    "AcceleratorUnavailableError": ["backend"],
     "ControllerRequestError": ["status_code"],
     "StoreFullError": ["path"],
     "RingEpochMismatch": ["expected", "actual"],

@@ -84,10 +84,10 @@ def _flash_prefill_wanted(cfg, t: int) -> bool:
         return False
     if cfg.attn_impl not in ("auto", "flash"):
         return False
-    shape_ok = t >= 128 and t % 128 == 0
+    from ..ops.attention import flash_auto, flash_supported
     if _FLASH_PREFILL_FLAG == "1":
-        return shape_ok
-    return shape_ok and jax.default_backend() == "tpu"
+        return flash_supported(t, cfg.n_heads, cfg.n_kv_heads)
+    return flash_auto(t, cfg.n_heads, cfg.n_kv_heads)
 
 
 # A from-zero prefill routes through ring attention instead of one-chip
@@ -168,9 +168,10 @@ def _layer_step(cfg, x, lw, layer_cache_k, layer_cache_v, q_pos, freqs_full,
                 q, k, v, current_mesh(), causal=True,
                 scale=cfg.head_dim ** -0.5, batch_axes=())
     elif flash_prefill:
-        from ..ops.attention import flash_attention
-        attn = flash_attention(q, k, v, causal=True,
-                               scale=cfg.head_dim ** -0.5)
+        from ..parallel.kernel_shard import flash_attention_sharded
+        from ..parallel.mesh_context import current_mesh
+        attn = flash_attention_sharded(q, k, v, current_mesh(), causal=True,
+                                       scale=cfg.head_dim ** -0.5)
     else:
         attn = _cached_attention(q, layer_cache_k, layer_cache_v, q_pos,
                                  cfg.head_dim ** -0.5)

@@ -179,9 +179,12 @@ def attention(q, k, v, cfg: LlamaConfig) -> jax.Array:
     """Dispatch to the fastest attention for the current backend/mesh.
 
     ``auto`` resolution: a live ``context`` mesh axis (installed via
-    ``parallel.mesh_context.use_mesh``) → ring attention; TPU backend → the
-    Pallas flash kernel; otherwise the XLA reference implementation.
+    ``parallel.mesh_context.use_mesh``) → ring attention; TPU backend and a
+    shape the kernel takes → the Pallas flash kernel; otherwise the XLA
+    reference implementation. Under a mesh the kernel runs per device over
+    its own batch rows and heads (``parallel.kernel_shard``).
     """
+    from ..ops.attention import flash_auto
     from ..parallel.mesh_context import axis_size, current_mesh
 
     scale = 1.0 / (cfg.head_dim ** 0.5)
@@ -190,7 +193,7 @@ def attention(q, k, v, cfg: LlamaConfig) -> jax.Array:
     if impl == "auto":
         if axis_size(mesh, "context") > 1:
             impl = "ring"
-        elif jax.default_backend() == "tpu":
+        elif flash_auto(q.shape[1], q.shape[2], k.shape[2]):
             impl = "flash"
         else:
             impl = "xla"
@@ -214,8 +217,9 @@ def attention(q, k, v, cfg: LlamaConfig) -> jax.Array:
             return ulysses_attention_sharded(q, k, v, mesh, causal=True, scale=scale)
         return ulysses_attention(q, k, v, axis_name="context", causal=True, scale=scale)
     if impl == "flash":
-        from ..ops.attention import flash_attention
-        return flash_attention(q, k, v, causal=True, scale=scale)
+        from ..parallel.kernel_shard import flash_attention_sharded
+        return flash_attention_sharded(q, k, v, mesh, causal=True,
+                                       scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown attn_impl {impl!r}; expected "
                          "auto|xla|flash|ring|ulysses")

@@ -119,15 +119,17 @@ def patchify(images: jax.Array, cfg: VitConfig) -> jax.Array:
 
 
 def _encoder_attention(q, k, v, cfg: VitConfig) -> jax.Array:
-    """Bidirectional attention; flash on TPU, XLA reference elsewhere."""
+    """Bidirectional attention; flash on TPU for a patch count the kernel
+    tiles, XLA reference elsewhere."""
+    from ..ops.attention import flash_attention, flash_auto
     from .llama import _xla_attention
 
     scale = cfg.head_dim ** -0.5
     impl = cfg.attn_impl
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+        impl = "flash" if flash_auto(q.shape[1], q.shape[2],
+                                     k.shape[2]) else "xla"
     if impl == "flash":
-        from ..ops.attention import flash_attention
         return flash_attention(q, k, v, causal=False, scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown attn_impl {impl!r}; expected "

@@ -7,6 +7,6 @@ matmuls better than a hand kernel would (verified against the fallback in
 benchmarks before adding any kernel here).
 """
 
-from .attention import flash_attention
+from .attention import flash_attention, flash_auto, flash_supported
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "flash_auto", "flash_supported"]

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import interpret_default
+
 NEG_INF = -1e30
 
 # TPU memory tiles are (8, 128) for fp32: a per-row statistic like the LSE
@@ -327,6 +329,23 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, dout):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def flash_supported(seq_len: int, n_heads: int, n_kv_heads: int) -> bool:
+    """Static shape check — what the kernel's blocks assume: a sequence the
+    128-row tile divides (the block search halves down from 512/1024 and
+    must stop on a tile Mosaic accepts) and GQA groups that divide evenly.
+    Callers decide kernel-or-reference from this, never from a caught
+    compile error."""
+    return (seq_len >= 128 and seq_len % 128 == 0
+            and n_heads % n_kv_heads == 0)
+
+
+def flash_auto(seq_len: int, n_heads: int, n_kv_heads: int) -> bool:
+    """The ``auto`` choice every dispatcher makes: the kernel on the TPU
+    backend for a shape it takes, the XLA reference otherwise."""
+    return (jax.default_backend() == "tpu"
+            and flash_supported(seq_len, n_heads, n_kv_heads))
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 1024,
@@ -334,13 +353,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Blockwise causal attention. q: (B, S, N, Hd); k, v: (B, S, NKV, Hd).
 
     Returns (B, S, N, Hd). NKV must divide N (GQA). S must be divisible by
-    the (clamped) block sizes. ``interpret=None`` auto-enables interpreter
-    mode off-TPU so the same code path is unit-testable on CPU.
+    the (clamped) block sizes; callers choose between this kernel and the
+    XLA reference beforehand with :func:`flash_supported`. ``interpret=None``
+    compiles on TPU and interprets on a CPU backend that was asked for
+    (:func:`~.backend.interpret_default`).
 
-    Default blocks come from an on-chip sweep (v5e, B=4 S=2048 N=12 Hd=128,
-    TPU_EVIDENCE.md): bk=1024 is ~14% faster fwd than 512 — fewer grid
-    steps and a longer K/V stream per tile amortize the revisit of the
-    q tile; bq beyond 512 bought nothing. Shorter sequences clamp down.
+    Default blocks: fewer grid steps and a longer K/V stream per tile
+    amortize the revisit of the q tile. Shorter sequences clamp down.
     """
     b, s, n, hd = q.shape
     nkv = k.shape[2]
@@ -348,7 +367,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = hd ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     # choose block sizes that divide S
     bq, bk = min(block_q, s), min(block_k, s)

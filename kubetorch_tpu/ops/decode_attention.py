@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import interpret_default
+
 NEG_INF = -1e30
 
 # query rows per block = GQA group size padded up to the fp32 sublane tile
@@ -125,7 +127,7 @@ def _decode_call(quant: bool, q, values, scales, pos, *,
     if scale is None:
         scale = hd ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     bk = min(block_k, s)
     while s % bk:
@@ -193,10 +195,8 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     Bit-compatible with the masked-einsum reference in
     ``serve.engine._decode_layer`` (asserted in tests/test_decode_kernel.py).
 
-    ``block_k=512`` validated by an on-chip sweep (v5e, 16 slots, S=4096):
-    1024 wins ~3% on a full cache but loses at quarter fill where the
-    finer frontier skip streams fewer rows — 512 is the serving-mix
-    compromise (slots are usually mid-generation, not full).
+    ``block_k=512``: a larger tile streams a full cache in fewer steps, a
+    smaller one skips more rows past a part-filled slot's frontier.
     """
     return _decode_call(False, q, (ck, cv), None, pos, scale=scale,
                         block_k=block_k, interpret=interpret)

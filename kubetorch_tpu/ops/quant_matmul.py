@@ -2,9 +2,8 @@
 
 XLA cannot fuse the int4 unpack (shift / sign-extend / concat) into a
 dot's operand pipeline the way it fuses the int8 ``convert``: the
-unpacked full-precision weight materializes in HBM every step, and the
-measured decode matmul lands ~4× SLOWER than int8
-(``scripts/tpu_int4_probe.py``). This kernel does the unpack in VMEM:
+unpacked full-precision weight materializes in HBM every step
+(``scripts/tpu_int4_probe.py`` times it). This kernel does the unpack in VMEM:
 each grid step DMAs one packed tile — half of int8's bytes — shifts the
 two nibble planes out on the VPU, and issues one MXU dot per plane
 against the matching halves of ``x`` (the half-split pack format of
@@ -26,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import interpret_default
 
 
 def _kernel(xlo_ref, xhi_ref, p_ref, slo_ref, shi_ref, o_ref):
@@ -55,7 +56,11 @@ def _q4_matmul(x, packed, scale, block_j: int, interpret: bool):
     block_k = half // (groups // 2)      # = the quantization group size
     kt = half // block_k
     xlo, xhi = x[:, : din // 2], x[:, din // 2:]
-    slo, shi = scale[: groups // 2], scale[groups // 2:]
+    # one scale row per K tile: Mosaic takes a (1, block_j) block only when
+    # the 1 is the array's whole second-to-last dim, so each half rides as
+    # (groups/2, 1, N) with the group dim squeezed out of the block
+    slo = scale[: groups // 2, None, :]
+    shi = scale[groups // 2:, None, :]
     grid = (dout // block_j, kt)
     out = pl.pallas_call(
         _kernel,
@@ -64,8 +69,8 @@ def _q4_matmul(x, packed, scale, block_j: int, interpret: bool):
             pl.BlockSpec((b, block_k), lambda j, k: (0, k)),        # x lo
             pl.BlockSpec((b, block_k), lambda j, k: (0, k)),        # x hi
             pl.BlockSpec((block_k, block_j), lambda j, k: (k, j)),  # packed
-            pl.BlockSpec((1, block_j), lambda j, k: (k, j)),        # s lo
-            pl.BlockSpec((1, block_j), lambda j, k: (k, j)),        # s hi
+            pl.BlockSpec((None, 1, block_j), lambda j, k: (k, 0, j)),  # s lo
+            pl.BlockSpec((None, 1, block_j), lambda j, k: (k, 0, j)),  # s hi
         ],
         out_specs=pl.BlockSpec((b, block_j), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, dout), jnp.float32),
@@ -87,7 +92,7 @@ def q4_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
     (g ∤ K/2, block_j ∤ N) must use the XLA fallback
     (``models.quant._dequant_int4``); ``supported`` checks."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     return _q4_matmul(x.astype(jnp.bfloat16), packed, scale,
                       block_j=min(block_j, packed.shape[1]),
                       interpret=bool(interpret))

@@ -167,11 +167,10 @@ def build_mesh(spec: MeshSpec | Dict[str, int] | None = None,
                devices: Optional[Sequence] = None):
     """Construct a ``jax.sharding.Mesh`` from a spec.
 
-    Devices are reshaped in canonical order so ``tensor`` varies fastest —
-    on a real slice JAX enumerates devices in torus order, putting tensor
-    neighbors one ICI hop apart. Uses ``jax.experimental.mesh_utils`` when the
-    topology is a real TPU slice for optimal physical layout, with a plain
-    reshape fallback (CPU meshes, odd shapes).
+    Devices are laid out in canonical order so ``tensor`` varies fastest. On
+    TPU ``jax.experimental.mesh_utils`` maps the axes onto the physical torus
+    (tensor neighbors one ICI hop apart) and a shape it cannot place raises;
+    other platforms have no topology and reshape in enumeration order.
     """
     import jax
     from jax.sharding import Mesh
@@ -186,13 +185,10 @@ def build_mesh(spec: MeshSpec | Dict[str, int] | None = None,
     spec = spec.resolve(len(devices))
 
     shape = spec.shape
-    try:
-        if devices[0].platform == "tpu":
-            from jax.experimental import mesh_utils
-            dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-        else:
-            raise ValueError  # fall through to reshape
-    except Exception:
+    if devices[0].platform == "tpu":
+        from jax.experimental import mesh_utils
+        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
+    else:
         dev_array = np.asarray(list(devices)).reshape(shape)
     return Mesh(dev_array, spec.names)
 
@@ -214,49 +210,16 @@ def normalize_batch_axes(live: Dict[str, int],
     return ba if len(ba) > 1 else (ba[0] if ba else None)
 
 
-def shard_map_fn():
-    """jax.shard_map across the JAX versions this image may carry (the
-    experimental path is the fallback).
-
-    Newer JAX renamed the replication-check kwarg ``check_rep`` →
-    ``check_vma``; callers here use the new name. When the installed
-    shard_map predates the rename, translate ``check_vma`` to
-    ``check_rep`` (same semantics: disable the static replication
-    checker) so one call site works on both sides of the rename."""
-    import functools
-    import inspect
-
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        return sm
-    if "check_vma" in params:
-        return sm
-
-    @functools.wraps(sm)
-    def _compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            val = kwargs.pop("check_vma")
-            if "check_rep" in params:
-                kwargs["check_rep"] = val
-        return sm(*args, **kwargs)
-
-    return _compat
-
-
-def lax_axis_size(axis):
-    """Static mesh-axis size from inside a shard_map body, across the JAX
-    API gap: ``lax.axis_size`` where it exists, else the older
-    ``core.axis_frame`` lookup (same static int on 0.4.x)."""
-    import jax
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.core.axis_frame(axis)
+def fit_batch_axes(live: Dict[str, int], dim: int,
+                    batch_axes: Sequence[str] = ("dcn", "data", "fsdp")):
+    """Batch-dim PartitionSpec entry for a dim of size ``dim``: the largest
+    prefix of the live batch axes whose total size divides it. An explicit
+    sharding must divide evenly — ``device_put`` and ``shard_map`` do not
+    pad the way GSPMD does."""
+    axes = tuple(a for a in batch_axes if a in live)
+    while axes and dim % math.prod(live[a] for a in axes):
+        axes = axes[:-1]
+    return normalize_batch_axes(live, axes)
 
 
 def best_mesh_for(n_devices: int, prefer: str = "fsdp") -> MeshSpec:

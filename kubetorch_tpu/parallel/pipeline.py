@@ -25,16 +25,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from .mesh import (AXIS_CONTEXT, AXIS_EXPERT, AXIS_FSDP, AXIS_PIPE,
-                   AXIS_TENSOR, lax_axis_size as _lax_axis_size,
-                   live_axes as _live_axes)
+                   AXIS_TENSOR, live_axes as _live_axes)
 from .sharding import (BATCH_AXES as _BATCH_AXES, LLAMA_RULES, VIT_RULES,
                        ShardingRules)
-
-
-def _shard_map():
-    # version-compat (check_vma ↔ check_rep) lives in one place: mesh.py
-    from .mesh import shard_map_fn
-    return shard_map_fn()
 
 
 def _reduce_stage_aux(aux_acc, mesh, axis):
@@ -68,14 +61,13 @@ def gpipe(stage_fn: Callable, mesh, *, axis: str = "pipe",
     """
     from jax.sharding import PartitionSpec as P
 
-    smap = _shard_map()
 
     def pipelined(stage_params, x):
         M = n_microbatches
 
         def per_device(local_params, x_local):
             p = lax.axis_index(axis)
-            n_stages = _lax_axis_size(axis)
+            n_stages = lax.axis_size(axis)
             xs = x_local.reshape(M, x_local.shape[0] // M, *x_local.shape[1:])
 
             def timestep(carry, t):
@@ -124,7 +116,7 @@ def gpipe(stage_fn: Callable, mesh, *, axis: str = "pipe",
         specs_out = out_specs if out_specs is not None else in_specs
         if stage_aux:
             specs_out = (specs_out, P())
-        return smap(per_device, mesh=mesh,
+        return jax.shard_map(per_device, mesh=mesh,
                     in_specs=(params_specs, in_specs),
                     # NOT `or`: an empty PartitionSpec (replicated) is falsy
                     out_specs=specs_out,
@@ -158,7 +150,6 @@ def gpipe_interleaved(chunk_fn: Callable, mesh, *, axis: str = "pipe",
     """
     from jax.sharding import PartitionSpec as P
 
-    smap = _shard_map()
     P_size = _live_axes(mesh).get(axis, 1)
     if n_microbatches % P_size:
         raise ValueError(f"interleaved schedule needs microbatches="
@@ -170,7 +161,7 @@ def gpipe_interleaved(chunk_fn: Callable, mesh, *, axis: str = "pipe",
 
         def per_device(local_params, x_local):
             p = lax.axis_index(axis)
-            n_stages = _lax_axis_size(axis)
+            n_stages = lax.axis_size(axis)
             xs = x_local.reshape(M, x_local.shape[0] // M, *x_local.shape[1:])
             # (V, 1, ...) local leaves → (V, ...): drop the sharded pipe dim
             chunks = jax.tree_util.tree_map(
@@ -225,7 +216,7 @@ def gpipe_interleaved(chunk_fn: Callable, mesh, *, axis: str = "pipe",
         specs_out = out_specs if out_specs is not None else in_specs
         if stage_aux:
             specs_out = (specs_out, P())
-        return smap(per_device, mesh=mesh,
+        return jax.shard_map(per_device, mesh=mesh,
                     in_specs=(params_specs, in_specs),
                     out_specs=specs_out,
                     check_vma=False)(stage_params, x)
@@ -307,7 +298,9 @@ def _resolve_stage_attn(cfg, live, tp: int, seq_len: int):
             f"attn_impl={cfg.attn_impl!r} in a pipeline needs a live "
             "context axis (mesh context size > 1); use xla/flash otherwise")
     if cfg.attn_impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+        from ..ops.attention import flash_auto
+        impl = "flash" if flash_auto(seq_len, cfg.n_heads,
+                                     cfg.n_kv_heads) else "xla"
         return _dc.replace(cfg, attn_impl=impl)
     return cfg
 

@@ -71,8 +71,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = hd ** -0.5
 
-    from .mesh import lax_axis_size
-    ring = lax_axis_size(axis_name)
+    ring = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     q_offset = my * sq
 
@@ -123,18 +122,27 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, mesh, *,
     spec = P(ba, context_axis if context_axis in live else None, ha, None)
 
     if context_axis not in live:
-        # no context sharding: plain attention, let GSPMD handle the rest
-        from ..ops.attention import flash_attention
-        try:
-            return flash_attention(q, k, v, causal=causal, scale=scale)
-        except Exception:
-            from ..models.llama import _xla_attention
-            return _xla_attention(q, k, v, scale or q.shape[-1] ** -0.5)
+        # no context sharding: plain attention on each device's rows/heads
+        return _local_attention(q, k, v, mesh, causal, scale)
 
     fn = functools.partial(ring_attention, axis_name=context_axis,
                            causal=causal, scale=scale)
-    return _shard_map()(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+def _local_attention(q, k, v, mesh, causal: bool, scale: Optional[float]):
+    """Full-sequence attention when nothing shards the sequence: the flash
+    kernel on TPU for shapes it takes (decided from the shape, beforehand),
+    the XLA reference otherwise. Shared with the ulysses wrapper."""
+    from ..ops.attention import flash_auto
+    if flash_auto(q.shape[1], q.shape[2], k.shape[2]):
+        from .kernel_shard import flash_attention_sharded
+        return flash_attention_sharded(q, k, v, mesh, causal=causal,
+                                       scale=scale)
+    from ..models.llama import _xla_attention
+    return _xla_attention(q, k, v, scale or q.shape[-1] ** -0.5,
+                          causal=causal)
 
 
 # ---------------------------------------------------------------------------
@@ -253,11 +261,6 @@ def sp_decode_supported(mesh, b: int, s: int, nkv: int, nh: int, *,
     return nkv % hsz == 0 and nh % hsz == 0
 
 
-def _shard_map():
-    from .mesh import shard_map_fn
-    return shard_map_fn()
-
-
 def sp_decode_attention_sharded(q, ck, cv, pos, mesh, *,
                                 scale: Optional[float] = None,
                                 batch_axes=("dcn", "data", "fsdp"),
@@ -272,9 +275,9 @@ def sp_decode_attention_sharded(q, ck, cv, pos, mesh, *,
         mesh, batch_axes, context_axis, head_axis)
     fn = functools.partial(sp_decode_attention, axis_name=context_axis,
                            scale=scale)
-    return _shard_map()(fn, mesh=mesh,
-                        in_specs=(q_spec, kv_spec, kv_spec, pos_spec),
-                        out_specs=q_spec, check_vma=False)(q, ck, cv, pos)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, pos_spec),
+                         out_specs=q_spec, check_vma=False)(q, ck, cv, pos)
 
 
 def sp_decode_attention_quant_sharded(q, kq, ks, vq, vs, pos, mesh, *,
@@ -289,7 +292,7 @@ def sp_decode_attention_quant_sharded(q, kq, ks, vq, vs, pos, mesh, *,
         mesh, batch_axes, context_axis, head_axis)
     fn = functools.partial(sp_decode_attention_quant,
                            axis_name=context_axis, scale=scale)
-    return _shard_map()(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(q_spec, kv_spec, sc_spec, kv_spec, sc_spec, pos_spec),
         out_specs=q_spec, check_vma=False)(q, kq, ks, vq, vs, pos)

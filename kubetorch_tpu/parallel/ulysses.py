@@ -41,8 +41,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Per-shard Ulysses attention. Local shapes: (B, S/C, N, Hd); requires
     C | N and C | NKV. Must run inside shard_map with ``axis_name`` bound."""
     n, nkv = q.shape[2], k.shape[2]
-    from .mesh import lax_axis_size
-    c = lax_axis_size(axis_name)
+    c = lax.axis_size(axis_name)
     if n % c or nkv % c:
         raise ValueError(
             f"ulysses degree {c} must divide n_heads={n} and n_kv_heads={nkv}")
@@ -51,17 +50,9 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kh = _seq_to_heads(k, axis_name)
     vh = _seq_to_heads(v, axis_name)
 
-    from ..models.llama import _xla_attention
-
-    scale = scale or q.shape[-1] ** -0.5
-    if jax.default_backend() == "tpu":
-        try:
-            from ..ops.attention import flash_attention
-            out = flash_attention(qh, kh, vh, causal=causal, scale=scale)
-        except Exception:
-            out = _xla_attention(qh, kh, vh, scale, causal=causal)
-    else:
-        out = _xla_attention(qh, kh, vh, scale, causal=causal)
+    # already inside shard_map (mesh=None): the local heads' full sequence
+    from .ring_attention import _local_attention
+    out = _local_attention(qh, kh, vh, None, causal, scale)
 
     return _heads_to_seq(out, axis_name)  # (B, S/C, N, Hd)
 
@@ -79,18 +70,9 @@ def ulysses_attention_sharded(q, k, v, mesh, *, causal: bool = True,
     from .mesh import live_axes
     live = live_axes(mesh)
     if context_axis not in live:
-        # no context sharding: same fallback ladder as the ring wrapper —
-        # flash only on TPU (off-TPU the kernel would silently run in the
-        # slow Pallas interpreter), XLA reference otherwise
-        if jax.default_backend() == "tpu":
-            try:
-                from ..ops.attention import flash_attention
-                return flash_attention(q, k, v, causal=causal, scale=scale)
-            except Exception:
-                pass
-        from ..models.llama import _xla_attention
-        return _xla_attention(q, k, v, scale or q.shape[-1] ** -0.5,
-                              causal=causal)
+        # no context sharding: same choice as the ring wrapper
+        from .ring_attention import _local_attention
+        return _local_attention(q, k, v, mesh, causal, scale)
     from .mesh import normalize_batch_axes
     ba = normalize_batch_axes(live, batch_axes)
     # preserve head sharding over tensor only when the ulysses degree still
@@ -106,6 +88,5 @@ def ulysses_attention_sharded(q, k, v, mesh, *, causal: bool = True,
 
     fn = functools.partial(ulysses_attention, axis_name=context_axis,
                            causal=causal, scale=scale)
-    from .mesh import shard_map_fn
-    return shard_map_fn()(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

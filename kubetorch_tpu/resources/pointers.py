@@ -76,7 +76,11 @@ def extract_pointers(obj: Any) -> Pointers:
     src_file = os.path.abspath(src_file)
     root = locate_working_dir(src_file)
     rel = os.path.relpath(src_file, root)
-    if rel.startswith(".."):
+    if rel.startswith("..") or not all(
+            part.isidentifier() for part in Path(rel).parent.parts):
+        # a directory between the marker and the file that is no package
+        # name (".cache/x", "my-checkout") cannot be spelled as an import
+        # path: ship the file's own directory instead
         root = str(Path(src_file).parent)
         rel = os.path.basename(src_file)
     module_name = rel[:-3].replace(os.sep, ".") if rel.endswith(".py") else Path(rel).stem

@@ -320,10 +320,17 @@ class AOTCompileCache:
                 key=key.digest(), name=name,
                 expected=expected, actual=actual)
         try:
+            import jax
             from jax.experimental import serialize_executable
-            payload, in_tree, out_tree = pickle.loads(data)
+            payload, in_tree, out_tree, device_ids = pickle.loads(data)
+            # load onto the devices it was compiled for: jax defaults
+            # execution_devices to EVERY device of the backend, so on a
+            # multi-device host a one-device executable would load fine
+            # and then fail its first call on the shard count
+            by_id = {d.id: d for d in jax.devices()}
             return serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             raise AOTCacheCorruptError(
                 f"AOT cache deserialize failed for {name!r}: {e}",
@@ -336,7 +343,9 @@ class AOTCompileCache:
         from jax.experimental import serialize_executable
 
         payload, in_tree, out_tree = serialize_executable.serialize(compiled)
-        data = pickle.dumps((payload, in_tree, out_tree))
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
+        data = pickle.dumps((payload, in_tree, out_tree, device_ids))
         self._write_entry(key, name, data)
         self._count("publish")
         self._store_publish(key, name,

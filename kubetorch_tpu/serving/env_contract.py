@@ -12,8 +12,10 @@ TPU-first deltas:
   ``jax.distributed.initialize`` coordinates and — critically on TPU — the
   per-host TPU visibility vars. One process per TPU *host* (megacore), not
   per chip.
-- TPU runtime vars (``TPU_WORKER_ID``, ``TPU_WORKER_HOSTNAMES``) are set so
-  libtpu agrees with the mesh about host ordering.
+- Multi-host worlds also get the TPU runtime vars (``TPU_WORKER_ID``,
+  ``TPU_WORKER_HOSTNAMES``) so libtpu agrees with the mesh about host ordering.
+- The persistent compile cache is not part of this contract: every rank of
+  every framework places it at start (``compile_cache.ensure_compile_cache``).
 """
 
 from __future__ import annotations
@@ -122,7 +124,6 @@ class JaxEnv(FrameworkEnv):
 
     name = "jax"
     coordinator_port = 1234
-    default_cache_dir = "/tmp/kt_jax_cache"
     # TPU chips are exclusively owned from spawn and jax.distributed
     # initializes once per process — the compiled mesh's identity cannot be
     # rebound per request. Worker-subset calls keep deployment-wide identity
@@ -135,20 +136,15 @@ class JaxEnv(FrameworkEnv):
             "JAX_COORDINATOR_ADDRESS": f"{info.master_ip}:{self.coordinator_port}",
             "JAX_NUM_PROCESSES": str(info.world_size),
             "JAX_PROCESS_ID": str(info.rank),
-            # libtpu host ordering must agree with the JAX process ids
-            "TPU_WORKER_ID": str(info.rank),
-            "TPU_WORKER_HOSTNAMES": ",".join(info.pod_ips),
         })
-        # Persistent XLA compilation cache: rank subprocesses are recreated on
-        # every hot reload / restart_procs, and without this each respawn pays
-        # the full jit compile again (tens of seconds for real models). The
-        # cache dir outlives subprocesses (same pod) and, when KT_JAX_CACHE_DIR
-        # points at a mounted volume, even pod restarts. Empty value disables;
-        # an explicit JAX_COMPILATION_CACHE_DIR in the pod env wins.
-        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-            cache_dir = os.environ.get("KT_JAX_CACHE_DIR", self.default_cache_dir)
-            if cache_dir:
-                e["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        if info.num_nodes > 1:
+            # libtpu host ordering must agree with the JAX process ids. One
+            # host needs neither: libtpu reads these, and a single-host world
+            # named by a loopback alias is not a slice it can resolve
+            e.update({
+                "TPU_WORKER_ID": str(info.rank),
+                "TPU_WORKER_HOSTNAMES": ",".join(info.pod_ips),
+            })
         return e
 
     def auto_nproc(self) -> int:
@@ -223,40 +219,3 @@ FRAMEWORKS: Dict[str, type] = {
 def framework_for(name: Optional[str]) -> FrameworkEnv:
     cls = FRAMEWORKS.get((name or "spmd").lower(), FrameworkEnv)
     return cls()
-
-
-def sync_jax_runtime_config() -> None:
-    """Re-apply env-derived jax config that jax froze at import time.
-
-    jax reads ``JAX_COMPILATION_CACHE_DIR`` (and the persistent-cache knobs)
-    once, at import. A rank subprocess applies its env contract *after*
-    interpreter startup, and jax may already be imported by then (spawn
-    re-imports the parent's modules; some images preload jax site-wide). If
-    so, push the values into ``jax.config`` explicitly — a no-op when jax
-    isn't loaded yet, since import will pick the env vars up itself.
-    """
-    import sys
-
-    if "jax" not in sys.modules:
-        return
-    import jax
-
-    mapping = {
-        "JAX_COMPILATION_CACHE_DIR": ("jax_compilation_cache_dir", str),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": (
-            "jax_persistent_cache_min_compile_time_secs", float),
-        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": (
-            "jax_persistent_cache_min_entry_size_bytes", int),
-    }
-    for env_key, (config_key, cast) in mapping.items():
-        value = os.environ.get(env_key)
-        if value:
-            try:
-                jax.config.update(config_key, cast(value))
-            except Exception as e:
-                # visible, not fatal: a failed sync means the worker falls
-                # back to cold compiles, which must not go unnoticed
-                import logging
-                logging.getLogger(__name__).warning(
-                    "failed to sync %s=%r into jax.config (%s): %s",
-                    env_key, value, config_key, e)

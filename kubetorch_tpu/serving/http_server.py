@@ -78,6 +78,8 @@ class ServerState:
         self.termination = asyncio.Event()
         self.termination_reason: Optional[str] = None
         self.supervisor = None
+        # config key of the supervisor being (or last) built — set when its
+        # build STARTS, so a same-launch reload can see it mid-warm-up
         self._supervisor_key: Optional[str] = None
         self._prewarm_task: Optional[asyncio.Task] = None
         self._prewarm_error: Optional[str] = None
@@ -148,6 +150,8 @@ class ServerState:
                 return self.supervisor
             if self.supervisor is not None:
                 await asyncio.to_thread(self.supervisor.cleanup)
+                self.supervisor = None
+            self._supervisor_key = key
             pointers = self.pointers()
             if pointers is None:
                 raise KubetorchError(
@@ -161,27 +165,39 @@ class ServerState:
             )
             await asyncio.to_thread(sup.setup)
             self.supervisor = sup
-            self._supervisor_key = key
             return sup
 
     async def reload(self, metadata: Dict[str, Any], launch_id: str) -> None:
         """Hot reload (reference _handle_reload :352): metadata → code sync →
         supervisor recreation → only then flip the launch_id."""
         apply_metadata(metadata)
-        await self._sync_code()
+        changed = await self._sync_code()
         # replay changed dockerfile instructions (reference run_image_setup)
         dockerfile = os.environ.get("KT_DOCKERFILE") or metadata.get("KT_DOCKERFILE")
         if dockerfile:
             from .image_setup import run_image_setup
-            await run_image_setup(dockerfile, state=self)
+            changed += (await run_image_setup(dockerfile,
+                                              state=self))["effects"]
         if os.environ.get("KT_APP_CMD") and not dockerfile:
             from .image_setup import start_app_process
             await start_app_process(self, os.environ["KT_APP_CMD"])
+            changed += 1
         async with self._load_lock:
+            loading = self.supervisor is not None or (
+                self._prewarm_task is not None
+                and not self._prewarm_task.done())
+            if (loading and not changed and launch_id == self.launch_id
+                    and self._config_key() == self._supervisor_key):
+                # a pod booted BY this launch (its env carried the launch id
+                # and metadata) is already loading exactly this: tearing its
+                # rank pool down now would only wait out the warm-up and then
+                # load the whole model a second time (seen on the chip: every
+                # fresh deploy initialized and compiled twice)
+                return
             if self.supervisor is not None:
                 await asyncio.to_thread(self.supervisor.cleanup)
                 self.supervisor = None
-                self._supervisor_key = None
+            self._supervisor_key = None
             # purge the user's modules under the same lock so a queued call
             # can't rebuild a supervisor from the stale module cache. Never
             # purge the runtime itself or __main__ (mp spawn needs it, and
@@ -229,8 +245,9 @@ class ServerState:
 
         self._prewarm_task = asyncio.create_task(_go())
 
-    async def _sync_code(self) -> None:
-        """Pull latest code from the data store (reference rsync pull :1140).
+    async def _sync_code(self) -> int:
+        """Pull latest code from the data store (reference rsync pull :1140);
+        returns how many files changed.
 
         No code tree in the store + a locally-present project root means the
         client shares our filesystem (local backend) and never pushed —
@@ -240,15 +257,16 @@ class ServerState:
         service = os.environ.get(KT_SERVICE_NAME)
         root = os.environ.get(KT_PROJECT_ROOT)
         if not (store_url and service and root):
-            return
+            return 0
         from ..data_store.sync import pull_tree
         from ..exceptions import SyncError
         try:
-            await asyncio.to_thread(pull_tree, store_url,
-                                    f"__code__/{service}", root)
+            stats = await asyncio.to_thread(pull_tree, store_url,
+                                            f"__code__/{service}", root)
+            return stats["fetched"] + stats["deleted"]
         except SyncError as e:
             if "No tree" in str(e) and os.path.isdir(root):
-                return
+                return 0
             raise
 
     def terminate(self, reason: str) -> None:

@@ -52,7 +52,9 @@ async def run_image_setup(dockerfile: str, state=None) -> Dict:
                                                  _PIP_INSTALL_CMD)
                       for k, v in new[start:] if k == "RUN")
     before = _installed_versions() if pip_touched else {}
+    effects = 0       # instructions that changed this pod (FROM/COPY don't)
     for kind, value in new[start:]:
+        effects += kind in ("RUN", "ENV", "CMD")
         if kind == "RUN":
             cmd = value.replace("$KT_PIP_INSTALL_CMD", _PIP_INSTALL_CMD)
             proc = await asyncio.create_subprocess_shell(
@@ -78,7 +80,8 @@ async def run_image_setup(dockerfile: str, state=None) -> Dict:
     if pip_touched:
         _evict_changed_distributions(before)
     _CACHED_DOCKERFILE = dockerfile.splitlines()
-    return {"instructions": len(new), "replayed": replayed}
+    return {"instructions": len(new), "replayed": replayed,
+            "effects": effects}
 
 
 def _installed_versions() -> dict:

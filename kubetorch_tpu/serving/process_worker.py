@@ -9,9 +9,13 @@ cleanup on teardown.
 TPU-first deltas:
 - **spawn** start method is mandatory (fork would duplicate a libtpu handle;
   TPU chips are exclusively owned per-process).
-- The framework env (JAX coordinator, TPU_WORKER_ID) is applied *before* the
-  callable module is imported, because importing user code typically imports
-  jax, which reads these at first device query.
+- The framework env (JAX coordinator, TPU_WORKER_ID) and the compile-cache
+  directory are applied *before* the callable module is imported, because
+  importing user code typically imports jax, which reads these at import or
+  at first device query.
+- A rank whose environment names the ``tpu`` platform first proves it holds
+  the chip before it loads user code; otherwise its load fails with a typed
+  ``AcceleratorUnavailableError`` — it never serves from the CPU.
 - HBM OOM from XLA is detected and repackaged as a typed ``HbmOomError``.
 """
 
@@ -99,9 +103,9 @@ def _worker_main(request_q: mp.Queue, response_q: mp.Queue,
     # The sender's SIGKILL (kubelet / term-rank chaos) stays the backstop.
     from .elastic import install_sigterm_drain
     install_sigterm_drain()
-    # after the tees: a failed sync must reach the rank-log channel
-    from .env_contract import sync_jax_runtime_config
-    sync_jax_runtime_config()
+    # before anything in this process can import jax, which reads it once
+    from ..compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     # flight recorder (ISSUE 20): armed only when KT_OBS_SPOOL is set —
     # a kill-rank SIGKILL mid-call then leaves this rank's in-flight span
     # and final metric snapshot in its own spool
@@ -155,6 +159,7 @@ async def _worker_loop(request_q, response_q, pointers_dict, init_args,
     response_q.put({"op": "state", "warmup": "started"})
     if pointers_dict:
         try:
+            require_accelerator()
             target = _load_target(pointers_dict, init_args)
         except BaseException as e:  # noqa: BLE001 — must report, not die
             load_error = e
@@ -279,6 +284,28 @@ async def _run_warmup(target: Any) -> None:
             await result
     except BaseException:  # noqa: BLE001
         print(f"[kt] __kt_warmup__ failed:\n{traceback.format_exc()}")
+
+
+def require_accelerator() -> None:
+    """One rank process per chip, and a rank that was given the chip holds
+    it. ``JAX_PLATFORMS`` naming ``tpu`` first is the statement that this
+    process owns the accelerator (the local backend sets it for pods whose
+    ``Compute`` asks for a TPU, and ``cpu`` for every other pod): initialize
+    jax now and refuse to load on any other backend."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() != "tpu":
+        return
+    from ..exceptions import AcceleratorUnavailableError
+    try:
+        import jax
+        backend = jax.default_backend()
+    except RuntimeError as e:    # jax: "Unable to initialize backend 'tpu'"
+        raise AcceleratorUnavailableError(
+            f"this rank was given the TPU but jax could not open it: {e}"
+        ) from e
+    if backend != "tpu":
+        raise AcceleratorUnavailableError(
+            f"this rank was given the TPU but jax came up on {backend!r}",
+            backend=backend)
 
 
 def _load_target(pointers_dict: Dict, init_args: Optional[Dict]) -> Any:

@@ -227,7 +227,7 @@ def template_main(spec_path: str) -> None:
     if spec.get("preimport", True):
         import jax._src.xla_bridge as _xb
         from ..serve import engine as _engine  # noqa: F401
-        assert not _xb._backends, \
+        assert not _xb.backends_are_initialized(), \
             "template imported a module that initialized the JAX backend " \
             "— forked children would inherit dead XLA thread pools"
 
@@ -450,6 +450,9 @@ class TemplateSupervisor:
 
 
 def main(argv) -> None:
+    # before either path imports jax; forked replicas inherit it
+    from ..compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     if argv and argv[0] == "--cold":
         cold_boot_main(argv[1], int(argv[2]), float(argv[3]))
     else:

@@ -30,6 +30,7 @@ the number the perf gate's ``train_step`` stage regresses against.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import jax
@@ -185,22 +186,30 @@ def make_train_step(loss_fn: Callable, optimizer=None, mesh=None,
             m["grad_norm"] = optax.global_norm(grads)
         return TrainState(new_params, new_opt, state.step + 1), m
 
-    jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
+    def in_mesh(fn):
+        """Install the ambient mesh for mesh-aware ops (ring attention, the
+        Pallas kernels' shard_map) INSIDE the traced function: it is read at
+        trace time, and every way of tracing — a call, ``.jitted.lower``,
+        ``grads_fn`` — must see it. A Mosaic kernel traced without it sits
+        bare under GSPMD, which jax refuses on the chip."""
+        if mesh is None:
+            return fn
+        from ..parallel.mesh_context import use_mesh
+
+        @functools.wraps(fn)
+        def traced(*args):
+            with use_mesh(mesh):
+                return fn(*args)
+        return traced
+
+    jitted = jax.jit(in_mesh(step), donate_argnums=(0,) if donate else ())
     step_hist = telemetry.train_metrics()["step_seconds"]
 
-    if mesh is None:
-        def wrapper(state, batch):
-            with telemetry.timed(step_hist, phase="compute"):
-                return jitted(state, batch)
-    else:
-        def wrapper(state, batch):
-            # Install the ambient mesh for mesh-aware ops (ring attention) —
-            # read at trace time, so it only matters on the first (tracing)
-            # call.
-            from ..parallel.mesh_context import use_mesh
-            with telemetry.timed(step_hist, phase="compute"), use_mesh(mesh):
-                return jitted(state, batch)
+    def wrapper(state, batch):
+        with telemetry.timed(step_hist, phase="compute"):
+            return jitted(state, batch)
 
+    if mesh is not None:
         def shard_state(state: TrainState) -> TrainState:
             """Place an (unsharded) TrainState onto the mesh per the rules."""
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -221,7 +230,7 @@ def make_train_step(loss_fn: Callable, optimizer=None, mesh=None,
     # overlap-equivalence tests and `bench.py --step-overlap` compare and
     # whose output sharding *is* the accumulator's (one fsdp shard per
     # device when overlap_grads is on)
-    wrapper.grads_fn = jax.jit(loss_and_grads)  # type: ignore[attr-defined]
+    wrapper.grads_fn = jax.jit(in_mesh(loss_and_grads))  # type: ignore[attr-defined]
     return wrapper
 
 

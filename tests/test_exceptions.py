@@ -9,6 +9,7 @@ from kubetorch_tpu import exceptions as exc
 # each constructor's expectation — the whole-registry round-trip below breaks
 # loudly when someone adds an attr without a sample here.
 _ATTR_SAMPLES = {
+    "backend": "cpu",
     "accelerator": "v5p-64",
     "topology": "4x4x4",
     "status_code": 503,

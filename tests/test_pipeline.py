@@ -8,15 +8,7 @@ import pytest
 pytestmark = pytest.mark.level("release")  # jit-heavy matrix: full tier only
 
 from kubetorch_tpu.models.llama import LlamaConfig, llama_forward, llama_init
-from kubetorch_tpu.parallel.mesh import MeshSpec, build_mesh, shard_map_fn
-
-# pre-rename shard_map (no check_vma kwarg, jax<=0.4.x): the compat shim
-# in mesh.shard_map_fn translates the kwarg, but the stage-aux scalar's
-# out_spec still trips the old transpose rule's _SpecError under grad —
-# a version bug the shim cannot reach. Tracked seed carryover (PR 6).
-import inspect
-_LEGACY_SHARD_MAP = "check_vma" not in inspect.signature(
-    shard_map_fn()).parameters
+from kubetorch_tpu.parallel.mesh import MeshSpec, build_mesh
 
 
 @pytest.fixture(scope="module")
@@ -375,10 +367,6 @@ def test_moe_pipeline_logits_match_sequential(cpu_mesh_devices):
     assert np.isfinite(float(aux)) and 0.2 < float(aux) < 5.0
 
 
-@pytest.mark.xfail(
-    _LEGACY_SHARD_MAP, strict=False,
-    reason="jax<0.5 shard_map _SpecError on the stage-aux scalar "
-           "out_spec under grad (see _LEGACY_SHARD_MAP note)")
 def test_moe_pipeline_grads_match_with_expert_axis(cpu_mesh_devices):
     """Grads through the in-stage expert slice + psum (the manual-EP
     backward: slice transpose scatters, psum transposes to identity)."""
@@ -409,10 +397,6 @@ def test_moe_pipeline_grads_match_with_expert_axis(cpu_mesh_devices):
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.xfail(
-    _LEGACY_SHARD_MAP, strict=False,
-    reason="jax<0.5 shard_map _SpecError on the stage-aux scalar "
-           "out_spec under grad (see _LEGACY_SHARD_MAP note)")
 def test_moe_pipeline_grads_match(cpu_mesh_devices):
     from kubetorch_tpu.models.moe import moe_init, moe_loss
     from kubetorch_tpu.parallel.mesh import MeshSpec, build_mesh

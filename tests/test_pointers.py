@@ -25,6 +25,27 @@ def test_locate_working_dir(tmp_project):
     assert ptr.locate_working_dir(str(f)) == str(tmp_project)
 
 
+def test_extract_under_a_directory_that_is_no_package_name(tmp_project):
+    """A checkout unpacked under ".scratch/clean-copy" inside a marked
+    project: the path to the file cannot be spelled as an import path, so
+    the file's own directory is what ships (seen with chip_smoke.py run
+    from a git-ignored copy: ``import_module(".chipcheck.clean.…")``)."""
+    import importlib.util
+
+    sub = tmp_project / ".scratch" / "clean-copy"
+    sub.mkdir(parents=True)
+    (sub / "oddmod.py").write_text("def f():\n    return 7\n")
+    spec = importlib.util.spec_from_file_location("oddmod",
+                                                  str(sub / "oddmod.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    p = ptr.extract_pointers(mod.f)
+    assert (p.project_root, p.module_name, p.file_path) == (
+        str(sub), "oddmod", "oddmod.py")
+    assert ptr.import_callable(p)() == 7
+    sys.modules.pop("oddmod", None)
+
+
 def test_import_callable_roundtrip(tmp_project):
     (tmp_project / "workmod.py").write_text(textwrap.dedent("""
         def double(x):

@@ -1252,12 +1252,6 @@ class TestChunkedPrefill:
             pass
         assert h2.result(timeout=0) == w2
 
-    def test_spec_engine_refuses_prefill_chunk(self, dense):
-        params, cfg = dense
-        from kubetorch_tpu.serve.spec_engine import SpeculativeEngine
-        with pytest.raises(ValueError, match="chunked prefill"):
-            SpeculativeEngine(params, cfg, params, cfg, prefill_chunk=4)
-
     def test_chunked_sampled_mode_matches_one_shot(self, dense):
         """Intermediate chunks use a constant dummy key, so the engine's
         key-split stream is IDENTICAL to one-shot admission — sampled

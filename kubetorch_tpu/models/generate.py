@@ -137,50 +137,58 @@ def _layer_step(cfg, x, lw, layer_cache_k, layer_cache_v, q_pos, freqs_full,
     from .quant import dequant_layer
     lw = dequant_layer(lw, cfg.dtype)
     b, t, d = x.shape
-    h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-    q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, t, cfg.n_heads,
-                                                   cfg.head_dim)
-    k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, t, cfg.n_kv_heads,
-                                                   cfg.head_dim)
-    v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, t, cfg.n_kv_heads,
-                                                   cfg.head_dim)
-    freqs = freqs_full[q_pos]                            # (T, Hd/2)
-    q, k = apply_rope(q, freqs), apply_rope(k, freqs)
+    # the named scopes are metadata on the ops, for a device trace to group
+    # time by (the engine's _decode_layer uses the same names)
+    with jax.named_scope("kt.qkv_rope"):
+        h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
+        q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, t, cfg.n_heads,
+                                                       cfg.head_dim)
+        k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, t, cfg.n_kv_heads,
+                                                       cfg.head_dim)
+        v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, t, cfg.n_kv_heads,
+                                                       cfg.head_dim)
+        freqs = freqs_full[q_pos]                            # (T, Hd/2)
+        q, k = apply_rope(q, freqs), apply_rope(k, freqs)
 
-    layer_cache_k = lax.dynamic_update_slice_in_dim(
-        layer_cache_k, k.astype(layer_cache_k.dtype), q_pos[0], axis=1)
-    layer_cache_v = lax.dynamic_update_slice_in_dim(
-        layer_cache_v, v.astype(layer_cache_v.dtype), q_pos[0], axis=1)
+    with jax.named_scope("kt.cache_update"):
+        layer_cache_k = lax.dynamic_update_slice_in_dim(
+            layer_cache_k, k.astype(layer_cache_k.dtype), q_pos[0], axis=1)
+        layer_cache_v = lax.dynamic_update_slice_in_dim(
+            layer_cache_v, v.astype(layer_cache_v.dtype), q_pos[0], axis=1)
 
     sp_impl = _sp_prefill_impl(cfg, b, t) if causal_prefill else None
-    if sp_impl is not None:
-        # long-prompt prefill on a context mesh: sequence-sharded
-        # attention — no chip holds the full (T, T) attention problem
-        from ..parallel.mesh_context import current_mesh
-        if sp_impl == "ulysses":
-            from ..parallel.ulysses import ulysses_attention_sharded
-            attn = ulysses_attention_sharded(
-                q, k, v, current_mesh(), causal=True,
-                scale=cfg.head_dim ** -0.5, batch_axes=())
+    with jax.named_scope("kt.attention"):
+        if sp_impl is not None:
+            # long-prompt prefill on a context mesh: sequence-sharded
+            # attention — no chip holds the full (T, T) attention problem
+            from ..parallel.mesh_context import current_mesh
+            if sp_impl == "ulysses":
+                from ..parallel.ulysses import ulysses_attention_sharded
+                attn = ulysses_attention_sharded(
+                    q, k, v, current_mesh(), causal=True,
+                    scale=cfg.head_dim ** -0.5, batch_axes=())
+            else:
+                from ..parallel.ring_attention import ring_attention_sharded
+                attn = ring_attention_sharded(
+                    q, k, v, current_mesh(), causal=True,
+                    scale=cfg.head_dim ** -0.5, batch_axes=())
+        elif flash_prefill:
+            from ..parallel.kernel_shard import flash_attention_sharded
+            from ..parallel.mesh_context import current_mesh
+            attn = flash_attention_sharded(q, k, v, current_mesh(),
+                                           causal=True,
+                                           scale=cfg.head_dim ** -0.5)
         else:
-            from ..parallel.ring_attention import ring_attention_sharded
-            attn = ring_attention_sharded(
-                q, k, v, current_mesh(), causal=True,
-                scale=cfg.head_dim ** -0.5, batch_axes=())
-    elif flash_prefill:
-        from ..parallel.kernel_shard import flash_attention_sharded
-        from ..parallel.mesh_context import current_mesh
-        attn = flash_attention_sharded(q, k, v, current_mesh(), causal=True,
-                                       scale=cfg.head_dim ** -0.5)
-    else:
-        attn = _cached_attention(q, layer_cache_k, layer_cache_v, q_pos,
-                                 cfg.head_dim ** -0.5)
-    x = x + lora_proj(attn.reshape(b, t, -1), lw["wo"], lora, "wo")
-    h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-    return (x + ffn_block(cfg, h, lw, token_mask=token_mask,
-                          keep_capacity=keep_capacity,
-                          moe_no_drop=moe_no_drop),
-            layer_cache_k, layer_cache_v)
+            attn = _cached_attention(q, layer_cache_k, layer_cache_v, q_pos,
+                                     cfg.head_dim ** -0.5)
+    with jax.named_scope("kt.out_proj"):
+        x = x + lora_proj(attn.reshape(b, t, -1), lw["wo"], lora, "wo")
+    with jax.named_scope("kt.ffn"):
+        h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
+        return (x + ffn_block(cfg, h, lw, token_mask=token_mask,
+                              keep_capacity=keep_capacity,
+                              moe_no_drop=moe_no_drop),
+                layer_cache_k, layer_cache_v)
 
 
 def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],

@@ -171,7 +171,10 @@ def moe_ffn(cfg: MoeConfig, x: jax.Array, lw: Dict[str, jax.Array],
     else:
         capacity = max(1, int(cfg.capacity_factor * s * K / E))
 
-    probs, gate_vals, gate_idx = _route(cfg, x, lw)
+    # named scopes are metadata on the ops (a device trace can group time by
+    # them); they change nothing the compiled program does
+    with jax.named_scope("kt.moe.route"):
+        probs, gate_vals, gate_idx = _route(cfg, x, lw)
 
     # aux load-balancing loss (Switch-style): E * Σ_e fraction_e * prob_e
     # computed on top-1 assignments
@@ -188,26 +191,27 @@ def moe_ffn(cfg: MoeConfig, x: jax.Array, lw: Dict[str, jax.Array],
                           m) / denom
         aux = E * jnp.sum(frac * (jnp.einsum("bse,bs->e", probs, m) / denom))
 
-    # position of each (token, k) inside its expert's capacity buffer
-    expert_onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # (B,S,K,E)
-    if token_mask is not None:
-        expert_onehot = expert_onehot * token_mask[:, :, None, None].astype(
-            jnp.int32)
-    flat = expert_onehot.reshape(b, s * K, E)
-    pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(b, s, K, E)
-    pos_in_expert = jnp.sum(pos_in_expert * expert_onehot, axis=-1)   # (B,S,K)
-    keep = pos_in_expert < (capacity if keep_capacity is None
-                            else jnp.minimum(keep_capacity, capacity))
+    with jax.named_scope("kt.moe.dispatch"):
+        # position of each (token, k) inside its expert's capacity buffer
+        expert_onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # (B,S,K,E)
+        if token_mask is not None:
+            expert_onehot = expert_onehot * token_mask[:, :, None, None].astype(
+                jnp.int32)
+        flat = expert_onehot.reshape(b, s * K, E)
+        pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(b, s, K, E)
+        pos_in_expert = jnp.sum(pos_in_expert * expert_onehot, axis=-1)   # (B,S,K)
+        keep = pos_in_expert < (capacity if keep_capacity is None
+                                else jnp.minimum(keep_capacity, capacity))
 
-    # dispatch (B,S,E,C) and combine (B,S,E,C) tensors
-    cap_onehot = jax.nn.one_hot(pos_in_expert, capacity, dtype=x.dtype)  # (B,S,K,C)
-    disp = jnp.einsum("bske,bskc->bsec",
-                      (expert_onehot * keep[..., None]).astype(x.dtype),
-                      cap_onehot)                                     # (B,S,E,C)
-    comb = jnp.einsum("bsk,bske,bskc->bsec",
-                      gate_vals.astype(x.dtype),
-                      (expert_onehot * keep[..., None]).astype(x.dtype),
-                      cap_onehot)
+        # dispatch (B,S,E,C) and combine (B,S,E,C) tensors
+        cap_onehot = jax.nn.one_hot(pos_in_expert, capacity, dtype=x.dtype)  # (B,S,K,C)
+        disp = jnp.einsum("bske,bskc->bsec",
+                          (expert_onehot * keep[..., None]).astype(x.dtype),
+                          cap_onehot)                                     # (B,S,E,C)
+        comb = jnp.einsum("bsk,bske,bskc->bsec",
+                          gate_vals.astype(x.dtype),
+                          (expert_onehot * keep[..., None]).astype(x.dtype),
+                          cap_onehot)
 
     if ep_axis is not None:
         # slice dispatch/combine down to this rank's local experts BEFORE
@@ -227,13 +231,17 @@ def moe_ffn(cfg: MoeConfig, x: jax.Array, lw: Dict[str, jax.Array],
     from .quant import dequant
     experts = {k: dequant(v, x.dtype) for k, v in lw["experts"].items()}
 
-    # route tokens to expert buffers: (E, B, C, D)
-    expert_in = jnp.einsum("bsec,bsd->ebcd", disp, x)
-    # batched expert SwiGLU over the E axis (sharded over "expert")
-    h = jax.nn.silu(jnp.einsum("ebcd,edf->ebcf", expert_in, experts["w_gate"])) \
-        * jnp.einsum("ebcd,edf->ebcf", expert_in, experts["w_up"])
-    expert_out = jnp.einsum("ebcf,efd->ebcd", h, experts["w_down"])
-    out = jnp.einsum("bsec,ebcd->bsd", comb, expert_out)
+    with jax.named_scope("kt.moe.dispatch"):
+        # route tokens to expert buffers: (E, B, C, D)
+        expert_in = jnp.einsum("bsec,bsd->ebcd", disp, x)
+    with jax.named_scope("kt.moe.experts"):
+        # batched expert SwiGLU over the E axis (sharded over "expert")
+        h = jax.nn.silu(
+            jnp.einsum("ebcd,edf->ebcf", expert_in, experts["w_gate"])) \
+            * jnp.einsum("ebcd,edf->ebcf", expert_in, experts["w_up"])
+        expert_out = jnp.einsum("ebcf,efd->ebcd", h, experts["w_down"])
+    with jax.named_scope("kt.moe.combine"):
+        out = jnp.einsum("bsec,ebcd->bsd", comb, expert_out)
     reduce = tuple(a for a in (ep_axis, tp_axis) if a is not None)
     if reduce:
         out = lax.psum(out, reduce)

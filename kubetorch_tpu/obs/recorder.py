@@ -238,6 +238,9 @@ class FlightRecorder:
                 "v": RECORD_VERSION,
                 "seq": self._seq,
                 "ts": now,
+                # this host's monotonic clock, the one spans' start_mono /
+                # end_mono and the engine's stamps are on
+                "mono": time.monotonic(),
                 "kind": kind,
                 "full": full,
                 "metrics": (cur if full

@@ -130,6 +130,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True):
             pltpu.VMEM((block_q, 1), jnp.float32),    # l
         ],
         interpret=interpret,
+        name="kt_flash_attention_fwd",
     )(q, k, v)
     return (res[0], res[1]) if need_lse else (res[0], None)
 

@@ -180,6 +180,7 @@ def _decode_call(quant: bool, q, values, scales, pos, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, nkv, gp, hd), q.dtype),
         interpret=interpret,
+        name="kt_decode_attention_quant" if quant else "kt_decode_attention",
     )(pos.astype(jnp.int32), *inputs)
     return out[:, :, :group].reshape(b, nh, hd)
 

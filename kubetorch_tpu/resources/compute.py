@@ -379,28 +379,36 @@ class Compute:
         import logging
         import time as _time
 
+        from .. import telemetry
+
         log = logging.getLogger("kubetorch")
         client = controller_client()
         deadline = _time.monotonic() + (timeout or self.launch_timeout)
         delay = 0.25
         seen_events: Dict[str, None] = {}     # insertion-ordered
-        while _time.monotonic() < deadline:
-            status = client.check_ready(self.namespace, name)
-            for msg in status.get("events") or []:
-                if msg not in seen_events:
-                    seen_events[msg] = None
-                    log.info("%s: %s", name, msg)
-            if status.get("ready"):
-                return
-            failure = status.get("failure")
-            if failure:
-                from .. import exceptions as _exc
-                cls = getattr(_exc, failure.get("error_type", ""),
-                              _exc.StartupError)
-                raise cls(f"launch of {name!r} failed: "
-                          f"{failure.get('message', '')}")
-            _time.sleep(delay)
-            delay = min(delay * 2, 5.0)
+        with telemetry.span("deploy.check_service_ready", polls=0,
+                            last_delay_s=0.0) as sp:
+            polls = 0
+            while _time.monotonic() < deadline:
+                polls += 1
+                sp.set_attr("polls", polls)
+                status = client.check_ready(self.namespace, name)
+                for msg in status.get("events") or []:
+                    if msg not in seen_events:
+                        seen_events[msg] = None
+                        log.info("%s: %s", name, msg)
+                if status.get("ready"):
+                    return
+                failure = status.get("failure")
+                if failure:
+                    from .. import exceptions as _exc
+                    cls = getattr(_exc, failure.get("error_type", ""),
+                                  _exc.StartupError)
+                    raise cls(f"launch of {name!r} failed: "
+                              f"{failure.get('message', '')}")
+                _time.sleep(delay)
+                sp.set_attr("last_delay_s", delay)
+                delay = min(delay * 2, 5.0)
         tail = "".join(f"\n  {m}" for m in list(seen_events)[-5:])
         raise ServiceTimeoutError(
             f"Service {name!r} not ready after "

@@ -13,6 +13,7 @@ import time
 import uuid
 from typing import Any, Dict, Optional
 
+from .. import telemetry
 from ..client import controller_client
 from ..config import config
 from ..exceptions import ServiceHealthError, ServiceTimeoutError
@@ -107,14 +108,27 @@ class Module:
         self.compute = compute
         launch_id = uuid.uuid4().hex
 
-        if sync_code:
-            self._sync_code()
-
-        result = compute._launch(self.name, self._metadata(), launch_id)
-        self.launch_id = result.get("launch_id", launch_id)
-        self.service_url = result.get("service_url")
-        compute._check_service_ready(self.name)
-        self._wait_for_http_health()
+        # one span for the deploy, a child for each of its waits; the pod's
+        # own boot phases come back in the last /ready (ISSUE 26), so the
+        # caller's ring alone says where a deploy's seconds went
+        with telemetry.span("client.deploy", service=self.name) as sp:
+            if sync_code:
+                with telemetry.span("deploy.sync_code"):
+                    self._sync_code()
+            with telemetry.span("deploy.launch"):
+                result = compute._launch(self.name, self._metadata(),
+                                         launch_id)
+            self.launch_id = result.get("launch_id", launch_id)
+            self.service_url = result.get("service_url")
+            compute._check_service_ready(self.name)
+            boot = self._wait_for_http_health()
+            for phase, seconds in (boot or {}).items():
+                if phase == "ready_for_s":
+                    # how long the service had been ready when the poll
+                    # noticed: what the back-off cost this deploy
+                    sp.set_attr("poll_slack_s", seconds)
+                else:
+                    sp.set_attr("boot." + phase, seconds)
         return self
 
     async def to_async(self, compute: Compute, **kwargs) -> "Module":
@@ -163,32 +177,42 @@ class Module:
 
     # -- health ---------------------------------------------------------------
 
-    def _wait_for_http_health(self, timeout: Optional[float] = None) -> None:
+    def _wait_for_http_health(self, timeout: Optional[float] = None
+                              ) -> Optional[Dict[str, Any]]:
         """Poll /ready?launch_id until the deployed launch answers
-        (reference ``_wait_for_http_health`` :1424)."""
+        (reference ``_wait_for_http_health`` :1424). Returns the pod's
+        ``boot`` record when it sent one."""
         if self.service_url is None:
             record = controller_client().get_workload(
                 self.compute.namespace, self.name)
             self.service_url = record.get("service_url")
         if self.service_url is None:
             if self._scaled_to_zero():
-                return
+                return None
             raise ServiceHealthError(f"No service URL for {self.name!r}")
         client = self._http_client()
         deadline = time.monotonic() + (timeout or
                                        (self.compute.launch_timeout
                                         if self.compute else 900))
         delay = 0.2
-        while time.monotonic() < deadline:
-            if client.is_ready(self.launch_id):
-                return
-            if self._scaled_to_zero():
-                # an autoscaled service with no pods is healthy-by-design:
-                # launch completed, then the idle window elapsed; the first
-                # call cold-starts it through the controller proxy
-                return
-            time.sleep(delay)
-            delay = min(delay * 2, 3.0)
+        with telemetry.span("deploy.wait_ready", polls=0,
+                            last_delay_s=0.0) as sp:
+            polls = 0
+            while time.monotonic() < deadline:
+                polls += 1
+                sp.set_attr("polls", polls)
+                body = client.ready_body(self.launch_id)
+                if body is not None:
+                    return body.get("boot")
+                if self._scaled_to_zero():
+                    # an autoscaled service with no pods is
+                    # healthy-by-design: launch completed, then the idle
+                    # window elapsed; the first call cold-starts it through
+                    # the controller proxy
+                    return None
+                time.sleep(delay)
+                sp.set_attr("last_delay_s", delay)
+                delay = min(delay * 2, 3.0)
         raise ServiceTimeoutError(
             f"Service {self.name!r} at {self.service_url} never became ready "
             f"for launch {self.launch_id}")

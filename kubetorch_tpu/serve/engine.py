@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import telemetry
 from ..models.generate import (KVCache, _layer_step, ffn_block, init_cache,
                                rope_freqs)
 from ..models.llama import rmsnorm
@@ -146,57 +147,68 @@ def _decode_layer(cfg, x, lw, ck, cv, pos, freqs, lora=None):
     each slot's new token (also its cache row); freqs: (B, Hd/2) complex.
     ``lora``: per-slot adapters already gathered to (B, D, R)/(B, R, O)
     per target (multi-LoRA serving — see ``GenerationEngine`` docs).
+
+    The named scopes are metadata on the ops, for a device trace to group
+    time by; they change nothing the compiled program does.
     """
     b = x.shape[0]
     hd = cfg.head_dim
     nh, nkv = cfg.n_heads, cfg.n_kv_heads
     lw = dequant_layer(lw, cfg.dtype)    # int8 serving weights (models.quant)
-    h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-    q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, nh, hd)
-    k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, nkv, hd)
-    v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, nkv, hd)
-    q, k = _rope_slot(q, freqs), _rope_slot(k, freqs)
+    with jax.named_scope("kt.qkv_rope"):
+        h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
+        q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, nh, hd)
+        k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, nkv, hd)
+        v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, nkv, hd)
+        q, k = _rope_slot(q, freqs), _rope_slot(k, freqs)
 
-    bi = jnp.arange(b)
-    ck = ck.at[bi, pos].set(k.astype(ck.dtype))
-    cv = cv.at[bi, pos].set(v.astype(cv.dtype))
+    with jax.named_scope("kt.cache_update"):
+        bi = jnp.arange(b)
+        ck = ck.at[bi, pos].set(k.astype(ck.dtype))
+        cv = cv.at[bi, pos].set(v.astype(cv.dtype))
 
     from ..parallel.mesh_context import current_mesh
     from ..parallel.ring_attention import (sp_decode_attention_sharded,
                                            sp_decode_supported)
     mesh = current_mesh()
-    if mesh is not None and sp_decode_supported(mesh, b, ck.shape[1],
-                                                nkv, nh):
-        # long-context serving: the cache's sequence axis is sharded over
-        # the context mesh axis; local attention + one online-softmax
-        # combine beats the all-gather GSPMD would otherwise insert (and
-        # the Pallas kernel, which needs all rows on one chip). Trace-time
-        # gate like the MoE gather (mesh fixed per engine — captured at
-        # construction and re-installed on whichever thread traces);
-        # shapes that don't divide the mesh fall back to the dense path.
-        attn = sp_decode_attention_sharded(
-            q, ck, cv, pos, mesh, scale=hd ** -0.5).reshape(b, 1, nh * hd)
-    elif _decode_kernel_wanted():
-        # fused flash-decode: streams K/V tiles, skips tiles past each
-        # slot's frontier entirely (ops/decode_attention.py); under a mesh
-        # each device runs it over its own slots and heads
-        from ..parallel.kernel_shard import decode_attention_sharded
-        attn = decode_attention_sharded(
-            q, ck, cv, pos, mesh, scale=hd ** -0.5).reshape(b, 1, nh * hd)
-    else:
-        group = nh // nkv
-        qg = q.reshape(b, nkv, group, hd)
-        logits = (jnp.einsum("bkgh,bskh->bkgs", qg, ck).astype(jnp.float32)
-                  * (hd ** -0.5))
-        s = ck.shape[1]
-        mask = jnp.arange(s)[None, :] <= pos[:, None]      # (B, S)
-        logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
-        attn = jnp.einsum("bkgs,bskh->bkgh", probs,
-                          cv).reshape(b, 1, nh * hd)
-    x = x + lora_proj(attn, lw["wo"], lora, "wo")
-    h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-    return x + ffn_block(cfg, h, lw), ck, cv
+    with jax.named_scope("kt.attention"):
+        if mesh is not None and sp_decode_supported(mesh, b, ck.shape[1],
+                                                    nkv, nh):
+            # long-context serving: the cache's sequence axis is sharded
+            # over the context mesh axis; local attention + one
+            # online-softmax combine beats the all-gather GSPMD would
+            # otherwise insert (and the Pallas kernel, which needs all rows
+            # on one chip). Trace-time gate like the MoE gather (mesh fixed
+            # per engine — captured at construction and re-installed on
+            # whichever thread traces); shapes that don't divide the mesh
+            # fall back to the dense path.
+            attn = sp_decode_attention_sharded(
+                q, ck, cv, pos, mesh,
+                scale=hd ** -0.5).reshape(b, 1, nh * hd)
+        elif _decode_kernel_wanted():
+            # fused flash-decode: streams K/V tiles, skips tiles past each
+            # slot's frontier entirely (ops/decode_attention.py); under a
+            # mesh each device runs it over its own slots and heads
+            from ..parallel.kernel_shard import decode_attention_sharded
+            attn = decode_attention_sharded(
+                q, ck, cv, pos, mesh,
+                scale=hd ** -0.5).reshape(b, 1, nh * hd)
+        else:
+            group = nh // nkv
+            qg = q.reshape(b, nkv, group, hd)
+            logits = (jnp.einsum("bkgh,bskh->bkgs", qg,
+                                 ck).astype(jnp.float32) * (hd ** -0.5))
+            s = ck.shape[1]
+            mask = jnp.arange(s)[None, :] <= pos[:, None]      # (B, S)
+            logits = jnp.where(mask[:, None, None], logits, NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
+            attn = jnp.einsum("bkgs,bskh->bkgh", probs,
+                              cv).reshape(b, 1, nh * hd)
+    with jax.named_scope("kt.out_proj"):
+        x = x + lora_proj(attn, lw["wo"], lora, "wo")
+    with jax.named_scope("kt.ffn"):
+        h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
+        return x + ffn_block(cfg, h, lw), ck, cv
 
 
 def _decode_layer_quant(cfg, x, lw, kq, ks, vq, vs, pos, freqs, lora=None):
@@ -598,7 +610,13 @@ class _Request:
     logprobs: list = field(default_factory=list)  # raw-model lp per token
     generated: int = 0
     submitted_at: float = field(default_factory=time.monotonic)
+    admitted_at: Optional[float] = None      # popped from the queue
     first_token_at: Optional[float] = None
+    # the engine's phase clock and block count when the request was seated,
+    # and, once it has retired or been cancelled, its life
+    # (GenerationEngine._close_life)
+    seated: Optional[tuple] = None
+    life: Optional[Dict[str, Any]] = None
 
 
 class RequestHandle:
@@ -613,6 +631,7 @@ class RequestHandle:
         self._engine = engine
         self._collected: List[int] = []
         self._done = False
+        self._reported = False
 
     @property
     def request_id(self) -> int:
@@ -670,12 +689,32 @@ class RequestHandle:
             self._pull(left)
         if self._req.error is not None:
             raise self._req.error
+        if not self._reported and self._req.life is not None:
+            # once, on the caller's current span (the rank's worker.execute
+            # when the engine serves as a kt.cls): the pod hands it back to
+            # the client in X-KT-Timing. No span current, or KT_TRACE=0:
+            # nothing happens.
+            self._reported = True
+            if telemetry.current_span() is not None:
+                telemetry.add_event("engine.request", **self._req.life)
         return list(self._collected)
 
     def time_to_first_token(self) -> Optional[float]:
         if self._req.first_token_at is None:
             return None
         return self._req.first_token_at - self._req.submitted_at
+
+    def timeline(self) -> Optional[Dict[str, Any]]:
+        """The request's life as the engine saw it, once it has finished or
+        been cancelled (None before): ``queue_s`` (submitted to popped from
+        the queue), ``prefill_s`` (popped to first token), ``decode_s``
+        (first token to retirement), ``blocks`` (decode blocks it sat in),
+        and the stepping thread's phase seconds while it was seated:
+        ``host_s`` / ``wait_s`` and one ``host.<phase>_s`` /
+        ``wait.<phase>_s`` each (``telemetry.engine_metrics``). All on the
+        monotonic clock."""
+        life = self._req.life
+        return None if life is None else dict(life)
 
 
 @dataclass
@@ -845,6 +884,13 @@ class GenerationEngine:
         self._tokens = self._steps = 0
         self._ttfts = deque(maxlen=256)   # rolling TTFT window
         self._t0 = time.monotonic()
+        # step anatomy: where the stepping thread's time goes, phase by
+        # phase (cumulative seconds here, kt_engine_phase_seconds per
+        # block, kt.engine.<phase> on a profiler's host timeline)
+        self._phases = telemetry.PhaseClock(
+            telemetry.engine_metrics()["phase_seconds"],
+            annotate=jax.profiler.TraceAnnotation, prefix="kt.engine.",
+            wait=telemetry.ENGINE_WAIT_PHASES)
         # persistent AOT compile cache (ISSUE 16): pre-load the
         # common-signature executables (prefill per bucket + the decode
         # step) so a warm replica skips tracing entirely. Mesh-sharded
@@ -1143,6 +1189,7 @@ class GenerationEngine:
             for i, req in enumerate(self._pending):
                 if req.rid == request_id:
                     del self._pending[i]
+                    self._close_life(req)
                     req.out.put(None)
                     return True
         # active slots are only mutated on the step path; flag the request
@@ -1195,6 +1242,7 @@ class GenerationEngine:
                 })
             except Exception:  # noqa: BLE001 — never wedge the step thread
                 pass
+        self._close_life(req)
         req.out.put(None)
         self._slot_req[slot] = None
         self._pos[slot] = 0
@@ -1207,6 +1255,26 @@ class GenerationEngine:
         self._aidx[slot] = 0
         self._finished += 1
         self._free_slot_ledgers(slot)
+
+    def _close_life(self, req: _Request) -> None:
+        """The request's life, written once when it retires or is cancelled
+        (before its stream ends, so a consumer that has seen the end can
+        read it): ``RequestHandle.timeline`` documents the fields. The
+        phase seconds are the stepping thread's between the request's
+        seating and now; one cancelled while still queued has only
+        ``queue_s``."""
+        now = time.monotonic()
+        life: Dict[str, Any] = {
+            "queue_s": (req.admitted_at or now) - req.submitted_at,
+            "tokens": int(req.generated), "cancelled": bool(req.cancelled)}
+        if req.admitted_at is not None and req.first_token_at is not None:
+            life["prefill_s"] = req.first_token_at - req.admitted_at
+            life["decode_s"] = now - req.first_token_at
+        if req.seated is not None:
+            phases, blocks = req.seated
+            life["blocks"] = self._phases.blocks - blocks
+            life.update(self._phases.since(phases))
+        req.life = life
 
     def _reap_cancelled(self) -> None:
         """Step-boundary retirement for cancelled active slots (the only
@@ -1294,6 +1362,8 @@ class GenerationEngine:
                 if not self._pending:
                     return
                 req = self._pending.popleft()
+            if req.admitted_at is None:   # a requeued long prompt keeps it
+                req.admitted_at = time.monotonic()
             slot = free.pop(0)
             if (self.prefill_chunk is not None and self._chunking is not None
                     and len(req.prompt) > self.prefill_chunk):
@@ -1315,7 +1385,8 @@ class GenerationEngine:
                 # _chunking is set, cancel() finds it there instead.
                 self._admitting = req
                 try:
-                    self._start_chunking(req, slot)
+                    with self._phases.phase("admit.setup"):
+                        self._start_chunking(req, slot)
                 except Exception as e:   # noqa: BLE001
                     req.error = e
                     req.out.put(None)
@@ -1389,6 +1460,7 @@ class GenerationEngine:
             self._chunking = None
             req.out.put(None)
             return
+        phase = self._phases.phase
         c = self.prefill_chunk
         rest = len(req.prompt) - consumed
         take = min(c, rest)
@@ -1398,23 +1470,27 @@ class GenerationEngine:
         last = take == rest
         try:
             if not last:
-                _f, k_acc, v_acc, _lp = _prefill_suffix(
-                    self.params, jnp.asarray(padded), jnp.int32(take),
-                    k_acc, v_acc, jnp.int32(frontier), self._dummy_key,
-                    jnp.zeros((1,), jnp.float32), self.cfg,
-                    top_k=self.top_k, **lkw)
-                self._chunking = (req, slot, k_acc[:, :, :self.max_len],
-                                  v_acc[:, :, :self.max_len],
-                                  consumed + take, frontier + take,
-                                  lkw, aidx, pref_toks)
+                # an intermediate chunk is dispatched and not waited for
+                with phase("admit.setup"):
+                    _f, k_acc, v_acc, _lp = _prefill_suffix(
+                        self.params, jnp.asarray(padded), jnp.int32(take),
+                        k_acc, v_acc, jnp.int32(frontier), self._dummy_key,
+                        jnp.zeros((1,), jnp.float32), self.cfg,
+                        top_k=self.top_k, **lkw)
+                    self._chunking = (req, slot, k_acc[:, :, :self.max_len],
+                                      v_acc[:, :, :self.max_len],
+                                      consumed + take, frontier + take,
+                                      lkw, aidx, pref_toks)
                 return
-            temp, temps, tp, pkw, row, bias_vec = self._sampling_setup(
-                req, pref_toks)
-            first, k_new, v_new, flp = _prefill_suffix(
-                self.params, jnp.asarray(padded), jnp.int32(take),
-                k_acc, v_acc, jnp.int32(frontier),
-                self._request_prefill_key(req, frontier + take),
-                temps, self.cfg, top_k=self.top_k, **lkw, **pkw)
+            with phase("admit.setup"):
+                temp, temps, tp, pkw, row, bias_vec = self._sampling_setup(
+                    req, pref_toks)
+                key = self._request_prefill_key(req, frontier + take)
+            with phase("admit.prefill"):
+                first, k_new, v_new, flp = _prefill_suffix(
+                    self.params, jnp.asarray(padded), jnp.int32(take),
+                    k_acc, v_acc, jnp.int32(frontier), key,
+                    temps, self.cfg, top_k=self.top_k, **lkw, **pkw)
             self._chunking = None
             self._finish_admission(req, slot, first, flp,
                                    k_new[:, :, :self.max_len],
@@ -1502,90 +1578,102 @@ class GenerationEngine:
         """Post-prefill slot bookkeeping shared by one-shot and chunked
         admission: splice the K/V rows, seat the request, seed ledgers,
         re-check the adapter mapping, emit the first sampled token."""
-        self._cache = _splice_slot(self._cache, jnp.int32(slot),
-                                   k_new, v_new)
-        first_tok = int(first[0])
-        self._slot_req[slot] = req
-        self._skeys[slot] = np.asarray(
-            jax.random.PRNGKey(req.seed) if req.seed is not None
-            else self._next_key(), np.uint32)
-        self._pos[slot] = start
-        self._tok[slot] = first_tok
-        self._temps[slot] = temp
-        self._top_ps[slot] = tp
-        self._fpen[slot] = req.frequency_penalty
-        self._ppen[slot] = req.presence_penalty
-        if row is not None:
-            row[first_tok] += 1
-            self._counts = _set_counts_row(self._counts, jnp.int32(slot),
-                                           jnp.asarray(row))
-        if bias_vec is not None:
-            if self._bias is None:
-                self._bias = jnp.zeros((self.slots, self.cfg.vocab_size),
-                                       jnp.float32)
-            self._bias = _set_counts_row(self._bias, jnp.int32(slot),
-                                         jnp.asarray(bias_vec))
-            self._bmask[slot] = 1.0
-        with self._lock:
-            # prefill ran outside the lock: if the adapter was evicted in
-            # that window (and its index possibly reused by a new tenant),
-            # pointing at the stale index would decode through the WRONG
-            # factors — re-check the mapping and fall back to base
-            if (req.adapter_id is not None
-                    and self._adapter_slots.get(req.adapter_id) != aidx):
-                aidx = 0
-            self._aidx[slot] = aidx
-        self._admitted += 1
-        self._emit(slot, first_tok, float(flp[0]))
-        # TTFT sample at the only place it's defined: the first emit
-        if req.first_token_at is not None:
-            self._ttfts.append(req.first_token_at - req.submitted_at)
+        phase = self._phases.phase
+        with phase("admit.seat"):
+            self._cache = _splice_slot(self._cache, jnp.int32(slot),
+                                       k_new, v_new)
+        with phase("admit.prefill"):
+            first_tok = int(first[0])     # the wait for the prefill
+        with phase("admit.seat"):
+            self._slot_req[slot] = req
+            req.seated = (self._phases.snapshot(), self._phases.blocks)
+            self._skeys[slot] = np.asarray(
+                jax.random.PRNGKey(req.seed) if req.seed is not None
+                else self._next_key(), np.uint32)
+            self._pos[slot] = start
+            self._tok[slot] = first_tok
+            self._temps[slot] = temp
+            self._top_ps[slot] = tp
+            self._fpen[slot] = req.frequency_penalty
+            self._ppen[slot] = req.presence_penalty
+            if row is not None:
+                row[first_tok] += 1
+                self._counts = _set_counts_row(
+                    self._counts, jnp.int32(slot), jnp.asarray(row))
+            if bias_vec is not None:
+                if self._bias is None:
+                    self._bias = jnp.zeros(
+                        (self.slots, self.cfg.vocab_size), jnp.float32)
+                self._bias = _set_counts_row(self._bias, jnp.int32(slot),
+                                             jnp.asarray(bias_vec))
+                self._bmask[slot] = 1.0
+            with self._lock:
+                # prefill ran outside the lock: if the adapter was evicted
+                # in that window (and its index possibly reused by a new
+                # tenant), pointing at the stale index would decode through
+                # the WRONG factors — re-check the mapping and fall back to
+                # base
+                if (req.adapter_id is not None
+                        and self._adapter_slots.get(req.adapter_id) != aidx):
+                    aidx = 0
+                self._aidx[slot] = aidx
+            self._admitted += 1
+            self._emit(slot, first_tok, float(flp[0]))
+            # TTFT sample at the only place it's defined: the first emit
+            if req.first_token_at is not None:
+                self._ttfts.append(req.first_token_at - req.submitted_at)
 
     def _admit_one(self, req: _Request, slot: int) -> None:
-        pref = self._resolve_prefix(req)
-        t = len(req.prompt)
-        temp, temps, tp, pkw, row, bias_vec = self._sampling_setup(
-            req, pref[3] if pref is not None else None)
-        adapter, aidx = self._resolve_adapter(req.adapter_id)
-        lkw = ({"adapter": adapter, "lora_scale": self._lora_cfg.scale}
-               if adapter is not None else {})
-        if req.prefix_id is not None:
-            pk, pv, p_real, p_toks, _pad = pref
-            p_bucket = pk.shape[2]
-            bucket = next((b for b in self._buckets if b >= t
-                           and p_bucket + b <= self.max_len), None)
-            if bucket is None:
-                # no bucket leaves room behind the prefix: pad the
-                # suffix to exactly what fits (still one compile per
-                # distinct size, bounded by max_len)
-                bucket = self.max_len - p_bucket
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :t] = req.prompt
-            start = p_real + t
-            first, k_new, v_new, flp = _prefill_suffix(
-                self.params, jnp.asarray(padded), jnp.int32(t), pk, pv,
-                jnp.int32(p_real), self._request_prefill_key(req, start),
-                temps, self.cfg, top_k=self.top_k, **lkw, **pkw)
-            self._prefix_hits += 1
-        else:
-            bucket = next(b for b in self._buckets if b >= t)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :t] = req.prompt
-            start = t
-            # common signature (no adapter/nucleus/penalty kwargs): use
-            # the pre-loaded AOT executable when the cache warmed one —
-            # statics (cfg, top_k) are baked in, so only dynamic args pass
-            exe = (self._aot_exec.get(("prefill", bucket))
-                   if not lkw and not pkw else None)
-            if exe is not None:
-                first, k_new, v_new, flp = exe(
-                    self.params, jnp.asarray(padded), jnp.int32(t),
-                    self._request_prefill_key(req, start), temps)
+        phase = self._phases.phase
+        with phase("admit.setup"):
+            pref = self._resolve_prefix(req)
+            t = len(req.prompt)
+            temp, temps, tp, pkw, row, bias_vec = self._sampling_setup(
+                req, pref[3] if pref is not None else None)
+            adapter, aidx = self._resolve_adapter(req.adapter_id)
+            lkw = ({"adapter": adapter, "lora_scale": self._lora_cfg.scale}
+                   if adapter is not None else {})
+            if req.prefix_id is not None:
+                pk, pv, p_real, p_toks, _pad = pref
+                p_bucket = pk.shape[2]
+                bucket = next((b for b in self._buckets if b >= t
+                               and p_bucket + b <= self.max_len), None)
+                if bucket is None:
+                    # no bucket leaves room behind the prefix: pad the
+                    # suffix to exactly what fits (still one compile per
+                    # distinct size, bounded by max_len)
+                    bucket = self.max_len - p_bucket
+                start = p_real + t
             else:
-                first, k_new, v_new, flp = _prefill(
-                    self.params, jnp.asarray(padded), jnp.int32(t),
-                    self._request_prefill_key(req, start), temps, self.cfg,
+                bucket = next(b for b in self._buckets if b >= t)
+                start = t
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :t] = req.prompt
+            tokens, true_len = jnp.asarray(padded), jnp.int32(t)
+            key = self._request_prefill_key(req, start)
+        # the dispatch; _finish_admission waits for the first token under
+        # the same phase, after it has queued the splice behind the prefill
+        with phase("admit.prefill"):
+            if req.prefix_id is not None:
+                first, k_new, v_new, flp = _prefill_suffix(
+                    self.params, tokens, true_len, pk, pv,
+                    jnp.int32(p_real), key, temps, self.cfg,
                     top_k=self.top_k, **lkw, **pkw)
+                self._prefix_hits += 1
+            else:
+                # common signature (no adapter/nucleus/penalty kwargs): use
+                # the pre-loaded AOT executable when the cache warmed one —
+                # statics (cfg, top_k) are baked in, so only dynamic args
+                # pass
+                exe = (self._aot_exec.get(("prefill", bucket))
+                       if not lkw and not pkw else None)
+                if exe is not None:
+                    first, k_new, v_new, flp = exe(
+                        self.params, tokens, true_len, key, temps)
+                else:
+                    first, k_new, v_new, flp = _prefill(
+                        self.params, tokens, true_len, key, temps, self.cfg,
+                        top_k=self.top_k, **lkw, **pkw)
         self._finish_admission(req, slot, first, flp, k_new, v_new, start,
                                temp, tp, row, aidx, bias_vec=bias_vec)
 
@@ -1625,32 +1713,38 @@ class GenerationEngine:
             return self._step_once()
 
     def _step_once(self) -> int:
+        phase = self._phases.phase
         # boundary hooks first: we are BETWEEN decode batches here (the
         # previous dispatch retired at the end of the last _step_once), so
         # a weight swap scheduled via at_batch_boundary never overlaps a
         # decode dispatch on the old params
-        self._run_boundary_hooks()
-        self._reap_cancelled()
+        with phase("hooks"):
+            self._run_boundary_hooks()
+            self._reap_cancelled()
         self._admit()
         active = [i for i, r in enumerate(self._slot_req) if r is not None]
         if active:
-            with self._lock:
-                banks = self._banks
-            # once a bank exists every step pays the per-slot gather, base
-            # traffic included (aidx 0 = the zero adapter) — the price of
-            # one shared compiled step
-            lkw = ({"banks": banks, "aidx": jnp.asarray(self._aidx),
-                    "lora_scale": self._lora_cfg.scale} if banks else {})
-            if self._nucleus:
-                lkw["top_ps"] = jnp.asarray(self._top_ps)
-            if self._counts is not None:
-                lkw.update(counts=self._counts,
-                           fpen=jnp.asarray(self._fpen),
-                           ppen=jnp.asarray(self._ppen))
-            if self._bias is not None:
-                lkw.update(bias=self._bias,
-                           bmask=jnp.asarray(self._bmask))
-            lkw["skeys"] = jnp.asarray(self._skeys)
+            self._phases.blocks += 1
+            with phase("upload"):
+                with self._lock:
+                    banks = self._banks
+                # once a bank exists every step pays the per-slot gather,
+                # base traffic included (aidx 0 = the zero adapter) — the
+                # price of one shared compiled step
+                lkw = ({"banks": banks, "aidx": jnp.asarray(self._aidx),
+                        "lora_scale": self._lora_cfg.scale} if banks else {})
+                if self._nucleus:
+                    lkw["top_ps"] = jnp.asarray(self._top_ps)
+                if self._counts is not None:
+                    lkw.update(counts=self._counts,
+                               fpen=jnp.asarray(self._fpen),
+                               ppen=jnp.asarray(self._ppen))
+                if self._bias is not None:
+                    lkw.update(bias=self._bias,
+                               bmask=jnp.asarray(self._bmask))
+                lkw["skeys"] = jnp.asarray(self._skeys)
+                pos, tok = jnp.asarray(self._pos), jnp.asarray(self._tok)
+                key, temps = self._next_key(), jnp.asarray(self._temps)
             # always the FULL configured block — never a tail-sized one:
             # n_steps is a static argname, so a variable tail would compile
             # a fresh variant mid-serving (a multi-second stall for every
@@ -1662,53 +1756,51 @@ class GenerationEngine:
             # the dispatch; sticky features fall back to the traced jits
             aot = (self._aot_exec.get(("decode", k))
                    if set(lkw) == {"skeys"} else None)
-            if k > 1:
-                if aot is not None:
-                    (self._cache, _fp, _ft, toks_k, lps_k,
-                     counts) = aot(
-                        self.params, self._cache, jnp.asarray(self._pos),
-                        jnp.asarray(self._tok), self._next_key(),
-                        jnp.asarray(self._temps), skeys=lkw["skeys"])
+            with phase("dispatch"):
+                if k > 1:
+                    if aot is not None:
+                        (self._cache, _fp, _ft, toks_k, lps_k,
+                         counts) = aot(
+                            self.params, self._cache, pos, tok, key, temps,
+                            skeys=lkw["skeys"])
+                    else:
+                        (self._cache, _fp, _ft, toks_k, lps_k,
+                         counts) = _decode_block(
+                            self.params, self._cache, pos, tok, key, temps,
+                            self.cfg, n_steps=k, top_k=self.top_k, **lkw)
+                    if self._counts is not None:
+                        self._counts = counts
                 else:
-                    (self._cache, _fp, _ft, toks_k, lps_k,
-                     counts) = _decode_block(
-                        self.params, self._cache, jnp.asarray(self._pos),
-                        jnp.asarray(self._tok), self._next_key(),
-                        jnp.asarray(self._temps), self.cfg, n_steps=k,
-                        top_k=self.top_k, **lkw)
-                if self._counts is not None:
-                    self._counts = counts
-            else:
-                if aot is not None:
-                    out = aot(
-                        self.params, self._cache, jnp.asarray(self._pos),
-                        jnp.asarray(self._tok), self._next_key(),
-                        jnp.asarray(self._temps), skeys=lkw["skeys"])
-                else:
-                    out = _decode_step(
-                        self.params, self._cache, jnp.asarray(self._pos),
-                        jnp.asarray(self._tok), self._next_key(),
-                        jnp.asarray(self._temps), self.cfg, top_k=self.top_k,
-                        **lkw)
-                if self._counts is not None:
-                    self._cache, nxt, lps, self._counts = out
-                else:
-                    self._cache, nxt, lps = out
-                toks_k, lps_k = nxt[None], lps[None]    # (1, B)
-            toks_k, lps_k = np.asarray(toks_k), np.asarray(lps_k)
+                    if aot is not None:
+                        out = aot(
+                            self.params, self._cache, pos, tok, key, temps,
+                            skeys=lkw["skeys"])
+                    else:
+                        out = _decode_step(
+                            self.params, self._cache, pos, tok, key, temps,
+                            self.cfg, top_k=self.top_k, **lkw)
+                    if self._counts is not None:
+                        self._cache, nxt, lps, self._counts = out
+                    else:
+                        self._cache, nxt, lps = out
+                    toks_k, lps_k = nxt[None], lps[None]    # (1, B)
+            with phase("fetch"):
+                toks_k, lps_k = np.asarray(toks_k), np.asarray(lps_k)
             self._steps += k
-            for i in range(k):
-                for slot in active:
-                    # a slot retired at emit i' < i skips the rest of its
-                    # block (garbage past the stop point). Each emitted
-                    # token consumed position _pos[slot]; the next feeds
-                    # back one position later.
-                    if self._slot_req[slot] is None:
-                        continue
-                    self._pos[slot] += 1
-                    self._tok[slot] = int(toks_k[i, slot])
-                    self._emit(slot, int(toks_k[i, slot]),
-                               float(lps_k[i, slot]))
+            with phase("emit"):
+                for i in range(k):
+                    for slot in active:
+                        # a slot retired at emit i' < i skips the rest of
+                        # its block (garbage past the stop point). Each
+                        # emitted token consumed position _pos[slot]; the
+                        # next feeds back one position later.
+                        if self._slot_req[slot] is None:
+                            continue
+                        self._pos[slot] += 1
+                        self._tok[slot] = int(toks_k[i, slot])
+                        self._emit(slot, int(toks_k[i, slot]),
+                                   float(lps_k[i, slot]))
+        self._phases.end_block()
         with self._lock:
             queued = len(self._pending)
         return (sum(r is not None for r in self._slot_req) + queued
@@ -1760,6 +1852,14 @@ class GenerationEngine:
         out = dict(self._aot_cache.counts) if self._aot_cache else {}
         out["executables"] = len(self._aot_exec)
         return out
+
+    def phase_seconds(self) -> Dict[str, Any]:
+        """Where the stepping thread's time has gone so far: cumulative
+        ``seconds`` per phase of a step (``telemetry.engine_metrics`` names
+        them; ``admit.prefill`` and ``fetch`` wait on the device, the rest
+        is the host's own work) and the decode ``blocks`` dispatched."""
+        return {"seconds": self._phases.snapshot(),
+                "blocks": self._phases.blocks}
 
     def stats(self) -> EngineStats:
         dt = max(time.monotonic() - self._t0, 1e-9)

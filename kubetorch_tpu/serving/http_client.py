@@ -254,6 +254,8 @@ class HTTPClient:
                     deadline=dl,
                     record=self.last_retry_delays)
                 sp.set_attr("status", resp.status_code)
+                telemetry.apply_timing(
+                    sp, resp.headers.get(telemetry.TIMING_HEADER))
         finally:
             if stop_streaming:
                 stop_streaming()
@@ -357,19 +359,35 @@ class HTTPClient:
                 deadline=dl,
                 record=self.last_retry_delays)
             sp.set_attr("status", cr.status)
+            telemetry.apply_timing(
+                sp, cr.headers.get(telemetry.TIMING_HEADER))
         return cr.result()
 
     # -- health ---------------------------------------------------------------
 
-    def is_ready(self, launch_id: Optional[str] = None,
-                 timeout: float = 2.0) -> bool:
+    def ready_body(self, launch_id: Optional[str] = None,
+                   timeout: float = 2.0) -> Optional[Dict[str, Any]]:
+        """The pod's ``/ready`` answer once it is ready, else None. Its
+        ``boot`` holds the launch's boot phases in seconds and
+        ``ready_for_s``, how long the service had been ready when this
+        poll noticed."""
         try:
             params = {"launch_id": launch_id} if launch_id else {}
             r = self._session.get(f"{self.base_url}/ready", params=params,
                                   timeout=timeout)
-            return r.status_code == 200
+            if r.status_code != 200:
+                return None
+            try:
+                body = r.json()
+            except ValueError:
+                body = None
+            return body if isinstance(body, dict) else {"ready": True}
         except _requests.RequestException:
-            return False
+            return None
+
+    def is_ready(self, launch_id: Optional[str] = None,
+                 timeout: float = 2.0) -> bool:
+        return self.ready_body(launch_id, timeout) is not None
 
     # -- metric streaming -----------------------------------------------------
 

@@ -60,6 +60,10 @@ from ..constants import server_port
 request_id_var: contextvars.ContextVar[str] = contextvars.ContextVar(
     "kt_request_id", default="")
 
+# phases of a launch's boot, as /ready reports them (ServerState.boot_body)
+BOOT_PHASES = ("pod_boot_s", "pool_spawn_s", "rank_spawn_s", "rank_accel_s",
+               "rank_import_s", "rank_init_s", "rank_warmup_s")
+
 RESERVED_ROUTES = {"health", "ready", "metrics", "app", "_kt", "debug"}
 
 # probes and the observability surface itself are never spanned: a 3s
@@ -85,6 +89,14 @@ class ServerState:
         self._prewarm_error: Optional[str] = None
         self._load_lock = asyncio.Lock()
         self.started_at = time.time()
+        # boot record of the current launch (ISSUE 26), durations only:
+        # pod_boot_s (this process's start to its server listening) and
+        # pool_spawn_s (the launch received, or the server listening if
+        # that came later, to the rank pool spawned); the ranks' own
+        # phases live on the pool. /ready hands them to the deploying
+        # client.
+        self.boot: Dict[str, float] = {}
+        self._launch_mono = time.monotonic()
         self.request_count = 0
         self.inflight = 0          # concurrency signal for the autoscaler
         self.last_activity = time.time()
@@ -164,8 +176,35 @@ class ServerState:
                 fn_name=pointers.cls_or_fn_name,
             )
             await asyncio.to_thread(sup.setup)
+            self.boot["pool_spawn_s"] = time.monotonic() - self._launch_mono
             self.supervisor = sup
             return sup
+
+    def note_listening(self) -> None:
+        """The server accepts connections from now on: closes ``pod_boot_s``
+        (from this process's start, by the kernel's record of it) and
+        starts the clock of the launch this pod was booted for."""
+        import psutil
+        self.boot["pod_boot_s"] = max(
+            0.0, time.time() - psutil.Process().create_time())
+        self._launch_mono = time.monotonic()
+
+    def boot_body(self) -> Dict[str, float]:
+        """``boot`` of a ready ``/ready``: every phase of the launch in
+        seconds (0.0 where it did not happen in this launch), the slowest
+        rank's ``rank_*_s``, and ``ready_for_s``: how long the service has
+        been ready, so the polling client can tell how late it noticed."""
+        rec = {}
+        pool = getattr(self.supervisor, "pool", None)
+        if pool is not None and hasattr(pool, "boot_record"):
+            rec = pool.boot_record()
+        ready_mono = rec.pop("ready_mono", None)
+        out = {k: 0.0 for k in BOOT_PHASES}
+        out.update(self.boot)
+        out.update(rec)
+        out["ready_for_s"] = (0.0 if ready_mono is None
+                              else max(0.0, time.monotonic() - ready_mono))
+        return {k: round(v, 6) for k, v in out.items()}
 
     async def reload(self, metadata: Dict[str, Any], launch_id: str) -> None:
         """Hot reload (reference _handle_reload :352): metadata → code sync →
@@ -212,6 +251,9 @@ class ServerState:
                         sys.modules.pop(name, None)
             self.launch_id = launch_id
             os.environ[KT_LAUNCH_ID] = launch_id
+            # a launch onto a pod that was already up: its boot starts here
+            self.boot = {}
+            self._launch_mono = time.monotonic()
         # open the load+warmup window NOW (readiness gates on it) instead of
         # on the first request — otherwise the warmup hook defers to exactly
         # the request it was supposed to pre-pay
@@ -502,7 +544,9 @@ async def ready(request: web.Request) -> web.Response:
              "warming": bool(getattr(sup, "warming", False)),
              "recovering": bool(getattr(sup, "recovering", False)),
              "healthy": bool(getattr(sup, "healthy", True))}, status=503)
-    return web.json_response({"ready": True, "launch_id": state.launch_id})
+    return web.json_response({"ready": True, "launch_id": state.launch_id,
+                              "boot": state.boot_body()})
+
 
 async def metrics(request: web.Request) -> web.Response:
     state: ServerState = request.app["state"]
@@ -685,8 +729,19 @@ async def run_callable(request: web.Request) -> web.Response:
     state.request_count += 1
     state.inflight += 1
     state.last_activity = time.time()
+    # the call's server-side timeline goes back on the response (ISSUE 26):
+    # every stage below adds its seconds to this request's collector, the
+    # pool adds the rank's. None, and no header, when tracing is disabled.
+    timing = telemetry.begin_call_timing()
+    sp = telemetry.current_span()
     try:
-        return await _run_callable_inner(request, state)
+        resp = await _run_callable_inner(request, state)
+        if timing is not None:
+            if sp is not None:
+                timing["pod.total"] = sp.seconds()
+            resp.headers[telemetry.TIMING_HEADER] = telemetry.format_timing(
+                telemetry.finish_call_timing(timing))
+        return resp
     finally:
         state.inflight -= 1
         state.last_activity = time.time()
@@ -925,6 +980,7 @@ async def _serve(app: web.Application, host: str, port: int) -> None:
     await runner.setup()          # fires on_startup (installs handlers)
     await web.TCPSite(runner, host, port).start()
     state: ServerState = app["state"]
+    state.note_listening()
     await state.termination.wait()
     deadline = time.monotonic() + float(
         os.environ.get("KT_TERMINATION_DRAIN_S", "25"))

@@ -240,8 +240,13 @@ class ProcessPool:
                     ).observe(dur, op=span["name"].split(".", 1)[1])
             return
         if resp.get("op") == "state":
-            # load+warmup bracket: gates /ready and shutdown escalation
+            # load+warmup bracket: gates /ready and shutdown escalation.
+            # The two ops also bound the rank's boot (ISSUE 26): "started"
+            # arrives when its worker loop runs, "done" carries its own
+            # phases, and the moment "done" is seen is when this rank
+            # became ready
             worker.in_warmup = resp.get("warmup") == "started"
+            worker.note_boot(resp)
             return
         req_id = resp.get("req_id")
         decode_error: Optional[BaseException] = None
@@ -250,10 +255,15 @@ class ProcessPool:
             # queue order even when the waiter already timed out/cancelled
             from .. import telemetry
             try:
-                with telemetry.stage("shm_copy", dir="resp"):
+                copy = telemetry.stage("shm_copy", dir="resp")
+                with copy:
                     shm_ring.decode_item_fields(
                         resp, getattr(worker, "shm_resp", None),
                         ("result",), "resp")
+                # this thread is outside the request's context: the
+                # copy's seconds ride the reply's own timing
+                telemetry.add_timing(resp.get("timing"), "shm_copy",
+                                     copy.seconds)
             except BaseException as e:  # noqa: BLE001
                 decode_error = e
         with self._futures_lock:
@@ -282,6 +292,10 @@ class ProcessPool:
     def _resolve(fut: asyncio.Future, resp: Dict) -> None:
         if fut.done():
             return
+        # the rank's side of the call's timeline (X-KT-Timing) travels on
+        # the future to the awaiting _submit, which is in the request's
+        # context and can hand it to the request's collector
+        fut.kt_timing = resp.get("timing")
         if resp.get("ok"):
             fut.set_result(resp.get("result"))
         else:
@@ -385,6 +399,9 @@ class ProcessPool:
             with self._futures_lock:
                 self._futures.pop(req_id, None)
             raise
+        finally:
+            telemetry.merge_timing(telemetry.current_call_timing(),
+                                   getattr(fut, "kt_timing", None))
 
     async def call(self, idx: int, method: Optional[str], args: list,
                    kwargs: dict, timeout: Optional[float] = None,
@@ -526,3 +543,15 @@ class ProcessPool:
     def warming(self) -> bool:
         """True while any live rank is still in its load+warmup window."""
         return any(w.alive and w.in_warmup for w in self.workers)
+
+    def boot_record(self) -> Dict[str, float]:
+        """The slowest rank's boot phases (``rank_*_s``, durations) and
+        ``ready_mono``: the monotonic time the last rank became ready
+        (absent while any is still warming)."""
+        boots = [getattr(w, "boot", {}) for w in self.workers]
+        out = dict(max(boots, key=lambda b: sum(
+            v for k, v in b.items() if k.endswith("_s")), default={}))
+        out.pop("ready_mono", None)
+        if boots and all("ready_mono" in b for b in boots):
+            out["ready_mono"] = max(b["ready_mono"] for b in boots)
+        return out

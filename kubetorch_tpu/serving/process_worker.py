@@ -27,6 +27,7 @@ import multiprocessing as mp
 import os
 import queue as queue_mod
 import threading
+import time
 import traceback
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
@@ -156,16 +157,23 @@ async def _worker_loop(request_q, response_q, pointers_dict, init_args,
     # marks the worker in_warmup and (a) /ready reports not-ready until done,
     # (b) shutdown withholds its SIGKILL escalation — a jit compile in
     # flight must never be force-killed (it can wedge the TPU runtime).
+    # The boot's phases ride the same state ops (ISSUE 26): the pool learns
+    # how long this rank took to open the chip, import the target, build it
+    # and warm it up, and /ready hands that to the deploying client.
+    from .. import telemetry
     response_q.put({"op": "state", "warmup": "started"})
+    laps = telemetry.Laps()
     if pointers_dict:
         try:
             require_accelerator()
-            target = _load_target(pointers_dict, init_args)
+            laps.lap("rank_accel_s")
+            target = _load_target(pointers_dict, init_args, laps)
         except BaseException as e:  # noqa: BLE001 — must report, not die
             load_error = e
         else:
             await _run_warmup(target)
-    response_q.put({"op": "state", "warmup": "done"})
+            laps.lap("rank_warmup_s")
+    response_q.put({"op": "state", "warmup": "done", "boot": laps.seconds})
 
     pending = set()
 
@@ -217,13 +225,14 @@ async def _worker_loop(request_q, response_q, pointers_dict, init_args,
                 # ring order, so slots free in allocation order); a hash
                 # mismatch answers this req_id with the typed corruption
                 # error — the parent pool retries once over the queue path
-                from .. import telemetry
                 from . import shm_ring
                 try:
-                    with telemetry.stage("shm_copy", dir="req"):
+                    copy = telemetry.stage("shm_copy", dir="req")
+                    with copy:
                         shm_ring.decode_item_fields(
                             item, rings.get("req"), ("args", "kwargs"),
                             "req")
+                    item["shm_req_s"] = copy.seconds
                 except BaseException as e:  # noqa: BLE001
                     from ..exceptions import package_exception
                     response_q.put({"req_id": item.get("req_id"),
@@ -308,12 +317,17 @@ def require_accelerator() -> None:
             backend=backend)
 
 
-def _load_target(pointers_dict: Dict, init_args: Optional[Dict]) -> Any:
+def _load_target(pointers_dict: Dict, init_args: Optional[Dict],
+                 laps=None) -> Any:
     obj = import_callable(Pointers.from_dict(pointers_dict))
+    if laps is not None:
+        laps.lap("rank_import_s")
     if isinstance(obj, type):
         args = (init_args or {}).get("args", [])
         kwargs = (init_args or {}).get("kwargs", {})
-        return obj(*args, **kwargs)
+        obj = obj(*args, **kwargs)
+        if laps is not None:
+            laps.lap("rank_init_s")
     return obj
 
 
@@ -416,6 +430,13 @@ async def _handle(item: Dict, target: Any, load_error, response_q, executor,
         rank=os.environ.get("RANK", "0"), method=item.get("method") or "",
         request_id=item.get("request_id", ""),
         queue_wait_s=round(queue_wait, 6))
+    # this rank's side of the call's timeline, sent back ON the reply (the
+    # spans below follow it and would come too late for the response)
+    timing = telemetry.begin_call_timing()
+    if timing is not None:
+        telemetry.note_timing("queue_wait", queue_wait)
+        if item.get("shm_req_s"):
+            telemetry.note_timing("shm_copy", item["shm_req_s"])
     try:
         with sp:
             await _handle_inner(item, target, load_error, response_q,
@@ -476,13 +497,35 @@ async def _handle_inner(item: Dict, target: Any, load_error, response_q,
                         resp, resp_ring, ("result",), threshold, "resp")
                 if n:
                     resp["_kt_shm"] = n
+        _attach_timing(resp, sp)
         response_q.put(resp)
     except BaseException as e:  # noqa: BLE001
         oom = detect_hbm_oom(e)
         payload = package_exception(oom if oom is not None else e)
         sp.set_status("error")
         sp.set_attr("error", payload.get("error_type", type(e).__name__))
-        response_q.put({"req_id": req_id, "ok": False, "error": payload})
+        resp = {"req_id": req_id, "ok": False, "error": payload}
+        _attach_timing(resp, sp)
+        response_q.put(resp)
+
+
+def _attach_timing(resp: Dict, sp) -> None:
+    """Put what this rank observed of the call on its reply: the stages
+    collected in the call's context, the seconds since ``worker.execute``
+    opened, and the numbers of the last ``engine.request`` event the call
+    left on that span (``RequestHandle.result``). Nothing when tracing is
+    disabled."""
+    from .. import telemetry
+
+    timing = telemetry.current_call_timing()
+    if timing is None or not sp:
+        return
+    timing[telemetry.RANK_EXECUTE] = sp.seconds()
+    for _ts, _mono, name, attrs in reversed(sp.events):
+        if name == "engine.request":
+            timing.update(telemetry.engine_timing(attrs))
+            break
+    resp["timing"] = timing
 
 
 class ProcessWorker:
@@ -508,6 +551,9 @@ class ProcessWorker:
         identity_env = fw_env if fw.per_call_identity else None
         # flipped by ProcessPool._route_responses from the worker's state ops
         self.in_warmup = True
+        # boot phases in seconds, and ready_mono once warm (note_boot)
+        self.boot: Dict[str, float] = {}
+        self._started_mono = 0.0
         # zero-copy envelope rings (ISSUE 10): one segment per direction,
         # created by THIS side (which owns their lifecycle — see
         # cleanup_shm) and attached by name in the child. Only built when
@@ -539,7 +585,20 @@ class ProcessWorker:
         )
 
     def start(self) -> None:
+        self._started_mono = time.monotonic()
         self.process.start()
+
+    def note_boot(self, state_op: Dict) -> None:
+        """From the rank's two state ops: ``started`` closes ``rank_spawn_s``
+        (process start to its worker loop running, seen from this side),
+        ``done`` brings the rank's own phases and stamps the moment it
+        became ready."""
+        now = time.monotonic()
+        if state_op.get("warmup") == "started":
+            self.boot = {"rank_spawn_s": now - self._started_mono}
+        else:
+            self.boot.update(state_op.get("boot") or {})
+            self.boot["ready_mono"] = now
 
     def submit(self, req: Dict) -> None:
         self.request_q.put(req)

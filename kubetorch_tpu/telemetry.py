@@ -52,6 +52,7 @@ from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 TRACE_HEADER = "X-KT-Trace"
+TIMING_HEADER = "X-KT-Timing"
 TRACE_ENV = "KT_TRACE"
 RING_ENV = "KT_TRACE_RING"
 
@@ -126,7 +127,8 @@ def active_spans() -> List[Dict]:
     for s in spans:
         d = s.to_dict()
         if s.end is None:
-            d["end"] = None          # still open — to_dict stamps "now"
+            d["end"] = d["end_mono"] = None   # still open — to_dict
+            #                                   stamps "now"
         out.append(d)
     return sorted(out, key=lambda d: d.get("start", 0.0))
 
@@ -136,7 +138,8 @@ class Span:
     current span, exiting records the end time and ships it to the ring."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start", "end",
-                 "status", "attrs", "events", "_token")
+                 "start_mono", "end_mono", "status", "attrs", "events",
+                 "_token")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str], attrs: Dict[str, Any]):
@@ -144,11 +147,18 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
+        # two stamps: wall time for display and cross-host ordering, and
+        # the monotonic clock the engine, the rank pool and every caller on
+        # this host measure with — on one host spans line up with
+        # ``_Request.submitted_at``, ``at_batch_boundary`` readings and a
+        # benchmark window without a clock-offset guess
         self.start = time.time()
+        self.start_mono = time.monotonic()
         self.end: Optional[float] = None
+        self.end_mono: Optional[float] = None
         self.status = "ok"
         self.attrs = attrs
-        self.events: List[Tuple[float, str, Dict[str, Any]]] = []
+        self.events: List[Tuple[float, float, str, Dict[str, Any]]] = []
         self._token = None
 
     def __bool__(self) -> bool:
@@ -163,7 +173,13 @@ class Span:
         self.status = status
 
     def add_event(self, name: str, **attrs: Any) -> None:
-        self.events.append((time.time(), name, attrs))
+        self.events.append((time.time(), time.monotonic(), name, attrs))
+
+    def seconds(self) -> float:
+        """Monotonic duration so far (to the end once closed)."""
+        end = self.end_mono if self.end_mono is not None \
+            else time.monotonic()
+        return end - self.start_mono
 
     def to_dict(self) -> Dict[str, Any]:
         return {
@@ -173,10 +189,13 @@ class Span:
             "parent_id": self.parent_id,
             "start": self.start,
             "end": self.end if self.end is not None else time.time(),
+            "start_mono": self.start_mono,
+            "end_mono": (self.end_mono if self.end_mono is not None
+                         else time.monotonic()),
             "status": self.status,
             "attrs": dict(self.attrs),
-            "events": [{"ts": ts, "name": n, "attrs": a}
-                       for ts, n, a in self.events],
+            "events": [{"ts": ts, "mono": mono, "name": n, "attrs": a}
+                       for ts, mono, n, a in self.events],
         }
 
     def __enter__(self) -> "Span":
@@ -187,6 +206,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.end = time.time()
+        self.end_mono = time.monotonic()
         if exc is not None:
             self.status = "error"
             self.attrs.setdefault("error", type(exc).__name__)
@@ -215,6 +235,9 @@ class _NoopSpan:
 
     def add_event(self, name: str, **attrs: Any) -> None:
         pass
+
+    def seconds(self) -> float:
+        return 0.0
 
     def to_dict(self) -> None:
         return None
@@ -373,6 +396,11 @@ def ingest_span(span_dict: Optional[Dict]) -> bool:
 # ---------------------------------------------------------------------------
 
 
+# attribute families a caller's span carries back from the other side: the
+# waterfall prints each as a line of its own under the span
+_TIMELINE_GROUPS = ("server.", "rank.", "pod.", "engine.", "boot.")
+
+
 def format_waterfall(spans: Iterable[Dict], width: int = 40) -> str:
     """ASCII waterfall for one trace's spans: tree-indented by parentage,
     each line showing offset+duration bars relative to the earliest start,
@@ -396,8 +424,29 @@ def format_waterfall(spans: Iterable[Dict], width: int = 40) -> str:
              f"({len(spans)} spans, {total * 1000:.1f}ms)"]
 
     def _attrs(s: Dict) -> str:
-        keep = {k: v for k, v in s.get("attrs", {}).items()}
+        keep = {k: v for k, v in s.get("attrs", {}).items()
+                if not k.startswith(_TIMELINE_GROUPS)}
         return " ".join(f"{k}={v}" for k, v in sorted(keep.items()))
+
+    def _timeline(s: Dict, depth: int) -> None:
+        # what came back from the other side of the call or the deploy
+        # (X-KT-Timing, the /ready boot body): durations, one line a group
+        attrs = s.get("attrs", {})
+        for group in _TIMELINE_GROUPS:
+            parts = []
+            for k, v in attrs.items():
+                if not k.startswith(group):
+                    continue
+                name = k[len(group):]
+                if name.endswith("_ms") and isinstance(v, (int, float)):
+                    parts.append(f"{name[:-3]}={v:.2f}ms")
+                elif name.endswith("_s") and isinstance(v, (int, float)):
+                    parts.append(f"{name[:-2]}={v:.3f}s")
+                else:
+                    parts.append(f"{name}={v}")
+            if parts:
+                lines.append(f"   {' ' * width} {'  ' * depth}  ◦ "
+                             f"{group[:-1]}: {' '.join(parts)}")
 
     def _bar(s: Dict) -> str:
         off = (s["start"] - t0) / total
@@ -412,6 +461,7 @@ def format_waterfall(spans: Iterable[Dict], width: int = 40) -> str:
         mark = " !" if s.get("status") == "error" else ""
         lines.append(f"  [{_bar(s)}] {'  ' * depth}{s['name']}{mark}  "
                      f"+{start_ms:.1f}ms {dur_ms:.1f}ms  {_attrs(s)}".rstrip())
+        _timeline(s, depth)
         for ev in s.get("events", []):
             ev_ms = (ev["ts"] - t0) * 1000
             ev_attrs = " ".join(f"{k}={v}" for k, v in
@@ -736,11 +786,12 @@ class _StageTimer:
     ``kt_stage_seconds`` observation (always; one dict op, no allocation
     churn on the disabled path)."""
 
-    __slots__ = ("stage", "attrs", "_span", "_t0")
+    __slots__ = ("stage", "attrs", "seconds", "_span", "_t0")
 
     def __init__(self, stage_name: str, attrs: Dict[str, Any]):
         self.stage = stage_name
         self.attrs = attrs
+        self.seconds = 0.0           # readable once the block has exited
 
     def __enter__(self):
         self._span = span(f"stage.{self.stage}", **self.attrs)
@@ -749,7 +800,9 @@ class _StageTimer:
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
-        observe_stage(self.stage, time.perf_counter() - self._t0)
+        self.seconds = time.perf_counter() - self._t0
+        observe_stage(self.stage, self.seconds)
+        note_timing(self.stage, self.seconds)
         self._span.__exit__(exc_type, exc, tb)
 
 
@@ -782,6 +835,294 @@ def timed(hist: Histogram, **labels: Any) -> _HistTimer:
     outside this module to measure latency when a ``kt_stage_seconds``
     stage is the wrong shape (e.g. phase-labelled step anatomy)."""
     return _HistTimer(hist, labels)
+
+
+# ---------------------------------------------------------------------------
+# One call's server-side timeline, handed back to the caller (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+# The pod answers a call with ``X-KT-Timing``, a ``Server-Timing``-style
+# list of ``name;dur=<ms>`` pairs (``;n=<count>`` for the one count) built
+# from what it observed for THAT request, and the client writes them onto
+# its ``client.call`` span. Durations only, so the header holds across
+# hosts whose clocks disagree. The caller's ring alone then shows where the
+# call's time went, with the pod long gone.
+#
+# Collected through a ContextVar holding one mutable dict per request: every
+# ``stage(...)`` that exits inside the request's context adds its seconds
+# (tasks and ``copy_context`` threads share the dict, not a copy). Nothing
+# is collected, formatted or sent when tracing is disabled.
+
+_call_timing: contextvars.ContextVar[Optional[Dict[str, Any]]] = \
+    contextvars.ContextVar("kt_call_timing", default=None)
+
+# what the rank reports of its own side of a call (``rank.execute``) and of
+# an engine request the call produced (``engine.*``)
+RANK_EXECUTE = "rank.execute"
+
+
+def begin_call_timing() -> Optional[Dict[str, Any]]:
+    """Open this request's timing collector and bind it to the context;
+    None (and nothing bound) when tracing is disabled."""
+    if not enabled():
+        return None
+    timing: Dict[str, Any] = {}
+    _call_timing.set(timing)
+    return timing
+
+
+def current_call_timing() -> Optional[Dict[str, Any]]:
+    return _call_timing.get()
+
+
+def add_timing(timing: Optional[Dict[str, Any]], name: str,
+               seconds: float) -> None:
+    """Add a server stage's seconds to a collector (a stage that runs
+    twice, as ``shm_copy`` does, sums)."""
+    if timing is not None:
+        key = "server." + name
+        timing[key] = timing.get(key, 0.0) + seconds
+
+
+def note_timing(name: str, seconds: float) -> None:
+    """:func:`add_timing` to the current request's collector, if any."""
+    add_timing(_call_timing.get(), name, seconds)
+
+
+def merge_timing(timing: Optional[Dict[str, Any]],
+                 rank: Optional[Dict[str, Any]]) -> None:
+    """Hand a rank's reply-side timing to the request's collector. A call
+    fanned out over several ranks keeps the slowest rank's."""
+    if timing is None or not rank:
+        return
+    have = timing.get("_rank")
+    if have is None or \
+            rank.get(RANK_EXECUTE, 0.0) > have.get(RANK_EXECUTE, 0.0):
+        timing["_rank"] = rank
+
+
+def finish_call_timing(timing: Dict[str, Any]) -> Dict[str, Any]:
+    """The request's collector as one flat mapping for the header: the
+    pod's own stages plus the (slowest) rank's, a stage seen on both sides
+    summed. ``server.respond`` is what is left of ``server.execute`` after
+    the wait for the rank and the rank's own time: the reply's way back
+    through the pool."""
+    out = {k: v for k, v in timing.items() if k != "_rank"}
+    for key, value in (timing.get("_rank") or {}).items():
+        if key.startswith("server."):
+            out[key] = out.get(key, 0.0) + value
+        else:
+            out[key] = value
+    if "server.execute" in out and RANK_EXECUTE in out:
+        respond = (out["server.execute"] - out[RANK_EXECUTE]
+                   - out.get("server.queue_wait", 0.0))
+        if respond >= 0.0:
+            out["server.respond"] = respond
+    return out
+
+
+def engine_timing(life: Dict[str, Any]) -> Dict[str, Any]:
+    """An ``engine.request`` event's numbers under the header's names."""
+    out: Dict[str, Any] = {}
+    for key in ("queue", "prefill", "decode", "host", "wait"):
+        if life.get(key + "_s") is not None:
+            out["engine." + key] = life[key + "_s"]
+    if life.get("blocks") is not None:
+        out["engine.blocks"] = int(life["blocks"])
+    for key, value in life.items():
+        if key.startswith(("host.", "wait.")):
+            out["engine." + key[:-2]] = value       # strip the ``_s``
+    return out
+
+
+def format_timing(timing: Dict[str, Any]) -> str:
+    """``{"server.execute": 0.0123, "engine.blocks": 4}`` →
+    ``server.execute;dur=12.3, engine.blocks;n=4``."""
+    parts = []
+    for name, value in timing.items():
+        if isinstance(value, int):
+            parts.append(f"{name};n={value}")
+        else:
+            parts.append(f"{name};dur={1e3 * value:.3f}")
+    return ", ".join(parts)
+
+
+def parse_timing(value: Optional[str]) -> Dict[str, Any]:
+    """The header as span attributes: ``<name>_ms`` (float milliseconds)
+    for a ``dur``, ``<name>`` (int) for an ``n``. Entries that do not parse
+    are skipped; absent or garbage input gives {} and never raises."""
+    out: Dict[str, Any] = {}
+    if not value or not isinstance(value, str):
+        return out
+    for entry in value.split(","):
+        name, _, param = entry.strip().partition(";")
+        key, _, raw = param.partition("=")
+        if not name or " " in name:
+            continue
+        try:
+            if key == "dur":
+                out[name + "_ms"] = float(raw)
+            elif key == "n":
+                out[name] = int(raw)
+        except ValueError:
+            continue
+    return out
+
+
+def apply_timing(sp, header_value: Optional[str]) -> None:
+    """Write a response's ``X-KT-Timing`` onto the caller's span."""
+    if not sp:
+        return
+    for key, value in parse_timing(header_value).items():
+        sp.set_attr(key, value)
+
+
+# ---------------------------------------------------------------------------
+# Phase clocks: a hot loop's anatomy, and a one-shot sequence's laps
+# ---------------------------------------------------------------------------
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "_note")
+
+    def __init__(self, clock: "PhaseClock", name: str):
+        self.clock = clock
+        self.name = name
+
+    def __enter__(self) -> "_Phase":
+        # the clock's own stamps are the outermost: what the annotation
+        # costs is inside the phase it names
+        clock = self.clock
+        clock._open = (self.name, time.monotonic())
+        self._note = clock.annotate(clock.prefix + self.name) \
+            if clock.annotate is not None else None
+        if self._note is not None:
+            self._note.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        clock = self.clock
+        name, t0 = clock._open
+        clock._open = None
+        dt = time.monotonic() - t0
+        clock.seconds[name] = clock.seconds.get(name, 0.0) + dt
+        clock._block[name] = clock._block.get(name, 0.0) + dt
+
+
+class PhaseClock:
+    """Where one thread's loop spends its time, phase by phase.
+
+    ``with clock.phase("fetch"):`` adds the block's seconds to the
+    cumulative ``seconds["fetch"]`` and, at the next :meth:`end_block`, to
+    one observation of ``hist{phase="fetch"}`` per phase per block (a phase
+    entered several times in a block observes their sum). ``annotate``, a
+    callable ``name -> context manager`` such as
+    ``jax.profiler.TraceAnnotation``, puts the same phases on a profiler's
+    host timeline as ``<prefix><phase>`` whenever anyone traces (this
+    module stays free of jax). Phases do not nest. Single-threaded by
+    contract: the owning loop is the only writer; :meth:`snapshot` may be
+    called from that thread at any point, also inside a phase, whose
+    seconds so far it counts."""
+
+    def __init__(self, hist: Optional[Histogram] = None, annotate=None,
+                 prefix: str = "", wait: Iterable[str] = ()):
+        self.hist = hist
+        self.annotate = annotate
+        self.prefix = prefix
+        self.wait = frozenset(wait)     # phases spent waiting on the device
+        self.seconds: Dict[str, float] = {}
+        self.blocks = 0
+        self._block: Dict[str, float] = {}
+        self._open: Optional[Tuple[str, float]] = None
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def end_block(self) -> None:
+        """Observe what this pass of the loop spent in each phase."""
+        if self.hist is not None:
+            for name, dt in self._block.items():
+                self.hist.observe(dt, phase=name)
+        self._block = {}
+
+    def snapshot(self) -> Dict[str, float]:
+        """Cumulative seconds per phase as of now."""
+        out = dict(self.seconds)
+        if self._open is not None:
+            name, t0 = self._open
+            out[name] = out.get(name, 0.0) + time.monotonic() - t0
+        return out
+
+    def since(self, earlier: Dict[str, float]) -> Dict[str, float]:
+        """Seconds per phase between an earlier :meth:`snapshot` and now,
+        with the totals ``host_s`` (phases that are the host's own work)
+        and ``wait_s`` (phases spent waiting on the device)."""
+        out: Dict[str, float] = {"host_s": 0.0, "wait_s": 0.0}
+        for name, total in self.snapshot().items():
+            dt = total - earlier.get(name, 0.0)
+            if dt <= 0.0:
+                continue
+            kind = "wait" if name in self.wait else "host"
+            out[f"{kind}.{name}_s"] = dt
+            out[kind + "_s"] += dt
+        return out
+
+
+class Laps:
+    """Consecutive phases of a one-shot sequence (a boot), each lap from
+    the end of the last: ``laps.lap("rank_import_s")`` after the import."""
+
+    __slots__ = ("seconds", "_t")
+
+    def __init__(self):
+        self.seconds: Dict[str, float] = {}
+        self._t = time.monotonic()
+
+    def lap(self, name: str) -> None:
+        now = time.monotonic()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - self._t
+        self._t = now
+
+
+# ---------------------------------------------------------------------------
+# Engine step anatomy metrics (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+# One decode block of the generation engine, from the host's side. The
+# first six are the host's own work between two dispatches; the last two
+# are spent waiting on the device.
+ENGINE_HOST_PHASES = ("hooks", "admit.setup", "admit.seat", "upload",
+                      "dispatch", "emit")
+ENGINE_WAIT_PHASES = ("admit.prefill", "fetch")
+
+# a block is 1-300 ms and a phase of it can be microseconds
+ENGINE_PHASE_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
+                        0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
+
+_ENGINE_METRICS: Optional[Dict[str, _Metric]] = None
+
+
+def engine_metrics() -> Dict[str, "_Metric"]:
+    """Get-or-create the generation engine's step anatomy (ISSUE 26):
+    ``kt_engine_phase_seconds{phase=...}``, one observation per phase per
+    decode block on the stepping thread (``serve/engine.py``): ``hooks``
+    (boundary hooks, reaping cancelled slots), ``admit.setup``,
+    ``admit.prefill``, ``admit.seat``, ``upload``, ``dispatch``, ``fetch``,
+    ``emit``. ``admit.prefill`` and ``fetch`` wait on the device; the rest
+    is the host's own work between two dispatches."""
+    global _ENGINE_METRICS
+    if _ENGINE_METRICS is None:
+        _ENGINE_METRICS = {
+            "phase_seconds": histogram(
+                "kt_engine_phase_seconds",
+                "Generation-engine step anatomy per decode block (phase: "
+                "hooks, admit.setup, admit.prefill, admit.seat, upload, "
+                "dispatch, fetch, emit)",
+                labels=("phase",), buckets=ENGINE_PHASE_BUCKETS),
+        }
+    return _ENGINE_METRICS
 
 
 # ---------------------------------------------------------------------------

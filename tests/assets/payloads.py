@@ -178,3 +178,27 @@ def store_fetcher(store_url, key):
     from kubetorch_tpu.data_store import commands as ds
     arr = ds.get(key, store_url=store_url)
     return float(arr.sum())
+
+
+class EngineService:
+    """A kt.cls that serves a tiny GenerationEngine (ISSUE 26): the call's
+    ``engine.request`` event lands on the rank's ``worker.execute`` span and
+    rides X-KT-Timing back to the caller."""
+
+    def __init__(self):
+        import jax
+        import jax.numpy as jnp
+
+        from kubetorch_tpu.models.llama import LlamaConfig, llama_init
+        from kubetorch_tpu.serve import GenerationEngine
+
+        cfg = LlamaConfig.tiny(attn_impl="xla", dtype=jnp.float32,
+                               remat=False)
+        self.engine = GenerationEngine(
+            llama_init(jax.random.PRNGKey(0), cfg), cfg, slots=2,
+            max_len=32, prefill_buckets=(8,), decode_block=2)
+
+    def generate(self, prompt, max_new_tokens):
+        self.engine.start()
+        return self.engine.submit(
+            prompt, max_new_tokens=max_new_tokens).result(timeout=120)

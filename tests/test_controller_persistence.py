@@ -66,6 +66,9 @@ def test_restore_drops_stale_local_addresses(tmp_path):
                      "pod_ips": ["127.77.1.1"]})
     p.append_logs("ns/svc", [{"line": "hello", "seq": 17}])
     p.append_event({"ts": 1.0, "service": "ns/svc", "message": "deployed"})
+    # the appends are queued behind the persister's writer thread; restore()
+    # reads the files and does not wait for it
+    p.flush()
 
     state = ControllerState(backend=LocalBackend(controller_url="http://x"),
                             state_dir=str(tmp_path))

@@ -172,6 +172,41 @@ def test_reload_prewarms_before_ready():
     run_server_test(body)
 
 
+def test_ready_carries_the_boot_timeline():
+    """A ready /ready says how the launch's boot went (ISSUE 26): every
+    phase in seconds, and ``ready_for_s``, which grows from poll to poll —
+    how long the service had been ready when the client noticed."""
+    from kubetorch_tpu.serving.http_server import BOOT_PHASES
+
+    async def body(client, state):
+        set_fn_metadata("Warmable")
+        await state.reload({}, launch_id="boot-1")
+        r = await client.get("/ready", params={"launch_id": "boot-1"})
+        if r.status == 503:
+            assert "boot" not in await r.json()     # not before it is ready
+        _, first = await wait_ready(client, "boot-1")
+        boot = first["boot"]
+        assert set(boot) == set(BOOT_PHASES) | {"ready_for_s"}
+        assert all(v >= 0.0 for v in boot.values()), boot
+        # this launch came to a pod that was up (no pod_boot of its own);
+        # its pool was spawned, and the rank took time to start and import
+        assert boot["pod_boot_s"] == 0.0
+        for phase in ("pool_spawn_s", "rank_spawn_s", "rank_import_s"):
+            assert boot[phase] > 0.0, (phase, boot)
+        await asyncio.sleep(0.3)
+        _, second = await wait_ready(client, "boot-1")
+        assert second["boot"]["ready_for_s"] \
+            >= boot["ready_for_s"] + 0.25
+        assert {k: v for k, v in second["boot"].items()
+                if k != "ready_for_s"} == \
+            {k: v for k, v in boot.items() if k != "ready_for_s"}
+        # a new launch on the same pod starts its own record
+        await state.reload({}, launch_id="boot-2")
+        _, again = await wait_ready(client, "boot-2")
+        assert again["boot"]["ready_for_s"] < second["boot"]["ready_for_s"]
+    run_server_test(body)
+
+
 def test_array_payload_roundtrip():
     async def body(client, state):
         set_fn_metadata("summer")

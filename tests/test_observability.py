@@ -175,6 +175,59 @@ class TestMetricStreamE2E:
             reset_config()
 
 
+    def test_deploy_leaves_one_span_with_the_boot_timeline(self):
+        """Module.to() runs under one ``client.deploy`` span: its children
+        are the deploy's waits, and the pod's boot phases and
+        ``poll_slack_s`` came back with the last /ready (ISSUE 26). With
+        the pod torn down, the caller's ring alone renders it. Through the
+        real local daemon, so slow like the rest of this class; tier-1 has
+        ``TestTimelineBackToTheCaller``'s twin with a stand-in controller."""
+        import kubetorch_tpu as kt
+        from kubetorch_tpu.config import reset_config
+        from kubetorch_tpu.serving.http_server import BOOT_PHASES
+
+        import payloads  # tests/assets
+
+        reset_config()
+        tel.RING.clear()
+        try:
+            f = kt.cls(payloads.Warmable)
+            t0 = time.monotonic()
+            f.to(kt.Compute(cpus=1))
+            t1 = time.monotonic()
+            try:
+                assert f.was_warmed() is True
+            finally:
+                f.teardown()
+            spans = tel.RING.snapshot()
+            deploys = [s for s in spans if s["name"] == "client.deploy"]
+            assert len(deploys) == 1
+            dep = deploys[0]
+            assert t0 <= dep["start_mono"] <= dep["end_mono"] <= t1
+            kids = {s["name"]: s for s in spans
+                    if s["parent_id"] == dep["span_id"]}
+            assert {"deploy.launch", "deploy.check_service_ready",
+                    "deploy.wait_ready"} <= set(kids)
+            assert kids["deploy.wait_ready"]["attrs"]["polls"] >= 1
+            assert kids["deploy.check_service_ready"]["attrs"]["polls"] >= 1
+            attrs = dep["attrs"]
+            for phase in BOOT_PHASES:
+                assert attrs["boot." + phase] >= 0.0, phase
+            # a fresh pod: its process booted, its pool spawned, its rank
+            # imported and built the class and ran the warm-up hook
+            for phase in ("pod_boot_s", "pool_spawn_s", "rank_spawn_s",
+                          "rank_import_s"):
+                assert attrs["boot." + phase] > 0.0, phase
+            assert 0.0 <= attrs["poll_slack_s"] <= 3.5   # the back-off's cap
+            took = dep["end_mono"] - dep["start_mono"]
+            assert attrs["boot.rank_spawn_s"] + attrs["poll_slack_s"] < took
+            text = tel.format_waterfall(tel.RING.find(dep["trace_id"]))
+            assert "client.deploy" in text and "boot: " in text
+            assert "rank_warmup=" in text and "poll_slack_s=" in text
+        finally:
+            reset_config()
+
+
 class TestPromQueryPassthrough:
     def test_query_relays_to_prometheus(self, monkeypatch):
         from aiohttp import web
@@ -377,6 +430,29 @@ class TestTelemetrySpans:
         # request_id lookup returned the WHOLE trace, not just the
         # span carrying the attribute
         assert tel.RING.find(outer.trace_id) == spans
+
+    def test_spans_carry_monotonic_stamps(self, clean_ring):
+        """One clock (ISSUE 26): beside the wall stamps a span and its
+        events carry ``time.monotonic()`` ones, in ``to_dict()`` and so in
+        the ring, /debug/traces and the recorder."""
+        t0 = time.monotonic()
+        with tel.span("timed") as sp:
+            tel.add_event("tick")
+            mid = sp.to_dict()               # still open: stamped "now"
+            time.sleep(0.01)
+        t1 = time.monotonic()
+        d = tel.RING.snapshot()[-1]
+        assert d == sp.to_dict()
+        assert t0 <= d["start_mono"] <= d["events"][0]["mono"] \
+            <= mid["end_mono"] <= d["end_mono"] <= t1
+        assert d["end_mono"] - d["start_mono"] >= 0.01
+        assert abs((d["end"] - d["start"])
+                   - (d["end_mono"] - d["start_mono"])) < 0.005
+        assert sp.seconds() == d["end_mono"] - d["start_mono"]
+        with tel.span("open") as live:
+            active = [s for s in tel.active_spans() if s["name"] == "open"]
+            assert active[0]["end_mono"] is None
+            assert active[0]["start_mono"] == live.start_mono
 
     def test_header_roundtrip_continues_trace(self, clean_ring):
         with tel.span("client.call") as sp:
@@ -649,6 +725,202 @@ class TestTracePropagationE2E:
                 assert spans["store.fetch"]["attrs"]["bytes"] == arr.nbytes
                 # queue wait was measured and shipped
                 assert "queue_wait_s" in spans["worker.execute"]["attrs"]
+
+
+class TestTimelineBackToTheCaller:
+    """ISSUE 26: the pod answers every call with X-KT-Timing, and the
+    client writes it onto its ``client.call`` span, so the caller's ring
+    alone shows where the call's time went."""
+
+    @staticmethod
+    def _last_call():
+        return next(s for s in reversed(tel.RING.snapshot())
+                    if s["name"] == "client.call")
+
+    def test_server_stages_and_engine_life_on_the_callers_span(
+            self, pod_metadata, clean_ring, monkeypatch):
+        from kubetorch_tpu.serving.http_client import HTTPClient
+        from kubetorch_tpu.serving.http_server import create_app
+        from tests.assets.threaded_server import ThreadedAiohttpServer
+
+        monkeypatch.setenv("KT_CLS_OR_FN_NAME", "EngineService")
+        with ThreadedAiohttpServer(create_app) as srv:
+            client = HTTPClient(srv.url, stream_logs=False)
+            toks = client.call_method("EngineService", "generate",
+                                      args=([3, 5, 7], 6), timeout=180)
+            assert len(toks) == 6
+            first = self._last_call()
+            toks = client.call_method("EngineService", "generate",
+                                      args=([3, 5, 7], 6), timeout=180)
+            span = self._last_call()
+        assert span["span_id"] != first["span_id"]
+        attrs = span["attrs"]
+        took_ms = 1e3 * (span["end_mono"] - span["start_mono"])
+        stages = {k: v for k, v in attrs.items()
+                  if k.startswith("server.")}
+        assert {"server.deserialize_ms", "server.queue_wait_ms",
+                "server.execute_ms", "server.device_transfer_ms",
+                "server.respond_ms"} <= set(stages)
+        assert all(v >= 0.0 for v in stages.values())
+        # the stages are disjoint parts of the pod's handling of the call,
+        # but for execute, which holds the rank's side
+        parts = sum(v for k, v in stages.items()
+                    if k != "server.execute_ms")
+        assert parts + attrs["rank.execute_ms"] <= attrs["pod.total_ms"] + 1
+        assert attrs["server.execute_ms"] <= attrs["pod.total_ms"] <= took_ms
+        assert sum(stages.values()) <= 2 * took_ms
+        # the engine's account of the request, from inside
+        assert attrs["engine.blocks"] >= 1
+        for key in ("engine.queue_ms", "engine.prefill_ms",
+                    "engine.decode_ms", "engine.host_ms", "engine.wait_ms"):
+            assert 0.0 <= attrs[key] <= attrs["rank.execute_ms"], key
+        assert any(k.startswith("engine.host.") for k in attrs)
+        # the pod is gone; the caller's ring renders the whole call
+        text = tel.format_waterfall(tel.RING.find(span["trace_id"]))
+        assert "server: " in text and "queue_wait=" in text
+        assert "engine: " in text and "prefill=" in text \
+            and "decode=" in text
+
+    def test_deploy_leaves_one_span_with_the_boot_timeline(
+            self, pod_metadata, clean_ring, monkeypatch):
+        """``Module.to()`` runs under one ``client.deploy`` span whose
+        children are the deploy's waits, and the pod's boot phases and
+        ``poll_slack_s`` come back with the last /ready. The pod is a real
+        server with a real rank; the controller is a stand-in that hands
+        the launch to it, as the daemon's push would (the deploy through
+        the daemon itself is ``TestMetricStreamE2E``'s, outside tier-1)."""
+        import requests as _rq
+
+        import kubetorch_tpu as kt
+        from kubetorch_tpu.resources import compute as compute_mod
+        from kubetorch_tpu.serving.http_server import BOOT_PHASES, create_app
+        from tests.assets.threaded_server import ThreadedAiohttpServer
+
+        import payloads  # tests/assets
+
+        monkeypatch.setenv("KT_CLS_OR_FN_NAME", "Warmable")
+
+        class Controller:
+            polls = 0
+
+            def check_ready(self, namespace, name):
+                self.polls += 1
+                return {"ready": self.polls >= 2}
+
+        monkeypatch.setattr(compute_mod, "controller_client", Controller)
+        with ThreadedAiohttpServer(create_app) as srv:
+            def launch(self, name, metadata, launch_id=None):
+                r = _rq.post(f"{srv.url}/_kt/reload", timeout=60, json={
+                    "metadata": {}, "launch_id": launch_id})
+                assert r.status_code == 200, r.text
+                return {"launch_id": launch_id, "service_url": srv.url}
+
+            monkeypatch.setattr(kt.Compute, "_launch", launch)
+            svc = kt.cls(payloads.Warmable)
+            t0 = time.monotonic()
+            svc.to(kt.Compute(cpus=1))
+            t1 = time.monotonic()
+            assert svc.was_warmed() is True
+        spans = tel.RING.snapshot()
+        deploys = [s for s in spans if s["name"] == "client.deploy"]
+        assert len(deploys) == 1
+        dep = deploys[0]
+        assert t0 <= dep["start_mono"] <= dep["end_mono"] <= t1
+        kids = {s["name"]: s for s in spans
+                if s["parent_id"] == dep["span_id"]}
+        assert {"deploy.sync_code", "deploy.launch",
+                "deploy.check_service_ready", "deploy.wait_ready"} \
+            <= set(kids)
+        assert kids["deploy.check_service_ready"]["attrs"]["polls"] == 2
+        assert kids["deploy.check_service_ready"]["attrs"][
+            "last_delay_s"] == 0.25
+        assert kids["deploy.wait_ready"]["attrs"]["polls"] >= 1
+        attrs = dep["attrs"]
+        assert {"boot." + p for p in BOOT_PHASES} | {"poll_slack_s"} \
+            <= set(attrs)
+        assert all(attrs["boot." + p] >= 0.0 for p in BOOT_PHASES)
+        for phase in ("pool_spawn_s", "rank_spawn_s", "rank_import_s"):
+            assert attrs["boot." + phase] > 0.0, phase
+        assert 0.0 <= attrs["poll_slack_s"] <= 3.5      # the back-off's cap
+        took = dep["end_mono"] - dep["start_mono"]
+        assert attrs["boot.rank_spawn_s"] + attrs["poll_slack_s"] < took
+        # the pod is gone; the caller's ring alone renders the deploy
+        text = tel.format_waterfall(tel.RING.find(dep["trace_id"]))
+        assert "client.deploy" in text and "deploy.wait_ready" in text
+        assert "boot: " in text and "rank_warmup=" in text
+        assert "poll_slack_s=" in text
+
+    def test_fanned_out_call_reports_the_slowest_rank(self):
+        timing = {"server.execute": 0.5}
+        tel.merge_timing(timing, {"rank.execute": 0.2, "engine.blocks": 3,
+                                  "server.device_transfer": 0.01})
+        tel.merge_timing(timing, {"rank.execute": 0.4,
+                                  "server.queue_wait": 0.02})
+        tel.merge_timing(timing, {"rank.execute": 0.3, "engine.blocks": 9})
+        flat = tel.finish_call_timing(timing)
+        assert flat["rank.execute"] == 0.4 and "engine.blocks" not in flat
+        assert flat["server.respond"] == pytest.approx(0.5 - 0.4 - 0.02)
+        assert tel.parse_timing(tel.format_timing(flat))[
+            "server.queue_wait_ms"] == pytest.approx(20.0)
+
+    def test_garbage_timing_header_is_ignored(self, clean_ring):
+        from aiohttp import web
+
+        from kubetorch_tpu.serving.http_client import HTTPClient
+        from tests.assets.threaded_server import ThreadedAiohttpServer
+
+        assert tel.parse_timing(None) == {}
+        assert tel.parse_timing(12) == {}
+        assert tel.parse_timing(";;;===,, ,a;dur=x,b;n=1.5,c d;dur=1") == {}
+        assert tel.parse_timing("ok;dur=1.5, bad;dur=, n;n=4,;dur=2") == \
+            {"ok_ms": 1.5, "n": 4}
+
+        async def answer(request):
+            return web.json_response(
+                41, headers={tel.TIMING_HEADER: "%%;;=,dur;dur=dur, =;="})
+
+        def app():
+            a = web.Application()
+            a.router.add_post("/f", answer)
+            return a
+
+        with ThreadedAiohttpServer(app) as srv:
+            out = HTTPClient(srv.url, stream_logs=False).call_method(
+                "f", timeout=30)
+        assert out == 41
+        attrs = self._last_call()["attrs"]
+        assert attrs["status"] == 200
+        assert not [k for k in attrs if k.startswith(("server.", "dur"))]
+
+    def test_tracing_off_sends_no_header_and_allocates_no_span(
+            self, pod_metadata, clean_ring, monkeypatch):
+        import requests as _rq
+
+        from kubetorch_tpu.serving.http_server import create_app
+        from tests.assets.threaded_server import ThreadedAiohttpServer
+
+        monkeypatch.setenv("KT_CLS_OR_FN_NAME", "summer")
+        with ThreadedAiohttpServer(create_app) as srv:
+            body = json.dumps({"args": [1, 2], "kwargs": {}})
+            on = _rq.post(f"{srv.url}/summer", data=body, timeout=120)
+            assert on.json() == 3
+            sent = tel.parse_timing(on.headers[tel.TIMING_HEADER])
+            assert sent["server.execute_ms"] > 0
+            assert "rank.execute_ms" in sent and "pod.total_ms" in sent
+            assert len(on.headers[tel.TIMING_HEADER]) < 400
+        # off before the pod and its rank start (a rank keeps the
+        # environment it was spawned with)
+        monkeypatch.setenv("KT_TRACE", "0")
+        assert tel.begin_call_timing() is None
+        assert tel.span("x") is tel.NOOP_SPAN
+        with ThreadedAiohttpServer(create_app) as srv:
+            time.sleep(0.5)      # the first pod's last rank spans are in
+            tel.RING.clear()
+            off = _rq.post(f"{srv.url}/summer", data=body, timeout=120)
+            assert off.json() == 3
+            assert tel.TIMING_HEADER not in off.headers
+            time.sleep(0.5)      # rank spans would have shipped by now
+            assert len(tel.RING) == 0
 
 
 class TestChaosRetryThroughTraces:

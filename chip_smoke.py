@@ -343,12 +343,18 @@ class SmokeEngine:
                 return jnp.einsum("bkgs,bskh->bkgh", pr,
                                   cv).reshape(slots, nh, hd)
             want = einsum_decode(qd, ck, cv, pos)
-            got, m = run(lambda *a: decode_attention(*a), qd, ck, cv, pos)
+
+            def grid(rows):
+                # layer 1 of a two-layer head-major grid (L, B, NKV, S, ..),
+                # as the engine holds it; layer 0 is zeros
+                return jnp.stack([jnp.zeros_like(rows), rows]).swapaxes(2, 3)
+            got, m = run(lambda *a: decode_attention(*a, 1),
+                         qd, grid(ck), grid(cv), pos)
             record(f"decode_attention_hd{hd}", err(got, want), 0.05, m)
             kq, ksc = quantize_rows(ck)
             vq, vsc = quantize_rows(cv)
-            got, m = run(lambda *a: decode_attention_quant(*a),
-                         qd, kq, ksc, vq, vsc, pos)
+            got, m = run(lambda *a: decode_attention_quant(*a, 1),
+                         qd, grid(kq), grid(ksc), grid(vq), grid(vsc), pos)
             record(f"decode_attention_quant_hd{hd}", err(got, want), 0.08, m)
 
         din, dout = (256, 512) if small else (2048, 8192)

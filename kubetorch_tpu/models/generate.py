@@ -32,12 +32,19 @@ NEG_INF = -1e30
 
 
 class KVCache(NamedTuple):
-    k: jax.Array   # (L, B, S_max, NKV, Hd)
+    """K and V of every layer, in one of two layouts. Row-major
+    (L, B, S_max, NKV, Hd): ``generate``'s own cache and a prompt's rows out
+    of a prefill (:func:`init_cache`, ``_layer_step``). Head-major
+    (L, SLOTS, NKV, S_max, Hd): the serving engine's slot grid
+    (``serve.engine.init_grid_cache``), which is the decode kernel's layout;
+    ``serve.engine._splice_slot`` is the one crossing."""
+    k: jax.Array
     v: jax.Array
 
 
 def init_cache(cfg: "LlamaConfig | MoeConfig", batch: int, max_len: int,
                dtype=None) -> KVCache:
+    """Zeroed row-major cache (L, B, S_max, NKV, Hd)."""
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     dtype = dtype or cfg.dtype
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))

@@ -13,18 +13,25 @@ in-range tile (Pallas elides the DMA when the block index repeats), so a
 slot 300 tokens into a 4096-row cache streams 8 tiles, not 32
 ([pos // block_k] + 1 of them); ``pl.when`` skips the matching compute.
 
-Two cache layouts share ONE kernel body (``_make_decode_kernel``):
+The kernel reads the engine's STACKED grid where it lies: K/V operands
+are the whole ``(L, B, NKV, S, Hd)`` grids (head-major, so the last two
+axes are (row, dim) and a ``BlockSpec`` can address one layer's tile), and
+the layer index rides in as a second prefetched scalar that the index maps
+put on the leading axis. No layer is sliced out and nothing is transposed
+on the way in.
 
-- full-precision (B, S, NKV, Hd) rows — probs round through the cache
-  dtype before the PV dot, matching the einsum reference bitwise;
-- int8 rows + per-row fp32 scales (``serve.kv_quant``) — the scales fold
-  into the math (logits columns ·ks, probs ·vs; all fp32), so the HBM
-  stream is int8 tiles plus one (1, block_k) scale row per tile and no fp
-  rows ever materialize.
+Two cache dtypes share ONE kernel body (``_make_decode_kernel``):
+
+- full-precision rows — probs round through the cache dtype before the PV
+  dot, matching the einsum reference bitwise;
+- int8 rows + per-row fp32 scales ``(L, B, NKV, S)`` (``serve.kv_quant``)
+  — the scales fold into the math (logits columns ·ks, probs ·vs; all
+  fp32), so the HBM stream is int8 tiles plus one (1, block_k) scale row
+  per tile and no fp rows ever materialize.
 
 Layout mirrors ``ops.attention``: (B, NKV, G, Hd) query block per grid
-step, K/V head-major, fp32 accumulators in VMEM scratch, the innermost
-grid axis sequential over K tiles.
+step, fp32 accumulators in VMEM scratch, the innermost grid axis
+sequential over K tiles.
 """
 
 from __future__ import annotations
@@ -51,7 +58,8 @@ def _make_decode_kernel(quant: bool, *, scale: float, block_k: int):
     row scales fold in — the frontier skip, init/finalize, and softmax
     scaffolding are shared so they can never drift apart."""
 
-    def kernel(pos_ref, q_ref, *refs):
+    def kernel(pos_ref, layer_ref, q_ref, *refs):
+        del layer_ref                     # read by the index maps only
         if quant:
             k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
         else:
@@ -73,12 +81,12 @@ def _make_decode_kernel(quant: bool, *, scale: float, block_k: int):
         @pl.when(start <= pos_b)
         def _compute():
             q = q_ref[0, 0].astype(jnp.float32)       # (Gp, Hd)
-            k = k_ref[0, 0].astype(jnp.float32)       # (BK, Hd)
+            k = k_ref[0, 0, 0].astype(jnp.float32)    # (BK, Hd)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * scale
             if quant:
-                s = s * ks_ref[0, 0]                  # (1, BK) logit columns
+                s = s * ks_ref[0, 0, 0]               # (1, BK) logit columns
             cols = start + jax.lax.broadcasted_iota(
                 jnp.int32, (q.shape[0], block_k), 1)
             s = jnp.where(cols <= pos_b, s, NEG_INF)
@@ -91,13 +99,13 @@ def _make_decode_kernel(quant: bool, *, scale: float, block_k: int):
             if quant:
                 # vs folds into the probs; int8 V dequantizes to fp32 —
                 # the whole PV dot runs fp32 (the quant einsum reference)
-                pv_lhs = p * vs_ref[0, 0]
-                v = v_ref[0, 0].astype(jnp.float32)
+                pv_lhs = p * vs_ref[0, 0, 0]
+                v = v_ref[0, 0, 0].astype(jnp.float32)
             else:
                 # p rounds through the cache dtype before the PV dot
                 # (fp32 acc) — same rounding as the einsum reference and
                 # the flash fwd kernel
-                v = v_ref[0, 0]
+                v = v_ref[0, 0, 0]
                 pv_lhs = p.astype(v.dtype)
             acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
                 pv_lhs, v, (((1,), (0,)), ((), ())),
@@ -113,15 +121,16 @@ def _make_decode_kernel(quant: bool, *, scale: float, block_k: int):
     return kernel
 
 
-def _decode_call(quant: bool, q, values, scales, pos, *,
+def _decode_call(quant: bool, q, values, scales, pos, layer, *,
                  scale: Optional[float], block_k: int,
                  interpret: Optional[bool]):
-    """Shared wrapper: shape derivation, GQA padding, head-major
-    transposes, frontier-clamp BlockSpecs, scratch, and output slicing for
-    both layouts. ``values`` = (ck, cv) rows (B, S, NKV, Hd); ``scales`` =
-    (ks, vs) per-row scales (B, S, NKV) for the quant layout, else None."""
+    """Shared wrapper: shape derivation, GQA padding, frontier-clamp
+    BlockSpecs, scratch, and output slicing for both dtypes. ``values`` =
+    (gk, gv) stacked grids (L, B, NKV, S, Hd), read in place; ``scales`` =
+    (ks, vs) per-row scales (L, B, NKV, S) for the quant grid, else None;
+    ``layer`` picks the grid's leading index (second prefetched scalar)."""
     b, nh, hd = q.shape
-    s, nkv = values[0].shape[1], values[0].shape[2]
+    nkv, s = values[0].shape[2], values[0].shape[3]
     assert nh % nkv == 0, f"GQA requires n_kv | n_heads, got {nkv}, {nh}"
     group = nh // nkv
     if scale is None:
@@ -144,34 +153,31 @@ def _decode_call(quant: bool, q, values, scales, pos, *,
     # block as the previous step, so past-frontier steps clamp to the last
     # in-range tile (the kernel's pl.when then skips the compute too).
     # pl.when alone would save FLOPs but still stream every tile from HBM.
-    def val_spec():
-        return pl.BlockSpec((1, 1, bk, hd),
-                            lambda b_, h, j, pos_: (
-                                b_, h, jnp.minimum(j, pos_[b_] // bk), 0))
-
-    def scale_spec():
-        return pl.BlockSpec((1, 1, 1, bk),
-                            lambda b_, h, j, pos_: (
-                                b_, h, 0, jnp.minimum(j, pos_[b_] // bk)))
-
+    val_spec = pl.BlockSpec(
+        (1, 1, 1, bk, hd),
+        lambda b_, h, j, pos_, l_: (
+            l_[0], b_, h, jnp.minimum(j, pos_[b_] // bk), 0))
+    scale_spec = pl.BlockSpec(
+        (1, 1, 1, 1, bk),
+        lambda b_, h, j, pos_, l_: (
+            l_[0], b_, h, 0, jnp.minimum(j, pos_[b_] // bk)))
     q_spec = pl.BlockSpec((1, 1, gp, hd),
-                          lambda b_, h, j, pos_: (b_, h, 0, 0))
+                          lambda b_, h, j, pos_, l_: (b_, h, 0, 0))
     inputs, in_specs = [qg], [q_spec]
     for i, val in enumerate(values):
-        inputs.append(val.transpose(0, 2, 1, 3))       # (B, NKV, S, Hd)
-        in_specs.append(val_spec())
+        inputs.append(val)
+        in_specs.append(val_spec)
         if quant:
-            inputs.append(scales[i].transpose(0, 2, 1)[:, :, None, :])
-            in_specs.append(scale_spec())              # (B, NKV, 1, S)
+            inputs.append(scales[i][:, :, :, None, :])   # (L, B, NKV, 1, S)
+            in_specs.append(scale_spec)
 
     out = pl.pallas_call(
         _make_decode_kernel(quant, scale=scale, block_k=bk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, nkv, s // bk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, gp, hd),
-                                   lambda b_, h, j, pos_: (b_, h, 0, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((gp, hd), jnp.float32),    # acc
                 pltpu.VMEM((gp, 1), jnp.float32),     # m
@@ -181,38 +187,43 @@ def _decode_call(quant: bool, q, values, scales, pos, *,
         out_shape=jax.ShapeDtypeStruct((b, nkv, gp, hd), q.dtype),
         interpret=interpret,
         name="kt_decode_attention_quant" if quant else "kt_decode_attention",
-    )(pos.astype(jnp.int32), *inputs)
+    )(pos.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      *inputs)
     return out[:, :, :group].reshape(b, nh, hd)
 
 
-def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                     pos: jax.Array, *, scale: Optional[float] = None,
-                     block_k: int = 512,
+def decode_attention(q: jax.Array, gk: jax.Array, gv: jax.Array,
+                     pos: jax.Array, layer, *,
+                     scale: Optional[float] = None, block_k: int = 512,
                      interpret: Optional[bool] = None) -> jax.Array:
-    """One new token per slot against its cache rows ``<= pos``.
+    """One new token per slot against its cache rows ``<= pos`` of one
+    layer of the stacked grid.
 
-    q: (B, NH, Hd); ck/cv: (B, S, NKV, Hd); pos: (B,) int32 — the row each
-    slot's new token occupies (already written). Returns (B, NH, Hd).
-    Bit-compatible with the masked-einsum reference in
-    ``serve.engine._decode_layer`` (asserted in tests/test_decode_kernel.py).
+    q: (B, NH, Hd); gk/gv: (L, B, NKV, S, Hd), the engine's head-major
+    grid, read in place; pos: (B,) int32 — the row each slot's new token
+    occupies (already written); layer: int or traced int32 scalar.
+    Returns (B, NH, Hd). Bit-compatible with the masked-einsum reference
+    in ``serve.engine._decode_layer`` (asserted in
+    tests/test_decode_kernel.py).
 
     ``block_k=512``: a larger tile streams a full cache in fewer steps, a
     smaller one skips more rows past a part-filled slot's frontier.
     """
-    return _decode_call(False, q, (ck, cv), None, pos, scale=scale,
+    return _decode_call(False, q, (gk, gv), None, pos, layer, scale=scale,
                         block_k=block_k, interpret=interpret)
 
 
 def decode_attention_quant(q: jax.Array, kq: jax.Array, ks: jax.Array,
-                           vq: jax.Array, vs: jax.Array, pos: jax.Array, *,
-                           scale: Optional[float] = None, block_k: int = 512,
+                           vq: jax.Array, vs: jax.Array, pos: jax.Array,
+                           layer, *, scale: Optional[float] = None,
+                           block_k: int = 512,
                            interpret: Optional[bool] = None) -> jax.Array:
-    """Flash-decode over an int8 cache (``serve.kv_quant``): same frontier
+    """Flash-decode over an int8 grid (``serve.kv_quant``): same frontier
     tile-skipping as :func:`decode_attention`, HALF the HBM stream.
 
-    q: (B, NH, Hd); kq/vq: (B, S, NKV, Hd) int8; ks/vs: (B, S, NKV) fp32
-    per-row scales; pos: (B,). Bit-compatible with the fp32 fold-in einsum
-    reference (``serve.engine._decode_layer_quant``), asserted in
-    tests/test_kv_quant.py."""
-    return _decode_call(True, q, (kq, vq), (ks, vs), pos, scale=scale,
+    q: (B, NH, Hd); kq/vq: (L, B, NKV, S, Hd) int8; ks/vs: (L, B, NKV, S)
+    fp32 per-row scales; pos: (B,); layer as in :func:`decode_attention`.
+    Bit-compatible with the fp32 fold-in einsum reference
+    (``serve.engine._decode_layer``), asserted in tests/test_kv_quant.py."""
+    return _decode_call(True, q, (kq, vq), (ks, vs), pos, layer, scale=scale,
                         block_k=block_k, interpret=interpret)

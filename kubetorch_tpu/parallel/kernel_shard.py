@@ -56,34 +56,36 @@ def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, mesh,
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def decode_attention_sharded(q, ck, cv, pos, mesh, *,
+def decode_attention_sharded(q, gk, gv, pos, layer, mesh, *,
                              scale: Optional[float] = None) -> jax.Array:
     """:func:`~..ops.decode_attention.decode_attention` with q (B, NH, Hd),
-    cache (B, S, NKV, Hd) and pos (B,) sharded slots × heads — the layout
-    ``serve.engine._cache_shardings`` keeps the grid in."""
+    the stacked grids (L, B, NKV, S, Hd) and pos (B,) sharded slots ×
+    heads — the layout ``serve.engine._cache_shardings`` keeps the grid
+    in; ``layer`` (scalar) is replicated."""
     fn = functools.partial(decode.decode_attention, scale=scale)
-    axes = _axes(mesh, q.shape[0], q.shape[1], ck.shape[2])
+    axes = _axes(mesh, q.shape[0], q.shape[1], gk.shape[2])
     if axes is None:
-        return fn(q, ck, cv, pos)
+        return fn(q, gk, gv, pos, layer)
     ba, ha = axes
-    q_spec, kv_spec = P(ba, ha, None), P(ba, None, ha, None)
+    q_spec, kv_spec = P(ba, ha, None), P(None, ba, ha, None, None)
     return jax.shard_map(fn, mesh=mesh,
-                         in_specs=(q_spec, kv_spec, kv_spec, P(ba)),
-                         out_specs=q_spec, check_vma=False)(q, ck, cv, pos)
+                         in_specs=(q_spec, kv_spec, kv_spec, P(ba), P()),
+                         out_specs=q_spec,
+                         check_vma=False)(q, gk, gv, pos, layer)
 
 
-def decode_attention_quant_sharded(q, kq, ks, vq, vs, pos, mesh, *,
+def decode_attention_quant_sharded(q, kq, ks, vq, vs, pos, layer, mesh, *,
                                    scale: Optional[float] = None) -> jax.Array:
-    """int8-cache variant: values (B, S, NKV, Hd) int8 and per-row scales
-    (B, S, NKV), both sharded slots × heads."""
+    """int8-grid variant: values (L, B, NKV, S, Hd) int8 and per-row scales
+    (L, B, NKV, S), both sharded slots × heads."""
     fn = functools.partial(decode.decode_attention_quant, scale=scale)
     axes = _axes(mesh, q.shape[0], q.shape[1], kq.shape[2])
     if axes is None:
-        return fn(q, kq, ks, vq, vs, pos)
+        return fn(q, kq, ks, vq, vs, pos, layer)
     ba, ha = axes
-    q_spec, kv_spec, sc_spec = (P(ba, ha, None), P(ba, None, ha, None),
-                                P(ba, None, ha))
+    q_spec, kv_spec, sc_spec = (P(ba, ha, None), P(None, ba, ha, None, None),
+                                P(None, ba, ha, None))
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(q_spec, kv_spec, sc_spec, kv_spec, sc_spec, P(ba)),
-        out_specs=q_spec, check_vma=False)(q, kq, ks, vq, vs, pos)
+        in_specs=(q_spec, kv_spec, sc_spec, kv_spec, sc_spec, P(ba), P()),
+        out_specs=q_spec, check_vma=False)(q, kq, ks, vq, vs, pos, layer)

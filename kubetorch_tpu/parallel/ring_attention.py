@@ -158,18 +158,19 @@ def sp_decode_attention(q, ck, cv, pos, *, axis_name: str,
     result without any device ever holding more than 1/C of the cache (and
     without the all-gather GSPMD would insert around a dense einsum).
 
-    q (B, NH, Hd) replicated over ``axis_name``; ck/cv (B, S_local, NKV,
-    Hd) this shard's rows; pos (B,) GLOBAL frontier per slot. Rounding
+    q (B, NH, Hd) replicated over ``axis_name``; ck/cv (B, NKV, S_local,
+    Hd) this shard's rows of one layer of the head-major grid; pos (B,)
+    GLOBAL frontier per slot. Rounding
     matches the engine's einsum reference (probs cast to the cache dtype
     before the PV dot); the split softmax itself combines in fp32."""
     b, nh, hd = q.shape
-    s_local, nkv = ck.shape[1], ck.shape[2]
+    nkv, s_local = ck.shape[1], ck.shape[2]
     group = nh // nkv
     if scale is None:
         scale = hd ** -0.5
     offset = lax.axis_index(axis_name) * s_local
     qg = q.reshape(b, nkv, group, hd)
-    s = jnp.einsum("bkgh,bskh->bkgs", qg, ck).astype(jnp.float32) * scale
+    s = jnp.einsum("bkgh,bksh->bkgs", qg, ck).astype(jnp.float32) * scale
     cols = offset + jnp.arange(s_local)
     mask = cols[None, :] <= pos[:, None]                     # (B, S_local)
     s = jnp.where(mask[:, None, None], s, NEG_INF)
@@ -177,7 +178,7 @@ def sp_decode_attention(q, ck, cv, pos, *, axis_name: str,
     p = jnp.exp(s - m)
     p = jnp.where(s <= NEG_INF, 0.0, p)
     l = jnp.sum(p, axis=-1, keepdims=True)                   # (b,k,g,1)
-    acc = jnp.einsum("bkgs,bskh->bkgh", p.astype(cv.dtype),
+    acc = jnp.einsum("bkgs,bksh->bkgh", p.astype(cv.dtype),
                      cv).astype(jnp.float32)
     m_g = lax.pmax(m, axis_name)
     corr = jnp.exp(m - m_g)                                  # (b,k,g,1)
@@ -195,15 +196,15 @@ def sp_decode_attention_quant(q, kq, ks, vq, vs, pos, *, axis_name: str,
     int8 KV cache and context sharding COMPOSE: 1/(2C) of the fp cache
     bytes per chip."""
     b, nh, hd = q.shape
-    s_local, nkv = kq.shape[1], kq.shape[2]
+    nkv, s_local = kq.shape[1], kq.shape[2]
     group = nh // nkv
     if scale is None:
         scale = hd ** -0.5
     offset = lax.axis_index(axis_name) * s_local
     qg = q.reshape(b, nkv, group, hd).astype(jnp.float32)
-    s = jnp.einsum("bkgh,bskh->bkgs", qg,
+    s = jnp.einsum("bkgh,bksh->bkgs", qg,
                    kq.astype(jnp.float32)) * scale
-    s = s * ks.transpose(0, 2, 1)[:, :, None, :]             # (B,NKV,1,S)
+    s = s * ks[:, :, None, :]                                # (B,NKV,1,S)
     cols = offset + jnp.arange(s_local)
     mask = cols[None, :] <= pos[:, None]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
@@ -211,8 +212,8 @@ def sp_decode_attention_quant(q, kq, ks, vq, vs, pos, *, axis_name: str,
     p = jnp.exp(s - m)
     p = jnp.where(s <= NEG_INF, 0.0, p)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    p = p * vs.transpose(0, 2, 1)[:, :, None, :]
-    acc = jnp.einsum("bkgs,bskh->bkgh", p, vq.astype(jnp.float32))
+    p = p * vs[:, :, None, :]
+    acc = jnp.einsum("bkgs,bksh->bkgh", p, vq.astype(jnp.float32))
     m_g = lax.pmax(m, axis_name)
     corr = jnp.exp(m - m_g)
     l_g = lax.psum(l * corr, axis_name)
@@ -234,8 +235,8 @@ def _sp_decode_specs(mesh, batch_axes, context_axis, head_axis):
                          "via sp_decode_supported)")
     ba = normalize_batch_axes(live, batch_axes)
     ha = head_axis if head_axis in live else None
-    return (P(ba, ha, None), P(ba, context_axis, ha, None),
-            P(ba, context_axis, ha), P(ba))
+    return (P(ba, ha, None), P(ba, ha, context_axis, None),
+            P(ba, ha, context_axis), P(ba))
 
 
 def sp_decode_supported(mesh, b: int, s: int, nkv: int, nh: int, *,
@@ -266,11 +267,11 @@ def sp_decode_attention_sharded(q, ck, cv, pos, mesh, *,
                                 batch_axes=("dcn", "data", "fsdp"),
                                 context_axis: str = "context",
                                 head_axis: str = "tensor") -> jax.Array:
-    """GSPMD wrapper for the engine's decode step: cache (B, S, NKV, Hd)
-    sharded batch×context×heads, q (B, NH, Hd) batch×heads, pos (B,)
-    batch. shard_map pins those layouts, so jit KEEPS the cache
-    context-sharded across steps instead of gathering it. Callers gate on
-    :func:`sp_decode_supported`."""
+    """GSPMD wrapper for the engine's decode step: one layer of the grid
+    (B, NKV, S, Hd) sharded batch×heads×context, q (B, NH, Hd)
+    batch×heads, pos (B,) batch. shard_map pins those layouts, so jit
+    KEEPS the cache context-sharded across steps instead of gathering it.
+    Callers gate on :func:`sp_decode_supported`."""
     q_spec, kv_spec, _, pos_spec = _sp_decode_specs(
         mesh, batch_axes, context_axis, head_axis)
     fn = functools.partial(sp_decode_attention, axis_name=context_axis,
@@ -286,8 +287,8 @@ def sp_decode_attention_quant_sharded(q, kq, ks, vq, vs, pos, mesh, *,
                                       context_axis: str = "context",
                                       head_axis: str = "tensor") -> jax.Array:
     """int8-cache variant of :func:`sp_decode_attention_sharded`: values
-    int8 (B, S, NKV, Hd) + per-row scales (B, S, NKV), both sharded over
-    batch×context×heads."""
+    int8 (B, NKV, S, Hd) + per-row scales (B, NKV, S), both sharded over
+    batch×heads×context."""
     q_spec, kv_spec, sc_spec, pos_spec = _sp_decode_specs(
         mesh, batch_axes, context_axis, head_axis)
     fn = functools.partial(sp_decode_attention_quant,

@@ -80,6 +80,11 @@ class AOTKey:
     # engines differing only in top_k would swap executables and sample
     # wrong
     top_k: Optional[int] = None
+    # the axis order of the engine's slot grid, which every decode
+    # executable takes and returns: an entry compiled for another grid
+    # layout (before it was head-major: no tag) must MISS here, not load
+    # and fail at call time on a shape mismatch
+    grid_layout: str = ""
     jax_version: str = ""
     jaxlib_version: str = ""
     backend: str = ""
@@ -88,6 +93,8 @@ class AOTKey:
     def for_engine(engine) -> "AOTKey":
         import jax
         import jaxlib
+
+        from .engine import GRID_LAYOUT
 
         mesh = getattr(engine, "_mesh", None)
         mesh_shape = (tuple(sorted(dict(mesh.shape).items()))
@@ -101,6 +108,7 @@ class AOTKey:
             quantize_kv=engine.quantize_kv,
             decode_block=engine.decode_block,
             top_k=engine.top_k,
+            grid_layout=GRID_LAYOUT,
             jax_version=jax.__version__,
             jaxlib_version=getattr(jaxlib, "__version__", ""),
             backend=jax.default_backend(),

@@ -8,9 +8,11 @@ to the next caller immediately.
 
 TPU-first design — everything the chip executes has a static shape:
 
-- **Slot grid.** The KV cache is one fixed ``(L, SLOTS, S_max, NKV, Hd)``
-  buffer. A request occupies a slot for its lifetime; admission and
-  retirement are host-side bookkeeping, never a recompile.
+- **Slot grid.** The KV cache is one fixed ``(L, SLOTS, NKV, S_max, Hd)``
+  buffer: head-major, the decode kernel's layout, so a decode block reads
+  and writes it where it lies (never sliced, transposed or copied). A
+  request occupies a slot for its lifetime; admission and retirement are
+  host-side bookkeeping, never a recompile.
 - **One decode step for the whole grid.** Every step decodes ALL slots in a
   single jitted call — per-slot absolute positions (a ``(SLOTS,)`` vector)
   drive RoPE and the causal mask, so slots at different depths batch into
@@ -20,7 +22,8 @@ TPU-first design — everything the chip executes has a static shape:
 - **Bucketed prefill.** Prompts are right-padded to a small set of bucket
   lengths (one compile each) and run through the same layer math as
   ``generate``'s prefill (flash kernel on TPU when shapes allow); the
-  resulting K/V rows are spliced into the slot with a donated
+  resulting K/V rows (row-major, as ``generate`` makes them) are
+  transposed and spliced into the slot with a donated
   ``dynamic_update_slice`` — no host round-trip, no cache copy.
 - **Buffer donation everywhere.** The decode step and the slot-splice
   donate the cache, so HBM holds exactly one grid regardless of step rate.
@@ -85,11 +88,12 @@ def _decode_kernel_wanted() -> bool:
 
 def _cache_shardings(cache):
     """NamedSharding pytree for the grid cache under the ambient mesh, or
-    None off-mesh: slots over the batch axes, the SEQUENCE dim over
-    ``context`` (long-context serving: 1/C of the cache per chip), heads
-    over ``tensor``. Without the explicit constraint GSPMD is free to
-    replicate the scan-carried cache even though the attention shard_map
-    consumes it sharded — correct, but forfeiting the memory split."""
+    None off-mesh: slots (axis 1) over the batch axes, heads (axis 2) over
+    ``tensor``, the SEQUENCE dim (axis 3) over ``context`` (long-context
+    serving: 1/C of the cache per chip). Without the explicit constraint
+    GSPMD is free to replicate the scan-carried cache even though the
+    attention shard_map consumes it sharded — correct, but forfeiting the
+    memory split."""
     from ..parallel.mesh_context import current_mesh
     mesh = current_mesh()
     if mesh is None:
@@ -104,14 +108,14 @@ def _cache_shardings(cache):
     from ..parallel.mesh import fit_batch_axes
 
     def leaf_sharding(x):
-        # values (L, B, S, NKV, Hd); quant scales (L, B, S, NKV)
+        # values (L, B, NKV, S, Hd); quant scales (L, B, NKV, S)
         ba = fit_batch_axes(live, x.shape[1])
-        ctx = "context" if ("context" in live
-                            and x.shape[2] % live["context"] == 0) else None
         tp = "tensor" if ("tensor" in live
-                          and x.shape[3] % live["tensor"] == 0) else None
-        spec = (P(None, ba, ctx, tp, None) if x.ndim == 5
-                else P(None, ba, ctx, tp))
+                          and x.shape[2] % live["tensor"] == 0) else None
+        ctx = "context" if ("context" in live
+                            and x.shape[3] % live["context"] == 0) else None
+        spec = (P(None, ba, tp, ctx, None) if x.ndim == 5
+                else P(None, ba, tp, ctx))
         return NamedSharding(mesh, spec)
 
     return jax.tree_util.tree_map(leaf_sharding, cache)
@@ -124,6 +128,63 @@ def _constrain_cache(cache):
         return cache
     return jax.tree_util.tree_map(jax.lax.with_sharding_constraint,
                                   cache, sh)
+
+
+# Axis order of the slot grid (int8 scales: the same less ``head_dim``). Part
+# of ``aot_cache.AOTKey``: a persisted executable takes the grid it was
+# compiled for.
+GRID_LAYOUT = "layer,slot,kv_head,row,head_dim"
+
+
+def init_grid_cache(cfg, slots: int, max_len: int) -> KVCache:
+    """Zeroed slot grid in the decode kernel's layout, head-major
+    (L, SLOTS, NKV, S_max, Hd): the last two axes are (row, dim), so the
+    kernel's ``BlockSpec`` addresses a layer's tile inside the stacked grid
+    and a decode block never slices, transposes or copies it."""
+    shape = (cfg.n_layers, slots, cfg.n_kv_heads, max_len, cfg.head_dim)
+    return KVCache(k=jnp.zeros(shape, cfg.dtype),
+                   v=jnp.zeros(shape, cfg.dtype))
+
+
+def _write_rows(grid, layer, pos, rows):
+    """Each slot's new row into the stacked grid, in place: grid
+    (L, B, NKV, S, Hd) takes rows (B, NKV, Hd) at ``[layer, b, :, pos[b]]``
+    (int8 scales (L, B, NKV, S) take (B, NKV)). One
+    ``dynamic_update_slice`` per slot (B is static) compiles to an in-place
+    chain; the gather-style ``grid.at[layer, arange(B), :, pos].set`` gets a
+    row-major layout on the TPU and a relayout copy of the whole grid on
+    each side of it. ``dynamic_update_slice`` clamps an out-of-range start
+    (see :func:`_decode_block`)."""
+    tail = (0,) * (grid.ndim - 4)
+    for b in range(rows.shape[0]):
+        grid = lax.dynamic_update_slice(
+            grid, rows[b][None, None, :, None].astype(grid.dtype),
+            (layer, b, 0, pos[b]) + tail)
+    return grid
+
+
+def _einsum_attention(q, leaves, pos, scale):
+    """Masked-einsum decode attention over ONE layer of the grid (the
+    reference math both Pallas kernels are bit-compatible with; what the
+    CPU tests run). ``leaves``: (ck, cv) (B, NKV, S, Hd), or the int8
+    (kq, ks, vq, vs) with scales (B, NKV, S) folded in (logits columns
+    ·ks, probs ·vs; all fp32)."""
+    quant = len(leaves) == 4
+    ck, cv = (leaves[0], leaves[2]) if quant else leaves
+    b, nh, hd = q.shape
+    nkv, s = ck.shape[1], ck.shape[2]
+    qg = q.reshape(b, nkv, nh // nkv, hd)
+    if quant:
+        qg, ck, cv = (a.astype(jnp.float32) for a in (qg, ck, cv))
+    logits = jnp.einsum("bkgh,bksh->bkgs", qg, ck).astype(jnp.float32) * scale
+    if quant:
+        logits = logits * leaves[1][:, :, None, :]
+    mask = jnp.arange(s)[None, :] <= pos[:, None]          # (B, S)
+    logits = jnp.where(mask[:, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    probs = (probs * leaves[3][:, :, None, :] if quant
+             else probs.astype(cv.dtype))
+    return jnp.einsum("bkgs,bksh->bkgh", probs, cv).reshape(b, nh, hd)
 
 
 def _rope_slot(x: jax.Array, freqs: jax.Array) -> jax.Array:
@@ -140,17 +201,24 @@ def _rope_slot(x: jax.Array, freqs: jax.Array) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _decode_layer(cfg, x, lw, ck, cv, pos, freqs, lora=None):
-    """One layer over one new token per slot.
+def _decode_layer(cfg, x, lw, cache, layer, pos, freqs, lora=None):
+    """One layer over one new token per slot, against layer ``layer`` of
+    the stacked grid, which is written and read where it lies.
 
-    x: (B, 1, D); ck/cv: (B, S, NKV, Hd); pos: (B,) absolute position of
-    each slot's new token (also its cache row); freqs: (B, Hd/2) complex.
-    ``lora``: per-slot adapters already gathered to (B, D, R)/(B, R, O)
-    per target (multi-LoRA serving — see ``GenerationEngine`` docs).
+    x: (B, 1, D); cache: the whole grid, ``KVCache`` (L, B, NKV, S, Hd) or
+    the int8 ``QuantKVCache`` (``kv_quant``: the new row is QUANTIZED
+    before it is written and attention folds the row scales in instead of
+    materializing fp rows); layer: traced int32; pos: (B,) absolute
+    position of each slot's new token (also its cache row); freqs:
+    (B, Hd/2) complex. ``lora``: per-slot adapters already gathered to
+    (B, D, R)/(B, R, O) per target (multi-LoRA serving — see
+    ``GenerationEngine`` docs). Returns (x', cache').
 
     The named scopes are metadata on the ops, for a device trace to group
     time by; they change nothing the compiled program does.
     """
+    from .kv_quant import QuantKVCache, quantize_rows
+    quant = isinstance(cache, QuantKVCache)
     b = x.shape[0]
     hd = cfg.head_dim
     nh, nkv = cfg.n_heads, cfg.n_kv_heads
@@ -163,17 +231,24 @@ def _decode_layer(cfg, x, lw, ck, cv, pos, freqs, lora=None):
         q, k = _rope_slot(q, freqs), _rope_slot(k, freqs)
 
     with jax.named_scope("kt.cache_update"):
-        bi = jnp.arange(b)
-        ck = ck.at[bi, pos].set(k.astype(ck.dtype))
-        cv = cv.at[bi, pos].set(v.astype(cv.dtype))
+        rows = (*quantize_rows(k), *quantize_rows(v)) if quant else (k, v)
+        cache = type(cache)(*(_write_rows(g, layer, pos, r)
+                              for g, r in zip(cache, rows)))
 
+    from ..parallel import kernel_shard
+    from ..parallel import ring_attention as ring
     from ..parallel.mesh_context import current_mesh
-    from ..parallel.ring_attention import (sp_decode_attention_sharded,
-                                           sp_decode_supported)
     mesh = current_mesh()
+    scale = hd ** -0.5
+
+    def layer_leaves():
+        # a layer-sized read: the reference paths only, no cell runs them
+        return tuple(lax.dynamic_index_in_dim(g, layer, 0, keepdims=False)
+                     for g in cache)
+
     with jax.named_scope("kt.attention"):
-        if mesh is not None and sp_decode_supported(mesh, b, ck.shape[1],
-                                                    nkv, nh):
+        if mesh is not None and ring.sp_decode_supported(
+                mesh, b, cache[0].shape[3], nkv, nh):
             # long-context serving: the cache's sequence axis is sharded
             # over the context mesh axis; local attention + one
             # online-softmax combine beats the all-gather GSPMD would
@@ -181,95 +256,27 @@ def _decode_layer(cfg, x, lw, ck, cv, pos, freqs, lora=None):
             # on one chip). Trace-time gate like the MoE gather (mesh fixed
             # per engine — captured at construction and re-installed on
             # whichever thread traces); shapes that don't divide the mesh
-            # fall back to the dense path.
-            attn = sp_decode_attention_sharded(
-                q, ck, cv, pos, mesh,
-                scale=hd ** -0.5).reshape(b, 1, nh * hd)
+            # fall back to the dense path. int8 × context sharding compose:
+            # 1/(2C) of the fp cache bytes per chip.
+            sp = (ring.sp_decode_attention_quant_sharded if quant
+                  else ring.sp_decode_attention_sharded)
+            attn = sp(q, *layer_leaves(), pos, mesh, scale=scale)
         elif _decode_kernel_wanted():
-            # fused flash-decode: streams K/V tiles, skips tiles past each
-            # slot's frontier entirely (ops/decode_attention.py); under a
-            # mesh each device runs it over its own slots and heads
-            from ..parallel.kernel_shard import decode_attention_sharded
-            attn = decode_attention_sharded(
-                q, ck, cv, pos, mesh,
-                scale=hd ** -0.5).reshape(b, 1, nh * hd)
+            # fused flash-decode over the stacked grid itself: streams K/V
+            # tiles, skips tiles past each slot's frontier entirely
+            # (ops/decode_attention.py); under a mesh each device runs it
+            # over its own slots and heads
+            kernel = (kernel_shard.decode_attention_quant_sharded if quant
+                      else kernel_shard.decode_attention_sharded)
+            attn = kernel(q, *cache, pos, layer, mesh, scale=scale)
         else:
-            group = nh // nkv
-            qg = q.reshape(b, nkv, group, hd)
-            logits = (jnp.einsum("bkgh,bskh->bkgs", qg,
-                                 ck).astype(jnp.float32) * (hd ** -0.5))
-            s = ck.shape[1]
-            mask = jnp.arange(s)[None, :] <= pos[:, None]      # (B, S)
-            logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
-            attn = jnp.einsum("bkgs,bskh->bkgh", probs,
-                              cv).reshape(b, 1, nh * hd)
+            attn = _einsum_attention(q, layer_leaves(), pos, scale)
+        attn = attn.reshape(b, 1, nh * hd).astype(x.dtype)
     with jax.named_scope("kt.out_proj"):
         x = x + lora_proj(attn, lw["wo"], lora, "wo")
     with jax.named_scope("kt.ffn"):
         h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-        return x + ffn_block(cfg, h, lw), ck, cv
-
-
-def _decode_layer_quant(cfg, x, lw, kq, ks, vq, vs, pos, freqs, lora=None):
-    """One layer over one new token per slot against an int8 cache
-    (``kv_quant``): identical projection/RoPE/FFN math to ``_decode_layer``,
-    but the new row is QUANTIZED before it is written and attention folds
-    the row scales in (logits columns ·ks, probs ·vs) instead of
-    materializing fp rows — the reference math the Pallas quant kernel is
-    bit-compatible with."""
-    from .kv_quant import quantize_rows
-    b = x.shape[0]
-    hd = cfg.head_dim
-    nh, nkv = cfg.n_heads, cfg.n_kv_heads
-    lw = dequant_layer(lw, cfg.dtype)
-    h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-    q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, nh, hd)
-    k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, nkv, hd)
-    v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, nkv, hd)
-    q, k = _rope_slot(q, freqs), _rope_slot(k, freqs)
-
-    bi = jnp.arange(b)
-    k_row, ks_row = quantize_rows(k)
-    v_row, vs_row = quantize_rows(v)
-    kq = kq.at[bi, pos].set(k_row)
-    ks = ks.at[bi, pos].set(ks_row)
-    vq = vq.at[bi, pos].set(v_row)
-    vs = vs.at[bi, pos].set(vs_row)
-
-    from ..parallel.mesh_context import current_mesh
-    from ..parallel.ring_attention import (
-        sp_decode_attention_quant_sharded, sp_decode_supported)
-    mesh = current_mesh()
-    if mesh is not None and sp_decode_supported(mesh, b, kq.shape[1],
-                                                nkv, nh):
-        # int8 cache × context sharding compose: 1/(2C) of the fp cache
-        # bytes per chip, scales folded into the per-shard combine
-        attn = sp_decode_attention_quant_sharded(
-            q, kq, ks, vq, vs, pos, mesh,
-            scale=hd ** -0.5).reshape(b, 1, nh * hd).astype(x.dtype)
-    elif _decode_kernel_wanted():
-        from ..parallel.kernel_shard import decode_attention_quant_sharded
-        attn = decode_attention_quant_sharded(
-            q, kq, ks, vq, vs, pos, mesh,
-            scale=hd ** -0.5).reshape(b, 1, nh * hd).astype(x.dtype)
-    else:
-        group = nh // nkv
-        s = kq.shape[1]
-        qg = q.reshape(b, nkv, group, hd).astype(jnp.float32)
-        logits = jnp.einsum("bkgh,bskh->bkgs", qg,
-                            kq.astype(jnp.float32)) * (hd ** -0.5)
-        logits = logits * ks.transpose(0, 2, 1)[:, :, None, :]
-        mask = jnp.arange(s)[None, :] <= pos[:, None]
-        logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        probs = probs * vs.transpose(0, 2, 1)[:, :, None, :]
-        attn = jnp.einsum("bkgs,bskh->bkgh", probs,
-                          vq.astype(jnp.float32)).reshape(
-                              b, 1, nh * hd).astype(x.dtype)
-    x = x + lora_proj(attn, lw["wo"], lora, "wo")
-    h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-    return x + ffn_block(cfg, h, lw), kq, ks, vq, vs
+        return x + ffn_block(cfg, h, lw), cache
 
 
 def _sample_slots(logits, key, temps, top_k: Optional[int], top_ps=None,
@@ -326,38 +333,24 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     block decode bit-equal to one-step even when sampling).
     Always returns the 4-tuple (cache', next_tok, logprobs, counts') —
     ``counts'`` is None when ``counts`` is."""
-    from .kv_quant import QuantKVCache
-    quant = isinstance(cache, QuantKVCache)
-    s_max = cache.kq.shape[2] if quant else cache.k.shape[2]
+    n_layers, s_max = cache[0].shape[0], cache[0].shape[3]
     x = params["embed"][toks[:, None]].astype(cfg.dtype)   # (B, 1, D)
     freqs = rope_freqs(cfg, s_max)[pos]                     # (B, Hd/2)
 
     from ..models.lora import gather_slot_adapters
 
-    def make_lora(bank_l):
-        return gather_slot_adapters(bank_l, aidx, lora_scale, banks)
+    # the grid rides in the CARRY, the layer index beside the weights:
+    # scanned as ``xs``/``ys``, XLA builds a second grid every step and
+    # slices every layer out of one and writes it back into the other
+    def body(carry, layer):
+        h, grid = carry
+        lw, l, bank_l = layer
+        lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
+        return _decode_layer(cfg, h, lw, grid, l, pos, freqs, lora=lora), None
 
-    if quant:
-        def body(carry, layer):
-            lw, kq, ks, vq, vs, bank_l = layer
-            h, kq, ks, vq, vs = _decode_layer_quant(
-                cfg, carry, lw, kq, ks, vq, vs, pos, freqs,
-                lora=make_lora(bank_l))
-            return h, (kq, ks, vq, vs)
-
-        x, leaves = lax.scan(body, x, (params["layers"], cache.kq, cache.ks,
-                                       cache.vq, cache.vs, banks or {}))
-        new_cache = QuantKVCache(*leaves)
-    else:
-        def body(carry, layer):
-            lw, ck, cv, bank_l = layer
-            h, ck, cv = _decode_layer(cfg, carry, lw, ck, cv, pos, freqs,
-                                      lora=make_lora(bank_l))
-            return h, (ck, cv)
-
-        x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k, cache.v,
-                                         banks or {}))
-        new_cache = KVCache(nk, nv)
+    (x, new_cache), _ = lax.scan(
+        body, (x, cache),
+        (params["layers"], jnp.arange(n_layers), banks or {}))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head_dot(x[:, 0], params, cfg.dtype)
     raw_logits = logits
@@ -391,9 +384,10 @@ def _decode_step(params, cache, pos, toks, rng, temps, cfg,
     token; pos (B,) its absolute position; temps (B,) its sampling
     temperature. ``banks`` (target → (A (L,N,D,R), B (L,N,R,O))) + ``aidx``
     (B,) select each slot's LoRA adapter (index 0 = the zero adapter =
-    base model). ``cache`` is a ``KVCache`` or an int8 ``QuantKVCache``
-    (``kv_quant``) — the pytree structure keys the jit, so each engine
-    compiles exactly one of the two bodies. Returns (cache', next_tok)."""
+    base model). ``cache`` is the head-major grid, a ``KVCache`` or an int8
+    ``QuantKVCache`` (``kv_quant``) — the pytree structure keys the jit, so
+    each engine compiles exactly one of the two bodies. Returns
+    (cache', next_tok)."""
     cache, nxt, lps, counts = _decode_step_impl(
         params, cache, pos, toks, rng, temps, cfg, top_k=top_k, banks=banks,
         aidx=aidx, lora_scale=lora_scale, top_ps=top_ps, counts=counts,
@@ -415,12 +409,21 @@ def _decode_block(params, cache, pos, toks, rng, temps, cfg, n_steps: int,
     overhead once per block instead of once per token — the difference
     between ~dispatch-bound and ~HBM-bound serving decode.
 
+    The grid (L, SLOTS, NKV, S_max, Hd) rides in the carry of this scan and
+    of the layer scan inside it: rows are written in place and the kernel
+    reads the stacked grid directly, so no step slices, transposes or
+    copies it (tests/test_decode_block_compiles.py holds the compiled
+    program to that).
+
     A slot that retires mid-block (eos/stop/budget) keeps computing garbage
     for the rest of the block; the host discards those tokens at emit time.
-    Its overshoot cache writes at positions ≥ S_max are XLA scatter-drops
-    (out-of-bounds scatter indices are dropped, not clipped), and rows past
-    a retired frontier are never attended before being rewritten — so the
-    garbage is unobservable. Returns
+    Its overshoot cache writes at positions ≥ S_max are CLAMPED
+    (``dynamic_update_slice`` clips an out-of-range start): they rewrite
+    the slot's own row S_max−1, after the slot's last kept token was
+    computed (a request's budget ends at or before that row). Rows past a
+    retired frontier are never attended before being rewritten, and the
+    next occupant writes every row before it attends it — so the garbage
+    is unobservable. Returns
     (cache', final_pos, final_tok, toks (K, B), logprobs (K, B), counts')."""
 
     def step_fn(carry, k):
@@ -550,24 +553,23 @@ def _set_counts_row(counts, slot, row):
 @partial(jax.jit, donate_argnums=(0,))
 def _splice_slot(cache, slot, k_new, v_new):
     """Write a prefill's K/V rows into one slot of the grid cache, donated
-    (no second grid-sized buffer ever exists). k/v_new: (L, 1, T_b, ...) in
-    the model dtype; for an int8 ``QuantKVCache`` grid the rows quantize
-    HERE — prefill itself always runs full-precision math."""
+    (no second grid-sized buffer ever exists). The one crossing between the
+    two layouts: k/v_new arrive row-major (L, 1, T_b, NKV, Hd) in the model
+    dtype, as ``_prefill`` / the prefix store hold them, and are transposed
+    to the grid's head-major (L, 1, NKV, T_b, Hd) as they are written (the
+    ≤ bucket-width new rows, not the grid). For an int8 ``QuantKVCache``
+    grid the rows quantize HERE — prefill itself always runs full-precision
+    math."""
     from .kv_quant import QuantKVCache, quantize_rows
     if isinstance(cache, QuantKVCache):
-        kq, ks = quantize_rows(k_new)
-        vq, vs = quantize_rows(v_new)
-        start = (0, slot, 0, 0, 0)
-        sstart = (0, slot, 0, 0)
-        return _constrain_cache(QuantKVCache(
-            kq=lax.dynamic_update_slice(cache.kq, kq, start),
-            ks=lax.dynamic_update_slice(cache.ks, ks, sstart),
-            vq=lax.dynamic_update_slice(cache.vq, vq, start),
-            vs=lax.dynamic_update_slice(cache.vs, vs, sstart)))
-    start = (0, slot, 0, 0, 0)
-    return _constrain_cache(KVCache(
-        k=lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype), start),
-        v=lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype), start)))
+        rows = (*quantize_rows(k_new), *quantize_rows(v_new))
+    else:
+        rows = (k_new, v_new)
+    return _constrain_cache(type(cache)(*(
+        lax.dynamic_update_slice(
+            g, jnp.swapaxes(r, 2, 3).astype(g.dtype),
+            (0, slot) + (0,) * (g.ndim - 2))
+        for g, r in zip(cache, rows))))
 
 
 # ---------------------------------------------------------------------------
@@ -811,7 +813,7 @@ class GenerationEngine:
             from .kv_quant import init_quant_cache
             self._cache = init_quant_cache(cfg, self.slots, self.max_len)
         else:
-            self._cache = init_cache(cfg, self.slots, self.max_len)
+            self._cache = init_grid_cache(cfg, self.slots, self.max_len)
         shardings = _cache_shardings(self._cache)
         if shardings is not None:
             # grid lives sharded from step 0 (slots over data axes, the

@@ -37,8 +37,9 @@ import jax.numpy as jnp
 
 
 class QuantKVCache(NamedTuple):
-    """Slot-grid cache in int8: values (L, B, S, NKV, Hd) int8, scales
-    (L, B, S, NKV) fp32 — one scale per written row per head."""
+    """Slot-grid cache in int8, head-major like the engine's fp grid (the
+    decode kernel's layout, read in place): values (L, B, NKV, S, Hd) int8,
+    scales (L, B, NKV, S) fp32 — one scale per written row per head."""
     kq: jax.Array
     ks: jax.Array
     vq: jax.Array
@@ -46,7 +47,9 @@ class QuantKVCache(NamedTuple):
 
 
 def init_quant_cache(cfg, batch: int, max_len: int) -> QuantKVCache:
-    vshape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    """Zeroed int8 slot grid: values (L, B, NKV, S_max, Hd), scales
+    (L, B, NKV, S_max)."""
+    vshape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
     sshape = vshape[:-1]
     return QuantKVCache(kq=jnp.zeros(vshape, jnp.int8),
                         ks=jnp.zeros(sshape, jnp.float32),

@@ -40,11 +40,11 @@ from jax import lax
 
 from .. import telemetry
 
-from ..models.generate import KVCache, ffn_block, init_cache, rope_freqs
+from ..models.generate import KVCache, ffn_block, rope_freqs
 from ..models.llama import rmsnorm
 from ..models.quant import dequant_layer, lm_head_dot, wdot
 from .engine import (GenerationEngine, _decode_block, _prefill,
-                     _prefill_suffix, _splice_slot)
+                     _prefill_suffix, _splice_slot, init_grid_cache)
 from .speculative import SpecStats
 
 NEG_INF = -1e30
@@ -89,16 +89,17 @@ def _grid_ingest(params, cache, blocks, start, true_len, cfg,
     bit-exactness oracles in tests/test_spec_engine.py, which fail on ANY
     drift in norm/RoPE/cache/MoE behavior.
 
-    ``cache`` may be a fp ``KVCache`` or an int8 ``QuantKVCache``
-    (``serve.kv_quant``) — the pytree structure keys the jit. The quant
+    ``cache`` is the engine's head-major grid (L, SLOTS, NKV, S_max, Hd),
+    a fp ``KVCache`` or an int8 ``QuantKVCache`` (``serve.kv_quant``) —
+    the pytree structure keys the jit. The quant
     branch quantizes new rows before writing and folds the row scales
     into the attention f32 einsums (logits columns ·ks, probs ·vs) — the
-    same reference math as ``engine._decode_layer_quant``, so the verify
+    same reference math as ``engine._einsum_attention``, so the verify
     window attends bit-compatibly with the T=1 decode it must match."""
     from .kv_quant import QuantKVCache, quantize_rows
     quant = isinstance(cache, QuantKVCache)
     b, w = blocks.shape
-    s_max = cache.kq.shape[2] if quant else cache.k.shape[2]
+    s_max = cache[0].shape[3]
     if s_eff is None:
         s_eff = s_max
     x = params["embed"][blocks].astype(cfg.dtype)
@@ -143,23 +144,25 @@ def _grid_ingest(params, cache, blocks, start, true_len, cfg,
             q, k, v = proj_qkv(lw, h, lora)
             k_row, ks_row = quantize_rows(k)
             v_row, vs_row = quantize_rows(v)
-            kq = kq.at[bi, posm].set(k_row)
-            ks = ks.at[bi, posm].set(ks_row)
-            vq = vq.at[bi, posm].set(v_row)
-            vs = vs.at[bi, posm].set(vs_row)
-            kq_a = lax.slice_in_dim(kq, 0, s_eff, axis=1)
-            ks_a = lax.slice_in_dim(ks, 0, s_eff, axis=1)
-            vq_a = lax.slice_in_dim(vq, 0, s_eff, axis=1)
-            vs_a = lax.slice_in_dim(vs, 0, s_eff, axis=1)
+            # layer slices are head-major (B, NKV, S, ...): the advanced
+            # indices around the head slice put (B, W) first, as the rows are
+            kq = kq.at[bi, :, posm].set(k_row)
+            ks = ks.at[bi, :, posm].set(ks_row)
+            vq = vq.at[bi, :, posm].set(v_row)
+            vs = vs.at[bi, :, posm].set(vs_row)
+            kq_a = lax.slice_in_dim(kq, 0, s_eff, axis=2)
+            ks_a = lax.slice_in_dim(ks, 0, s_eff, axis=2)
+            vq_a = lax.slice_in_dim(vq, 0, s_eff, axis=2)
+            vs_a = lax.slice_in_dim(vs, 0, s_eff, axis=2)
             qg = q.reshape(b, w, nkv, group, hd).astype(jnp.float32)
-            logits = jnp.einsum("bwkgh,bskh->bkgws", qg,
+            logits = jnp.einsum("bwkgh,bksh->bkgws", qg,
                                 kq_a.astype(jnp.float32)) * (hd ** -0.5)
-            # fold the K row scales over the S axis: ks_a (B, S, NKV)
-            logits = logits * ks_a.transpose(0, 2, 1)[:, :, None, None, :]
+            # fold the K row scales over the S axis: ks_a (B, NKV, S)
+            logits = logits * ks_a[:, :, None, None, :]
             logits = jnp.where(win_mask()[:, None, None], logits, NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1)
-            probs = probs * vs_a.transpose(0, 2, 1)[:, :, None, None, :]
-            attn = jnp.einsum("bkgws,bskh->bwkgh", probs,
+            probs = probs * vs_a[:, :, None, None, :]
+            attn = jnp.einsum("bkgws,bksh->bwkgh", probs,
                               vq_a.astype(jnp.float32)).reshape(
                                   b, w, nh * hd).astype(h.dtype)
             return finish(lw, h, attn, lora), (kq, ks, vq, vs)
@@ -175,16 +178,16 @@ def _grid_ingest(params, cache, blocks, start, true_len, cfg,
             lora = make_lora(bank_l)
             h = carry
             q, k, v = proj_qkv(lw, h, lora)
-            ck = ck.at[bi, posm].set(k.astype(ck.dtype))
-            cv = cv.at[bi, posm].set(v.astype(cv.dtype))
-            ck_a = lax.slice_in_dim(ck, 0, s_eff, axis=1)
-            cv_a = lax.slice_in_dim(cv, 0, s_eff, axis=1)
+            ck = ck.at[bi, :, posm].set(k.astype(ck.dtype))
+            cv = cv.at[bi, :, posm].set(v.astype(cv.dtype))
+            ck_a = lax.slice_in_dim(ck, 0, s_eff, axis=2)
+            cv_a = lax.slice_in_dim(cv, 0, s_eff, axis=2)
             qg = q.reshape(b, w, nkv, group, hd)
-            logits = jnp.einsum("bwkgh,bskh->bkgws", qg,
+            logits = jnp.einsum("bwkgh,bksh->bkgws", qg,
                                 ck_a).astype(jnp.float32) * (hd ** -0.5)
             logits = jnp.where(win_mask()[:, None, None], logits, NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
-            attn = jnp.einsum("bkgws,bskh->bwkgh", probs,
+            attn = jnp.einsum("bkgws,bksh->bwkgh", probs,
                               cv_a).reshape(b, w, nh * hd)
             return finish(lw, h, attn, lora), (ck, cv)
 
@@ -263,7 +266,8 @@ class SpeculativeEngine(GenerationEngine):
         self._adapt_every = max(1, int(spec_adapt_every))
         self._rounds_since_adapt = 0
         self._accept_ewma: Optional[float] = None
-        self._draft_cache = init_cache(draft_cfg, self.slots, self.max_len)
+        self._draft_cache = init_grid_cache(draft_cfg, self.slots,
+                                            self.max_len)
         # per-slot ledgers: rows both caches validly cover, and the tokens
         # emitted but not yet ingested (1..k+1 long while active).
         # NB: self._pending is the BASE class's request queue — the token

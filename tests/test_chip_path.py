@@ -346,12 +346,13 @@ def test_kernels_run_sharded_over_batch_and_heads(cpu_mesh_devices):
     np.testing.assert_allclose(np.asarray(g), np.asarray(gw), atol=1e-4)
 
     qd = jax.random.normal(keys[0], (4, 4, 16), jnp.float32)
-    ck = jax.random.normal(keys[1], (4, 256, 2, 16), jnp.float32)
-    cv = jax.random.normal(keys[2], (4, 256, 2, 16), jnp.float32)
+    # the engine's stacked head-major grid (L, B, NKV, S, Hd), layer 1
+    ck = jax.random.normal(keys[1], (2, 4, 2, 256, 16), jnp.float32)
+    cv = jax.random.normal(keys[2], (2, 4, 2, 256, 16), jnp.float32)
     pos = jnp.asarray([0, 7, 130, 255], jnp.int32)
-    want = decode_attention(qd, ck, cv, pos)
+    want = decode_attention(qd, ck, cv, pos, 1)
     got = jax.jit(lambda *a: ks.decode_attention_sharded(*a, mesh))(
-        qd, ck, cv, pos)
+        qd, ck, cv, pos, jnp.int32(1))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 

@@ -20,6 +20,7 @@ run in milliseconds; the engine-equivalence and fork drills carry
 """
 
 import asyncio
+import dataclasses
 import glob
 import json
 import os
@@ -144,6 +145,33 @@ class TestAOTCache:
         # engines differing only in top_k must not share a cache line
         assert _key().digest() != _key(top_k=40).digest()
         assert _key(top_k=5).digest() != _key(top_k=40).digest()
+
+    def test_key_carries_the_grid_layout(self, tmp_path):
+        """An executable persisted for another slot-grid layout (before the
+        grid was head-major the key had no such field) is never found: the
+        lookup is a typed miss, not a load that fails on shapes at call
+        time."""
+        from kubetorch_tpu.serve.engine import GRID_LAYOUT
+
+        class _Eng:
+            cfg = {"kind": "probe"}
+            _mesh = None
+            _buckets = [8]
+            slots, max_len = 2, 64
+            quantize_kv, decode_block = False, 1
+            top_k = None
+        key = AOTKey.for_engine(_Eng())
+        assert key.grid_layout == GRID_LAYOUT == \
+            "layer,slot,kv_head,row,head_dim"
+        old = dataclasses.replace(key, grid_layout="")
+        assert key.digest() != old.digest()
+        assert "grid_layout" in key.describe()
+        cache = AOTCompileCache(tmp_path)
+        cache.put(old, "decode_1", _build())
+        with pytest.raises(AOTCacheMissError) as e:
+            cache.load(key, "decode_1")
+        assert e.value.reason == "incompatible"    # a key mismatch, typed
+        cache.load(old, "decode_1")            # the old line itself is intact
 
     def test_engine_key_carries_top_k(self):
         class _Eng:

@@ -2,10 +2,13 @@
 the same code path the TPU runs compiled (mirrors test_flash_attention.py).
 
 Contracts: numerically equal to the masked-einsum reference for any
-per-slot position vector, and the ENGINE produces identical tokens with
+per-slot position vector, reading its layer IN PLACE out of a stacked
+head-major grid (L, B, NKV, S, Hd) by a non-zero layer index (the other
+layers hold other numbers), and the ENGINE produces identical tokens with
 the kernel forced on (KT_DECODE_KERNEL=1 in a subprocess, since the flag
 freezes at import)."""
 
+import functools
 import os
 import subprocess
 import sys
@@ -34,6 +37,20 @@ def _einsum_ref(q, ck, cv, pos, scale):
     return jnp.einsum("bkgs,bskh->bkgh", probs, cv).reshape(b, nh, hd)
 
 
+def _stacked(rows, layer, n_layers=3):
+    """A row-major layer (B, S, NKV, Hd) as layer ``layer`` of a head-major
+    grid (L, B, NKV, S, Hd) whose other layers hold other numbers: reading
+    the wrong layer cannot pass."""
+    head_major = rows.transpose(0, 2, 1, 3)
+    return jnp.stack([head_major if l == layer else head_major[::-1] + 1 + l
+                      for l in range(n_layers)])
+
+
+def _decode(q, ck, cv, pos, layer, **kw):
+    return decode_attention(q, _stacked(ck, layer), _stacked(cv, layer), pos,
+                            layer, **kw)
+
+
 class TestKernel:
     @pytest.mark.parametrize("shape", [
         (4, 256, 8, 4, 128),     # multi-tile, GQA group 2
@@ -48,7 +65,7 @@ class TestKernel:
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
         cv = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
         pos = jnp.asarray(rng.integers(0, s, b), jnp.int32)
-        got = decode_attention(q, ck, cv, pos, block_k=128)
+        got = _decode(q, ck, cv, pos, 1 + b % 2, block_k=128)
         want = _einsum_ref(q, ck, cv, pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
@@ -61,7 +78,7 @@ class TestKernel:
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
         cv = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
         pos = jnp.asarray([0, s - 1], jnp.int32)
-        got = decode_attention(q, ck, cv, pos, block_k=64)
+        got = _decode(q, ck, cv, pos, jnp.int32(2), block_k=64)
         want = _einsum_ref(q, ck, cv, pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
@@ -72,7 +89,9 @@ class TestKernel:
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.bfloat16)
         cv = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.bfloat16)
         pos = jnp.asarray([100, 255], jnp.int32)
-        got = decode_attention(q, ck, cv, pos, block_k=128)
+        got = jax.jit(functools.partial(decode_attention, block_k=128))(
+            q, _stacked(ck, 1), _stacked(cv, 1), pos,
+            jnp.int32(1))                             # a traced layer index
         want = _einsum_ref(q.astype(jnp.float32), ck.astype(jnp.float32),
                            cv.astype(jnp.float32), pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.02

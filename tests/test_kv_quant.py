@@ -7,6 +7,8 @@ fold-in einsum reference (same fp32 math, scales on logits columns / probs);
 protocol with logits close to the fp engine's — and half the cache bytes.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -46,9 +48,13 @@ class TestRowQuant:
 
     def test_cache_is_half_size(self, dense):
         _, cfg = dense
-        from kubetorch_tpu.models.generate import init_cache
-        fp = init_cache(cfg, 4, 256, dtype=jnp.bfloat16)
+        from kubetorch_tpu.serve.engine import init_grid_cache
+        fp = init_grid_cache(dataclasses.replace(cfg, dtype=jnp.bfloat16),
+                             4, 256)
         qc = init_quant_cache(cfg, 4, 256)
+        assert qc.kq.shape == fp.k.shape == (
+            cfg.n_layers, 4, cfg.n_kv_heads, 256, cfg.head_dim)
+        assert qc.ks.shape == fp.k.shape[:-1]
         fp_bytes = sum(a.size * a.dtype.itemsize for a in fp)
         q_bytes = sum(a.size * a.dtype.itemsize for a in qc)
         # per bf16 row of Hd values (2·Hd bytes): Hd int8 + 4 scale bytes
@@ -59,7 +65,8 @@ class TestRowQuant:
 
 
 def _quant_einsum_reference(q, kq, ks, vq, vs, pos, scale):
-    """The fold-in math of serve.engine._decode_layer_quant, standalone."""
+    """The fold-in math of serve.engine._einsum_attention, standalone and
+    over row-major (B, S, NKV, ...) rows."""
     b, nh, hd = q.shape
     s, nkv = kq.shape[1], kq.shape[2]
     group = nh // nkv
@@ -73,6 +80,13 @@ def _quant_einsum_reference(q, kq, ks, vq, vs, pos, scale):
     probs = probs * vs.transpose(0, 2, 1)[:, :, None, :]
     return jnp.einsum("bkgs,bskh->bkgh", probs,
                       vq.astype(jnp.float32)).reshape(b, nh, hd)
+
+
+def _grid(rows, layer=1):
+    """Row-major rows (B, S, NKV, ...) as layer ``layer`` of a two-layer
+    head-major grid (L, B, NKV, S, ...), the engine's layout."""
+    return jnp.stack([rows if l == layer else jnp.zeros_like(rows)
+                      for l in range(2)]).swapaxes(2, 3)
 
 
 class TestQuantKernel:
@@ -92,9 +106,9 @@ class TestQuantKernel:
         kq, ks = quantize_rows(kf)
         vq, vs = quantize_rows(vf)
         pos = jnp.array([s - 1, 5, s // 2][:b], jnp.int32)
-        got = decode_attention_quant(q, kq, ks, vq, vs, pos,
-                                     scale=hd ** -0.5, block_k=bk,
-                                     interpret=True)
+        got = decode_attention_quant(q, _grid(kq), _grid(ks), _grid(vq),
+                                     _grid(vs), pos, 1, scale=hd ** -0.5,
+                                     block_k=bk, interpret=True)
         want = _quant_einsum_reference(q, kq, ks, vq, vs, pos, hd ** -0.5)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -111,7 +125,8 @@ class TestQuantKernel:
         q = jax.random.normal(jax.random.PRNGKey(3), (b, nh, hd),
                               jnp.float32)
         pos = jnp.array([s - 1, 100], jnp.int32)
-        fp = decode_attention(q, kf, vf, pos, interpret=True)
+        fp = decode_attention(q, _grid(kf), _grid(vf), pos, 1,
+                              interpret=True)
         kq, ks = quantize_rows(kf)
         vq, vs = quantize_rows(vf)
         want = _quant_einsum_reference(q, kq, ks, vq, vs, pos, hd ** -0.5)

@@ -646,8 +646,10 @@ class TestContextShardedServing:
         pos = jnp.array([5, 15, 63, 0], jnp.int32)
         mesh = build_mesh({"data": 2, "context": 4},
                           devices=cpu_mesh_devices[:8])
+        # the op takes one layer of the head-major grid: (B, NKV, S, Hd)
         got = jax.jit(lambda *a: sp_decode_attention_sharded(
-            *a, mesh, scale=hd ** -0.5))(q, ck, cv, pos)
+            *a, mesh, scale=hd ** -0.5))(
+                q, ck.swapaxes(1, 2), cv.swapaxes(1, 2), pos)
 
         group = nh // nkv
         qg = q.reshape(b, nkv, group, hd)

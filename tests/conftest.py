@@ -137,3 +137,26 @@ def tmp_project(tmp_path):
     """A throwaway project dir with a marker so locate_working_dir resolves."""
     (tmp_path / ".git").mkdir()
     return tmp_path
+
+
+@pytest.fixture()
+def hold_heads(monkeypatch):
+    """Steer the decode kernel's wrapper to ``per_step`` ("one", "divisor":
+    the largest proper one, "all") KV heads a grid step the only way there
+    is: through the VMEM budget it divides by. Returns the plan the wrapper
+    will then make, after asserting it is the one asked for."""
+    from kubetorch_tpu.ops import decode_attention as kernel_mod
+
+    def hold(per_step, b, nkv, s, hd, itemsize, block_k=512):
+        want = {"one": 1, "all": nkv}.get(per_step) or max(
+            [h for h in range(1, nkv) if nkv % h == 0], default=1)
+        bk = kernel_mod.decode_plan(b, nkv, s, hd, itemsize,
+                                    block_k=block_k).block_k
+        monkeypatch.setattr(kernel_mod, "KV_VMEM_BUDGET",
+                            4 * want * bk * hd * itemsize)
+        plan = kernel_mod.decode_plan(b, nkv, s, hd, itemsize,
+                                      block_k=block_k)
+        assert plan.heads == want, (plan, want)
+        assert plan.grid == (b * nkv // want, s // bk), plan
+        return plan
+    return hold

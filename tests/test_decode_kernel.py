@@ -19,9 +19,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from kubetorch_tpu.ops.decode_attention import decode_attention
+from kubetorch_tpu.ops import decode_attention as kernel_mod
+from kubetorch_tpu.ops.decode_attention import decode_attention, decode_plan
 
 pytestmark = pytest.mark.level("unit")
+
+# heads per grid step (conftest's ``hold_heads``): one, the largest proper
+# divisor of the KV heads there are, all of them
+PER_STEP = ("one", "divisor", "all")
 
 
 def _einsum_ref(q, ck, cv, pos, scale):
@@ -46,9 +51,9 @@ def _stacked(rows, layer, n_layers=3):
                       for l in range(n_layers)])
 
 
-def _decode(q, ck, cv, pos, layer, **kw):
-    return decode_attention(q, _stacked(ck, layer), _stacked(cv, layer), pos,
-                            layer, **kw)
+def _decode(q, ck, cv, pos, layer, n_layers=3, **kw):
+    return decode_attention(q, _stacked(ck, layer, n_layers),
+                            _stacked(cv, layer, n_layers), pos, layer, **kw)
 
 
 class TestKernel:
@@ -58,8 +63,10 @@ class TestKernel:
         (3, 128, 6, 2, 128),     # odd batch, group 3 (padded rows)
         (1, 64, 8, 8, 64),       # group 1 (pure MHA)
     ])
-    def test_matches_einsum(self, shape):
+    @pytest.mark.parametrize("per_step", PER_STEP)
+    def test_matches_einsum(self, shape, per_step, hold_heads):
         b, s, nh, nkv, hd = shape
+        hold_heads(per_step, b, nkv, s, hd, 4, 128)
         rng = np.random.default_rng(hash(shape) % 2**31)
         q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.float32)
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
@@ -69,10 +76,12 @@ class TestKernel:
         want = _einsum_ref(q, ck, cv, pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
-    def test_edge_positions(self):
+    @pytest.mark.parametrize("per_step", PER_STEP)
+    def test_edge_positions(self, per_step, hold_heads):
         """pos at row 0 (only the fresh token visible) and at the last row
         (whole cache visible)."""
-        b, s, nh, nkv, hd = 2, 128, 4, 2, 64
+        b, s, nh, nkv, hd = 2, 128, 8, 4, 64
+        hold_heads(per_step, b, nkv, s, hd, 4, 64)
         rng = np.random.default_rng(7)
         q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.float32)
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.float32)
@@ -82,8 +91,10 @@ class TestKernel:
         want = _einsum_ref(q, ck, cv, pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
-    def test_bf16_inputs(self):
+    @pytest.mark.parametrize("per_step", PER_STEP)
+    def test_bf16_inputs(self, per_step, hold_heads):
         b, s, nh, nkv, hd = 2, 256, 8, 4, 128
+        hold_heads(per_step, b, nkv, s, hd, 2, 128)
         rng = np.random.default_rng(3)
         q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.bfloat16)
         ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.bfloat16)
@@ -95,6 +106,64 @@ class TestKernel:
         want = _einsum_ref(q.astype(jnp.float32), ck.astype(jnp.float32),
                            cv.astype(jnp.float32), pos, hd ** -0.5)
         assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.02
+
+    def test_cells_shape_mixed_fill(self):
+        """The benchmark cells' call: 16 slots × 2,048 rows, 32 heads over 8
+        KV heads of 128, bf16, default tile; one slot at row 0, one at the
+        last row, the rest between, tiles' edges among them."""
+        b, s, nh, nkv, hd = 16, 2048, 32, 8, 128
+        assert decode_plan(b, nkv, s, hd, 2).grid == (16, 4)
+        rng = np.random.default_rng(30)
+        q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.bfloat16)
+        ck = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.bfloat16)
+        cv = jnp.asarray(rng.standard_normal((b, s, nkv, hd)), jnp.bfloat16)
+        pos = jnp.asarray([0, s - 1, 511, 512, 1023, 1024, 1535, 1536,
+                           *rng.integers(1, s - 1, b - 8)], jnp.int32)
+        got = _decode(q, ck, cv, pos, 1, n_layers=2)
+        want = _einsum_ref(q.astype(jnp.float32), ck.astype(jnp.float32),
+                           cv.astype(jnp.float32), pos, hd ** -0.5)
+        assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 0.02
+
+
+class TestPlan:
+    """``decode_plan``: the grid and tile the wrapper launches, from shapes
+    alone (no chip, nothing traced)."""
+
+    def test_cells_shape(self):
+        plan = decode_plan(16, 8, 2048, 128, 2)
+        assert plan == (8, 512, (16, 4), 2 * 2 ** 20)
+        # the int8 grid's tiles are half as large: the same grid
+        assert decode_plan(16, 8, 2048, 128, 1)[:3] == (8, 512, (16, 4))
+
+    @pytest.mark.parametrize("nkv,itemsize,heads", [
+        (8, 4, 4),        # float32 tiles are twice as large: half the heads
+        (32, 2, 8),       # MHA at 32 heads: a quarter of them
+        (12, 2, 6),       # the largest divisor that fits, not a power of two
+        (7, 4, 1),        # a prime the budget cannot hold whole
+        (7, 1, 7),        # the same heads as int8 fit
+    ])
+    def test_falls_to_a_divisor(self, nkv, itemsize, heads):
+        plan = decode_plan(16, nkv, 2048, 128, itemsize)
+        assert plan.heads == heads and nkv % plan.heads == 0
+        assert plan.grid == (16 * nkv // heads, 4)
+        assert plan.tile_pair_bytes == 2 * heads * 512 * 128 * itemsize
+        assert 2 * plan.tile_pair_bytes <= kernel_mod.KV_VMEM_BUDGET
+
+    @pytest.mark.parametrize("nkv", [1, 2, 4, 8])
+    def test_local_heads_under_a_mesh(self, nkv):
+        """Under ``shard_map`` the wrapper sees the device's own heads: all
+        of them go into one grid step at the cells' tile."""
+        assert decode_plan(16, nkv, 2048, 128, 2).grid == (16, 4)
+        assert decode_plan(16, nkv, 2048, 128, 2).heads == nkv
+
+    def test_one_head_is_taken_whatever_it_weighs(self):
+        plan = decode_plan(2, 3, 8192, 256, 4, block_k=4096)
+        assert kernel_mod.KV_VMEM_BUDGET < 2 * plan.tile_pair_bytes
+        assert plan.heads == 1 and plan.grid == (6, 2)
+
+    def test_tile_divides_the_cache(self):
+        assert decode_plan(1, 2, 96, 64, 4, block_k=64).block_k == 32
+        assert decode_plan(1, 2, 64, 64, 4).block_k == 64
 
 
 @pytest.mark.slow

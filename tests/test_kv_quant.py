@@ -90,13 +90,17 @@ def _grid(rows, layer=1):
 
 
 class TestQuantKernel:
+    @pytest.mark.parametrize("per_step", ["one", "divisor", "all"])
     @pytest.mark.parametrize("shape", [
         (2, 8, 2, 64, 256, 512),   # b, nh, nkv, hd, s, block_k
         (3, 4, 4, 32, 1024, 256),
+        (16, 32, 8, 128, 2048, 512),   # the benchmark cells' call
     ])
-    def test_kernel_matches_einsum_reference(self, shape):
+    def test_kernel_matches_einsum_reference(self, shape, per_step,
+                                             hold_heads):
         from kubetorch_tpu.ops.decode_attention import decode_attention_quant
         b, nh, nkv, hd, s, bk = shape
+        hold_heads(per_step, b, nkv, s, hd, 1, bk)
         rng = jax.random.PRNGKey(1)
         kf = jax.random.normal(rng, (b, s, nkv, hd), jnp.float32)
         vf = jax.random.normal(jax.random.PRNGKey(2), (b, s, nkv, hd),
@@ -105,7 +109,9 @@ class TestQuantKernel:
                               jnp.float32)
         kq, ks = quantize_rows(kf)
         vq, vs = quantize_rows(vf)
-        pos = jnp.array([s - 1, 5, s // 2][:b], jnp.int32)
+        # a slot at the last row, one near row 0, the rest between
+        pos = jnp.array(([s - 1, 5, s // 2] + [0] + [
+            (37 * i * i) % s for i in range(4, b)])[:b], jnp.int32)
         got = decode_attention_quant(q, _grid(kq), _grid(ks), _grid(vq),
                                      _grid(vs), pos, 1, scale=hd ** -0.5,
                                      block_k=bk, interpret=True)

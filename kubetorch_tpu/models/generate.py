@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .llama import LlamaConfig, apply_rope, rmsnorm, rope_freqs
-from .lora import lora_proj
+from .block import decoder_block, dense_ffn, rmsnorm
+from .llama import LlamaConfig, rope_freqs
 from .moe import MoeConfig, moe_ffn, moe_ffn_decode
 
 NEG_INF = -1e30
@@ -34,7 +34,7 @@ NEG_INF = -1e30
 class KVCache(NamedTuple):
     """K and V of every layer, in one of two layouts. Row-major
     (L, B, S_max, NKV, Hd): ``generate``'s own cache and a prompt's rows out
-    of a prefill (:func:`init_cache`, ``_layer_step``). Head-major
+    of a prefill (:func:`init_cache`, :func:`cache_attend`). Head-major
     (L, SLOTS, NKV, S_max, Hd): the serving engine's slot grid
     (``serve.engine.init_grid_cache``), which is the decode kernel's layout;
     ``serve.engine._splice_slot`` is the one crossing."""
@@ -129,82 +129,85 @@ def _sp_prefill_impl(cfg, b: int, t: int) -> Optional[str]:
     return impl
 
 
+def cache_attend(cfg, layer_cache_k, layer_cache_v, q_pos,
+                 flash_prefill: bool = False, causal_prefill: bool = False):
+    """The block's attention operation over a row-major layer cache
+    (B, S, NKV, Hd): write the T new rows at ``q_pos[0]``, then attend.
+    A from-zero prefill (``causal_prefill``) is causal self-attention over
+    its own T tokens and takes the sequence-sharded or the flash kernel where
+    :func:`_sp_prefill_impl` / the caller's ``flash_prefill`` say so;
+    everything else attends the cache under the absolute-position mask.
+    Returns ``attend(q, k, v) -> (attn, (cache_k, cache_v))``."""
+    scale = cfg.head_dim ** -0.5
+
+    def attend(q, k, v):
+        b, t = q.shape[:2]
+        with jax.named_scope("kt.cache_update"):
+            ck = lax.dynamic_update_slice_in_dim(
+                layer_cache_k, k.astype(layer_cache_k.dtype), q_pos[0],
+                axis=1)
+            cv = lax.dynamic_update_slice_in_dim(
+                layer_cache_v, v.astype(layer_cache_v.dtype), q_pos[0],
+                axis=1)
+
+        sp_impl = _sp_prefill_impl(cfg, b, t) if causal_prefill else None
+        with jax.named_scope("kt.attention"):
+            if sp_impl is not None:
+                # long-prompt prefill on a context mesh: sequence-sharded
+                # attention — no chip holds the full (T, T) attention problem
+                from ..parallel.mesh_context import current_mesh
+                if sp_impl == "ulysses":
+                    from ..parallel.ulysses import ulysses_attention_sharded
+                    attn = ulysses_attention_sharded(
+                        q, k, v, current_mesh(), causal=True, scale=scale,
+                        batch_axes=())
+                else:
+                    from ..parallel.ring_attention import \
+                        ring_attention_sharded
+                    attn = ring_attention_sharded(
+                        q, k, v, current_mesh(), causal=True, scale=scale,
+                        batch_axes=())
+            elif flash_prefill:
+                from ..parallel.kernel_shard import flash_attention_sharded
+                from ..parallel.mesh_context import current_mesh
+                attn = flash_attention_sharded(q, k, v, current_mesh(),
+                                               causal=True, scale=scale)
+            else:
+                attn = _cached_attention(q, ck, cv, q_pos, scale)
+        return attn, (ck, cv)
+
+    return attend
+
+
 def _layer_step(cfg, x, lw, layer_cache_k, layer_cache_v, q_pos, freqs_full,
                 flash_prefill: bool = False, token_mask=None,
                 keep_capacity=None, lora=None, moe_no_drop: bool = False,
                 causal_prefill: bool = False):
-    """One transformer layer over T new tokens, updating this layer's cache.
-    ``lw`` may carry int8-quantized leaves (``models.quant``) — dequantized
-    here, inside the scan body, so only the current layer materializes in
-    the compute dtype. ``lora``: None, or (adapters_by_target, scale) with
-    this LAYER's factors per target (``models.lora.lora_proj``) — the
-    unmerged activation-path adapters multi-LoRA serving runs; applied to
-    the same target set as the engine's ``_decode_layer`` (wq/wk/wv/wo) so
-    prefill and decode adapter semantics can never diverge."""
-    from .quant import dequant_layer
-    lw = dequant_layer(lw, cfg.dtype)
-    b, t, d = x.shape
-    # the named scopes are metadata on the ops, for a device trace to group
-    # time by (the engine's _decode_layer uses the same names)
-    with jax.named_scope("kt.qkv_rope"):
-        h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-        q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, t, cfg.n_heads,
-                                                       cfg.head_dim)
-        k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, t, cfg.n_kv_heads,
-                                                       cfg.head_dim)
-        v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, t, cfg.n_kv_heads,
-                                                       cfg.head_dim)
-        freqs = freqs_full[q_pos]                            # (T, Hd/2)
-        q, k = apply_rope(q, freqs), apply_rope(k, freqs)
-
-    with jax.named_scope("kt.cache_update"):
-        layer_cache_k = lax.dynamic_update_slice_in_dim(
-            layer_cache_k, k.astype(layer_cache_k.dtype), q_pos[0], axis=1)
-        layer_cache_v = lax.dynamic_update_slice_in_dim(
-            layer_cache_v, v.astype(layer_cache_v.dtype), q_pos[0], axis=1)
-
-    sp_impl = _sp_prefill_impl(cfg, b, t) if causal_prefill else None
-    with jax.named_scope("kt.attention"):
-        if sp_impl is not None:
-            # long-prompt prefill on a context mesh: sequence-sharded
-            # attention — no chip holds the full (T, T) attention problem
-            from ..parallel.mesh_context import current_mesh
-            if sp_impl == "ulysses":
-                from ..parallel.ulysses import ulysses_attention_sharded
-                attn = ulysses_attention_sharded(
-                    q, k, v, current_mesh(), causal=True,
-                    scale=cfg.head_dim ** -0.5, batch_axes=())
-            else:
-                from ..parallel.ring_attention import ring_attention_sharded
-                attn = ring_attention_sharded(
-                    q, k, v, current_mesh(), causal=True,
-                    scale=cfg.head_dim ** -0.5, batch_axes=())
-        elif flash_prefill:
-            from ..parallel.kernel_shard import flash_attention_sharded
-            from ..parallel.mesh_context import current_mesh
-            attn = flash_attention_sharded(q, k, v, current_mesh(),
-                                           causal=True,
-                                           scale=cfg.head_dim ** -0.5)
-        else:
-            attn = _cached_attention(q, layer_cache_k, layer_cache_v, q_pos,
-                                     cfg.head_dim ** -0.5)
-    with jax.named_scope("kt.out_proj"):
-        x = x + lora_proj(attn.reshape(b, t, -1), lw["wo"], lora, "wo")
-    with jax.named_scope("kt.ffn"):
-        h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-        return (x + ffn_block(cfg, h, lw, token_mask=token_mask,
-                              keep_capacity=keep_capacity,
-                              moe_no_drop=moe_no_drop),
-                layer_cache_k, layer_cache_v)
+    """One transformer layer over T new tokens at absolute positions
+    ``q_pos`` (T,), updating this layer's row-major cache:
+    ``models.block.decoder_block`` over :func:`cache_attend` and
+    :func:`ffn_block`. ``lora``: None, or (adapters_by_target, scale) with
+    this LAYER's factors per target — the unmerged activation-path adapters
+    multi-LoRA serving runs."""
+    x, (layer_cache_k, layer_cache_v), _ = decoder_block(
+        cfg, x, lw, freqs_full[q_pos],
+        cache_attend(cfg, layer_cache_k, layer_cache_v, q_pos,
+                     flash_prefill=flash_prefill,
+                     causal_prefill=causal_prefill),
+        partial(ffn_block, cfg, token_mask=token_mask,
+                keep_capacity=keep_capacity, moe_no_drop=moe_no_drop),
+        lora=lora)
+    return x, layer_cache_k, layer_cache_v
 
 
 def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],
               token_mask=None, keep_capacity=None,
-              moe_no_drop: bool = False) -> jax.Array:
-    """Post-norm FFN for a decode/prefill layer — dense SwiGLU, or the MoE
-    dispatch when the layer carries a ``router`` leaf. Shared by the scanned
-    ``generate`` path and the continuous-batching engine (``serve.engine``)
-    so their expert-routing semantics can never diverge.
+              moe_no_drop: bool = False):
+    """The block's FFN operation for a decode/prefill layer, (out, aux) —
+    dense SwiGLU, or the MoE dispatch when the layer carries a ``router``
+    leaf. Shared by the scanned ``generate`` path and the continuous-batching
+    engines (``serve.engine``, ``serve.spec_engine``), each binding its own
+    routing masks, so their expert-routing semantics can never diverge.
 
     MoE choice: true decode steps (T == 1, where capacity slots can never
     overflow, so both formulations are exactly equal) gather just the K
@@ -227,13 +230,10 @@ def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],
         if (t == 1 and cfg.decode_gather_ffn
                 and axis_size(current_mesh(), AXIS_EXPERT) == 1
                 and 2 * b * cfg.experts_per_token <= cfg.n_experts):
-            return moe_ffn_decode(cfg, h, lw)
-        ffn, _ = moe_ffn(cfg, h, lw, token_mask=token_mask,
-                         keep_capacity=keep_capacity, no_drop=moe_no_drop)
-        return ffn
-    from .quant import wdot
-    return wdot(jax.nn.silu(wdot(h, lw["w_gate"]))
-                * wdot(h, lw["w_up"]), lw["w_down"])
+            return moe_ffn_decode(cfg, h, lw), None
+        return moe_ffn(cfg, h, lw, token_mask=token_mask,
+                       keep_capacity=keep_capacity, no_drop=moe_no_drop)
+    return dense_ffn(h, lw)
 
 
 def forward_with_cache(params, tokens, cache: KVCache, start_pos,

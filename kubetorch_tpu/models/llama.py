@@ -28,6 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .block import (apply_rope,  # noqa: F401  (its importers' home)
+                    decoder_block, dense_ffn, rmsnorm)
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -118,12 +121,6 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
-def rmsnorm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (xf * scale * weight).astype(x.dtype)
-
-
 def rope_freqs(cfg: LlamaConfig, seq_len: int) -> jax.Array:
     """(S, Hd/2) complex rotation table, fp32."""
     inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim))
@@ -149,15 +146,6 @@ def rope_freqs(cfg: LlamaConfig, seq_len: int) -> jax.Array:
     t = jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)
     return jnp.cos(freqs) + 1j * jnp.sin(freqs)
-
-
-def apply_rope(x: jax.Array, freqs: jax.Array) -> jax.Array:
-    """x: (B, S, N, Hd). Rotate pairs in fp32, return in x.dtype."""
-    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-    xc = lax.complex(xf[..., 0], xf[..., 1])
-    rotated = xc * freqs[None, :, None, :]
-    out = jnp.stack([jnp.real(rotated), jnp.imag(rotated)], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _xla_attention(q, k, v, scale: float, causal: bool = True) -> jax.Array:
@@ -226,34 +214,28 @@ def attention(q, k, v, cfg: LlamaConfig) -> jax.Array:
     return _xla_attention(q, k, v, scale)
 
 
+def self_attend(cfg: LlamaConfig):
+    """The block's attention operation for a stack with no cache: causal
+    self-attention over the layer's own T tokens (:func:`attention`)."""
+    def attend(q, k, v):
+        with jax.named_scope("kt.attention"):
+            return attention(q, k, v, cfg), None
+    return attend
+
+
 def _layer(cfg: LlamaConfig, x: jax.Array, lw: Dict[str, jax.Array],
            freqs: jax.Array, tp_axis: Optional[str] = None) -> jax.Array:
     """One decoder layer. With ``tp_axis`` set, the body is the Megatron
     tensor-parallel variant for use inside ``shard_map``: ``lw`` leaves are
-    the LOCAL shards — wq/wk/wv/w_gate/w_up column-sharded (this device holds
-    ``n_heads/tp`` query heads, ``n_kv_heads/tp`` kv heads, ``ffn_dim/tp``
-    hidden units), wo/w_down row-sharded, norms replicated — and exactly two
-    ``psum``s run per layer (attention output, FFN output), explicit because
-    GSPMD cannot see inside shard_map. Head counts come from the local shapes
-    (equal to cfg's when unsharded), so one body serves both paths. GQA
-    grouping survives sharding: contiguous head blocks keep q-head
-    i ↔ kv-head i//group alignment per shard as long as tp | n_kv_heads.
-    """
-    b, s, d = x.shape
-    hd = cfg.head_dim
-    nh = lw["wq"].shape[-1] // hd
-    nkv = lw["wk"].shape[-1] // hd
-    psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
-    h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-    q = (h @ lw["wq"]).reshape(b, s, nh, hd)
-    k = (h @ lw["wk"]).reshape(b, s, nkv, hd)
-    v = (h @ lw["wv"]).reshape(b, s, nkv, hd)
-    q, k = apply_rope(q, freqs), apply_rope(k, freqs)
-    attn = attention(q, k, v, cfg).reshape(b, s, -1)
-    x = x + psum(attn @ lw["wo"])
-    h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-    ffn = (jax.nn.silu(h @ lw["w_gate"]) * (h @ lw["w_up"])) @ lw["w_down"]
-    return x + psum(ffn)
+    the LOCAL shards — wq/wk/wv/w_gate/w_up column-sharded, wo/w_down
+    row-sharded, norms replicated — and exactly two ``psum``s run per layer
+    (attention output, FFN output), explicit because GSPMD cannot see inside
+    shard_map (``models.block.decoder_block`` takes the head counts from the
+    local shapes, so one body serves both paths)."""
+    psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else None
+    x, _, _ = decoder_block(cfg, x, lw, freqs, self_attend(cfg),
+                            partial(dense_ffn, reduce=psum), reduce=psum)
+    return x
 
 
 def llama_hidden(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:

@@ -14,13 +14,15 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .llama import (LlamaConfig, apply_rope, attention, rmsnorm, rope_freqs)
+from .block import decoder_block, rmsnorm
+from .llama import LlamaConfig, rope_freqs, self_attend
 
 
 @dataclass(frozen=True)
@@ -314,25 +316,15 @@ def moe_ffn_decode(cfg: MoeConfig, x: jax.Array, lw: Dict[str, jax.Array]):
 
 def _moe_layer(cfg: MoeConfig, carry, lw: Dict[str, jax.Array], freqs,
                tp_axis=None, ep_axis=None):
-    """One MoE decoder layer; with tp/ep axes set it is the shard_map-safe
-    variant (head counts from local shapes, explicit psums) mirroring
-    ``llama._layer``."""
+    """One MoE decoder layer, the aux loss summed beside the activations;
+    with tp/ep axes set it is the shard_map-safe variant (explicit psums),
+    mirroring ``llama._layer``."""
     x, aux_sum = carry
-    b, s, d = x.shape
-    hd = cfg.head_dim
-    nh = lw["wq"].shape[-1] // hd
-    nkv = lw["wk"].shape[-1] // hd
-    psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
-    lcfg = cfg._llama_view()
-    h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-    q = (h @ lw["wq"]).reshape(b, s, nh, hd)
-    k = (h @ lw["wk"]).reshape(b, s, nkv, hd)
-    v = (h @ lw["wv"]).reshape(b, s, nkv, hd)
-    q, k = apply_rope(q, freqs), apply_rope(k, freqs)
-    x = x + psum(attention(q, k, v, lcfg).reshape(b, s, -1) @ lw["wo"])
-    h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-    ffn_out, aux = moe_ffn(cfg, h, lw, ep_axis=ep_axis, tp_axis=tp_axis)
-    return (x + ffn_out, aux_sum + aux)
+    psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else None
+    x, _, aux = decoder_block(
+        cfg, x, lw, freqs, self_attend(cfg._llama_view()),
+        partial(moe_ffn, cfg, ep_axis=ep_axis, tp_axis=tp_axis), reduce=psum)
+    return (x, aux_sum + aux)
 
 
 def moe_forward(params: Dict[str, Any], tokens: jax.Array, cfg: MoeConfig):

@@ -257,7 +257,7 @@ def decode_attention(q: jax.Array, gk: jax.Array, gv: jax.Array,
     grid, read in place; pos: (B,) int32 — the row each slot's new token
     occupies (already written); layer: int or traced int32 scalar.
     Returns (B, NH, Hd). Bit-compatible with the masked-einsum reference
-    in ``serve.engine._decode_layer`` (asserted in
+    in ``serve.engine._einsum_attention`` (asserted in
     tests/test_decode_kernel.py).
 
     ``block_k=512`` rows of every local KV head make a grid step (2 MiB of
@@ -283,6 +283,6 @@ def decode_attention_quant(q: jax.Array, kq: jax.Array, ks: jax.Array,
     q: (B, NH, Hd); kq/vq: (L, B, NKV, S, Hd) int8; ks/vs: (L, B, NKV, S)
     fp32 per-row scales; pos: (B,); layer as in :func:`decode_attention`.
     Bit-compatible with the fp32 fold-in einsum reference
-    (``serve.engine._decode_layer``), asserted in tests/test_kv_quant.py."""
+    (``serve.engine._einsum_attention``), asserted in tests/test_kv_quant.py."""
     return _decode_call(True, q, (kq, vq), (ks, vs), pos, layer, scale=scale,
                         block_k=block_k, interpret=interpret)

@@ -56,12 +56,11 @@ import numpy as np
 from jax import lax
 
 from .. import telemetry
+from ..models.block import decoder_block, rmsnorm
 from ..models.generate import (KVCache, _layer_step, ffn_block, init_cache,
                                rope_freqs)
-from ..models.llama import rmsnorm
-from ..models.lora import lora_proj
 from ..models.moe import moe_prefill_keep_capacity as _moe_keep_capacity
-from ..models.quant import dequant_layer, lm_head_dot
+from ..models.quant import lm_head_dot
 
 NEG_INF = -1e30
 
@@ -164,119 +163,96 @@ def _write_rows(grid, layer, pos, rows):
 
 
 def _einsum_attention(q, leaves, pos, scale):
-    """Masked-einsum decode attention over ONE layer of the grid (the
-    reference math both Pallas kernels are bit-compatible with; what the
-    CPU tests run). ``leaves``: (ck, cv) (B, NKV, S, Hd), or the int8
-    (kq, ks, vq, vs) with scales (B, NKV, S) folded in (logits columns
-    ·ks, probs ·vs; all fp32)."""
+    """Masked-einsum attention of a window a slot over ONE layer of the grid
+    (the reference math both Pallas kernels are bit-compatible with; what
+    the CPU tests run, and the speculative window's attention). q
+    (B, W, NH, Hd) at absolute positions ``pos`` (B, W); ``leaves``: (ck, cv)
+    (B, NKV, S, Hd), or the int8 (kq, ks, vq, vs) with scales (B, NKV, S)
+    folded in (logits columns ·ks, probs ·vs; all fp32). Returns
+    (B, W, NH, Hd)."""
     quant = len(leaves) == 4
     ck, cv = (leaves[0], leaves[2]) if quant else leaves
-    b, nh, hd = q.shape
+    b, w, nh, hd = q.shape
     nkv, s = ck.shape[1], ck.shape[2]
-    qg = q.reshape(b, nkv, nh // nkv, hd)
+    qg = q.reshape(b, w, nkv, nh // nkv, hd)
     if quant:
         qg, ck, cv = (a.astype(jnp.float32) for a in (qg, ck, cv))
-    logits = jnp.einsum("bkgh,bksh->bkgs", qg, ck).astype(jnp.float32) * scale
+    logits = jnp.einsum("bwkgh,bksh->bkgws", qg,
+                        ck).astype(jnp.float32) * scale
     if quant:
-        logits = logits * leaves[1][:, :, None, :]
-    mask = jnp.arange(s)[None, :] <= pos[:, None]          # (B, S)
+        logits = logits * leaves[1][:, :, None, None, :]
+    mask = jnp.arange(s)[None, None, :] <= pos[:, :, None]  # (B, W, S)
     logits = jnp.where(mask[:, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    probs = (probs * leaves[3][:, :, None, :] if quant
+    probs = (probs * leaves[3][:, :, None, None, :] if quant
              else probs.astype(cv.dtype))
-    return jnp.einsum("bkgs,bksh->bkgh", probs, cv).reshape(b, nh, hd)
+    return jnp.einsum("bkgws,bksh->bwkgh", probs, cv).reshape(b, w, nh, hd)
 
 
-def _rope_slot(x: jax.Array, freqs: jax.Array) -> jax.Array:
-    """RoPE with a PER-SLOT rotation: x (B, N, Hd), freqs (B, Hd/2) complex.
+def grid_attend(cfg, cache, layer, pos):
+    """The block's attention operation over the slot grid: one new token a
+    slot, against layer ``layer`` of the stacked grid, which is written and
+    read where it lies.
 
-    ``models.llama.apply_rope`` broadcasts one (T, Hd/2) table over the
-    batch — decode slots sit at different absolute positions, so here the
-    table is indexed per slot instead."""
-    b, n, hd = x.shape
-    xf = x.astype(jnp.float32).reshape(b, n, hd // 2, 2)
-    xc = lax.complex(xf[..., 0], xf[..., 1])
-    rotated = xc * freqs[:, None, :]
-    out = jnp.stack([jnp.real(rotated), jnp.imag(rotated)], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def _decode_layer(cfg, x, lw, cache, layer, pos, freqs, lora=None):
-    """One layer over one new token per slot, against layer ``layer`` of
-    the stacked grid, which is written and read where it lies.
-
-    x: (B, 1, D); cache: the whole grid, ``KVCache`` (L, B, NKV, S, Hd) or
-    the int8 ``QuantKVCache`` (``kv_quant``: the new row is QUANTIZED
-    before it is written and attention folds the row scales in instead of
-    materializing fp rows); layer: traced int32; pos: (B,) absolute
-    position of each slot's new token (also its cache row); freqs:
-    (B, Hd/2) complex. ``lora``: per-slot adapters already gathered to
-    (B, D, R)/(B, R, O) per target (multi-LoRA serving — see
-    ``GenerationEngine`` docs). Returns (x', cache').
-
-    The named scopes are metadata on the ops, for a device trace to group
-    time by; they change nothing the compiled program does.
-    """
-    from .kv_quant import QuantKVCache, quantize_rows
+    cache: the whole grid, ``KVCache`` (L, B, NKV, S, Hd) or the int8
+    ``QuantKVCache`` (``kv_quant``: the new row is QUANTIZED before it is
+    written and attention folds the row scales in instead of materializing
+    fp rows); layer: traced int32; pos: (B,) absolute position of each
+    slot's new token (also its cache row). Returns
+    ``attend(q, k, v) -> (attn, cache')`` over q (B, 1, NH, Hd) and
+    k, v (B, 1, NKV, Hd)."""
+    from .kv_quant import QuantKVCache, grid_rows
     quant = isinstance(cache, QuantKVCache)
-    b = x.shape[0]
-    hd = cfg.head_dim
-    nh, nkv = cfg.n_heads, cfg.n_kv_heads
-    lw = dequant_layer(lw, cfg.dtype)    # int8 serving weights (models.quant)
-    with jax.named_scope("kt.qkv_rope"):
-        h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
-        q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, nh, hd)
-        k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, nkv, hd)
-        v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, nkv, hd)
-        q, k = _rope_slot(q, freqs), _rope_slot(k, freqs)
+    scale = cfg.head_dim ** -0.5
 
-    with jax.named_scope("kt.cache_update"):
-        rows = (*quantize_rows(k), *quantize_rows(v)) if quant else (k, v)
-        cache = type(cache)(*(_write_rows(g, layer, pos, r)
-                              for g, r in zip(cache, rows)))
+    def attend(q, k, v):
+        b, nh, nkv = q.shape[0], q.shape[2], k.shape[2]
+        q1 = q[:, 0]
+        with jax.named_scope("kt.cache_update"):
+            grid = type(cache)(*(
+                _write_rows(g, layer, pos, r)
+                for g, r in zip(cache, grid_rows(cache, k[:, 0], v[:, 0]))))
 
-    from ..parallel import kernel_shard
-    from ..parallel import ring_attention as ring
-    from ..parallel.mesh_context import current_mesh
-    mesh = current_mesh()
-    scale = hd ** -0.5
+        from ..parallel import kernel_shard
+        from ..parallel import ring_attention as ring
+        from ..parallel.mesh_context import current_mesh
+        mesh = current_mesh()
 
-    def layer_leaves():
-        # a layer-sized read: the reference paths only, no cell runs them
-        return tuple(lax.dynamic_index_in_dim(g, layer, 0, keepdims=False)
-                     for g in cache)
+        def layer_leaves():
+            # a layer-sized read: the reference paths only, no cell runs them
+            return tuple(lax.dynamic_index_in_dim(g, layer, 0, keepdims=False)
+                         for g in grid)
 
-    with jax.named_scope("kt.attention"):
-        if mesh is not None and ring.sp_decode_supported(
-                mesh, b, cache[0].shape[3], nkv, nh):
-            # long-context serving: the cache's sequence axis is sharded
-            # over the context mesh axis; local attention + one
-            # online-softmax combine beats the all-gather GSPMD would
-            # otherwise insert (and the Pallas kernel, which needs all rows
-            # on one chip). Trace-time gate like the MoE gather (mesh fixed
-            # per engine — captured at construction and re-installed on
-            # whichever thread traces); shapes that don't divide the mesh
-            # fall back to the dense path. int8 × context sharding compose:
-            # 1/(2C) of the fp cache bytes per chip.
-            sp = (ring.sp_decode_attention_quant_sharded if quant
-                  else ring.sp_decode_attention_sharded)
-            attn = sp(q, *layer_leaves(), pos, mesh, scale=scale)
-        elif _decode_kernel_wanted():
-            # fused flash-decode over the stacked grid itself: streams K/V
-            # tiles, skips tiles past each slot's frontier entirely
-            # (ops/decode_attention.py); under a mesh each device runs it
-            # over its own slots and heads
-            kernel = (kernel_shard.decode_attention_quant_sharded if quant
-                      else kernel_shard.decode_attention_sharded)
-            attn = kernel(q, *cache, pos, layer, mesh, scale=scale)
-        else:
-            attn = _einsum_attention(q, layer_leaves(), pos, scale)
-        attn = attn.reshape(b, 1, nh * hd).astype(x.dtype)
-    with jax.named_scope("kt.out_proj"):
-        x = x + lora_proj(attn, lw["wo"], lora, "wo")
-    with jax.named_scope("kt.ffn"):
-        h = rmsnorm(x, lw["ffn_norm"], cfg.norm_eps)
-        return x + ffn_block(cfg, h, lw), cache
+        with jax.named_scope("kt.attention"):
+            if mesh is not None and ring.sp_decode_supported(
+                    mesh, b, grid[0].shape[3], nkv, nh):
+                # long-context serving: the cache's sequence axis is sharded
+                # over the context mesh axis; local attention + one
+                # online-softmax combine beats the all-gather GSPMD would
+                # otherwise insert (and the Pallas kernel, which needs all
+                # rows on one chip). Trace-time gate like the MoE gather
+                # (mesh fixed per engine — captured at construction and
+                # re-installed on whichever thread traces); shapes that
+                # don't divide the mesh fall back to the dense path. int8 ×
+                # context sharding compose: 1/(2C) of the fp cache bytes per
+                # chip.
+                sp = (ring.sp_decode_attention_quant_sharded if quant
+                      else ring.sp_decode_attention_sharded)
+                attn = sp(q1, *layer_leaves(), pos, mesh, scale=scale)
+            elif _decode_kernel_wanted():
+                # fused flash-decode over the stacked grid itself: streams
+                # K/V tiles, skips tiles past each slot's frontier entirely
+                # (ops/decode_attention.py); under a mesh each device runs
+                # it over its own slots and heads
+                kernel = (kernel_shard.decode_attention_quant_sharded if quant
+                          else kernel_shard.decode_attention_sharded)
+                attn = kernel(q1, *grid, pos, layer, mesh, scale=scale)
+            else:
+                attn = _einsum_attention(q, layer_leaves(), pos[:, None],
+                                         scale)
+            return attn.astype(q.dtype), grid
+
+    return attend
 
 
 def _sample_slots(logits, key, temps, top_k: Optional[int], top_ps=None,
@@ -335,7 +311,7 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     ``counts'`` is None when ``counts`` is."""
     n_layers, s_max = cache[0].shape[0], cache[0].shape[3]
     x = params["embed"][toks[:, None]].astype(cfg.dtype)   # (B, 1, D)
-    freqs = rope_freqs(cfg, s_max)[pos]                     # (B, Hd/2)
+    freqs = rope_freqs(cfg, s_max)[pos][:, None]            # (B, 1, Hd/2)
 
     from ..models.lora import gather_slot_adapters
 
@@ -345,8 +321,13 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     def body(carry, layer):
         h, grid = carry
         lw, l, bank_l = layer
+        # per-slot adapters gathered to (B, D, R)/(B, R, O) per target
+        # (multi-LoRA serving — see ``GenerationEngine`` docs)
         lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
-        return _decode_layer(cfg, h, lw, grid, l, pos, freqs, lora=lora), None
+        h, grid, _ = decoder_block(cfg, h, lw, freqs,
+                                   grid_attend(cfg, grid, l, pos),
+                                   partial(ffn_block, cfg), lora=lora)
+        return (h, grid), None
 
     (x, new_cache), _ = lax.scan(
         body, (x, cache),
@@ -560,16 +541,12 @@ def _splice_slot(cache, slot, k_new, v_new):
     ≤ bucket-width new rows, not the grid). For an int8 ``QuantKVCache``
     grid the rows quantize HERE — prefill itself always runs full-precision
     math."""
-    from .kv_quant import QuantKVCache, quantize_rows
-    if isinstance(cache, QuantKVCache):
-        rows = (*quantize_rows(k_new), *quantize_rows(v_new))
-    else:
-        rows = (k_new, v_new)
+    from .kv_quant import grid_rows
     return _constrain_cache(type(cache)(*(
         lax.dynamic_update_slice(
             g, jnp.swapaxes(r, 2, 3).astype(g.dtype),
             (0, slot) + (0,) * (g.ndim - 2))
-        for g, r in zip(cache, rows))))
+        for g, r in zip(cache, grid_rows(cache, k_new, v_new)))))
 
 
 # ---------------------------------------------------------------------------

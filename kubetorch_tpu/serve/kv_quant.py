@@ -69,6 +69,14 @@ def quantize_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+def grid_rows(grid, k: jax.Array, v: jax.Array) -> tuple:
+    """New K/V rows as ``grid``'s leaves take them: (k, v) for a
+    full-precision grid, (kq, ks, vq, vs) for an int8 one (four leaves)."""
+    if len(grid) == 4:
+        return (*quantize_rows(k), *quantize_rows(v))
+    return k, v
+
+
 def dequantize_rows(q: jax.Array, scale: jax.Array) -> jax.Array:
     """fp32 rows back; exact inverse of the fold-into-attention math for
     callers that need plain rows (tests, debugging)."""

@@ -40,25 +40,41 @@ from jax import lax
 
 from .. import telemetry
 
-from ..models.generate import KVCache, ffn_block, rope_freqs
-from ..models.llama import rmsnorm
-from ..models.quant import dequant_layer, lm_head_dot, wdot
-from .engine import (GenerationEngine, _decode_block, _prefill,
-                     _prefill_suffix, _splice_slot, init_grid_cache)
+from ..models.block import decoder_block, rmsnorm
+from ..models.generate import ffn_block, rope_freqs
+from ..models.quant import lm_head_dot
+from .engine import (GenerationEngine, _decode_block, _einsum_attention,
+                     _prefill, _prefill_suffix, _splice_slot, init_grid_cache)
 from .speculative import SpecStats
 
-NEG_INF = -1e30
 
+def window_attend(cfg, leaves, posm, s_eff: int):
+    """The block's attention operation over ONE layer of the head-major grid
+    for a window a slot: write the (B, W) new rows at ``posm`` (B, W), then
+    attend the first ``s_eff`` rows under the per-(slot, offset) causal mask
+    with the engine's reference einsums (``engine._einsum_attention``), so
+    the verify window attends bit-compatibly with the T=1 decode it must
+    match.
 
-def _rope_grid(x: jax.Array, freqs: jax.Array) -> jax.Array:
-    """RoPE with per-(slot, offset) rotations: x (B, W, N, Hd), freqs
-    (B, W, Hd/2) complex — the grid generalization of ``_rope_slot``."""
-    b, w, n, hd = x.shape
-    xf = x.astype(jnp.float32).reshape(b, w, n, hd // 2, 2)
-    xc = lax.complex(xf[..., 0], xf[..., 1])
-    rotated = xc * freqs[:, :, None, :]
-    out = jnp.stack([jnp.real(rotated), jnp.imag(rotated)], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    ``leaves``: (ck, cv) (B, NKV, S, Hd), or the int8 (kq, ks, vq, vs) with
+    scales (B, NKV, S): new rows are quantized before they are written.
+    Returns ``attend(q, k, v) -> (attn, leaves')``."""
+    from .kv_quant import grid_rows
+
+    def attend(q, k, v):
+        bi = jnp.arange(q.shape[0])[:, None]
+        with jax.named_scope("kt.cache_update"):
+            # layer slices are head-major (B, NKV, S, ...): the advanced
+            # indices around the head slice put (B, W) first, as the rows are
+            grid = tuple(g.at[bi, :, posm].set(r.astype(g.dtype))
+                         for g, r in zip(leaves, grid_rows(leaves, k, v)))
+        with jax.named_scope("kt.attention"):
+            attn = _einsum_attention(
+                q, tuple(lax.slice_in_dim(g, 0, s_eff, axis=2) for g in grid),
+                posm, cfg.head_dim ** -0.5)
+            return attn.astype(q.dtype), grid
+
+    return attend
 
 
 @partial(jax.jit, static_argnames=("cfg", "s_eff", "lora_scale"),
@@ -83,117 +99,36 @@ def _grid_ingest(params, cache, blocks, start, true_len, cfg,
     flash-decode kernel gives the T=1 path, as a static slice here (one
     compile per bucket, a handful over a request's lifetime).
 
-    The layer body is deliberately specialized (three position shapes live
-    in this codebase: (T,) scanned generate, (B,) slot decode, (B, W)
-    here) — divergence from ``generate``'s semantics is pinned by the
-    bit-exactness oracles in tests/test_spec_engine.py, which fail on ANY
-    drift in norm/RoPE/cache/MoE behavior.
-
     ``cache`` is the engine's head-major grid (L, SLOTS, NKV, S_max, Hd),
     a fp ``KVCache`` or an int8 ``QuantKVCache`` (``serve.kv_quant``) —
-    the pytree structure keys the jit. The quant
-    branch quantizes new rows before writing and folds the row scales
-    into the attention f32 einsums (logits columns ·ks, probs ·vs) — the
-    same reference math as ``engine._einsum_attention``, so the verify
-    window attends bit-compatibly with the T=1 decode it must match."""
-    from .kv_quant import QuantKVCache, quantize_rows
-    quant = isinstance(cache, QuantKVCache)
+    the pytree structure keys the jit (:func:`window_attend` handles
+    both). The grid is scanned as ``xs``/``ys`` here, a layer slice a step,
+    where the decode block carries it."""
     b, w = blocks.shape
     s_max = cache[0].shape[3]
     if s_eff is None:
         s_eff = s_max
     x = params["embed"][blocks].astype(cfg.dtype)
     posm = start[:, None] + jnp.arange(w)[None, :]          # (B, W)
-    freqs_full = rope_freqs(cfg, s_max)
-    freqs = freqs_full[posm]                                 # (B, W, Hd/2)
+    freqs = rope_freqs(cfg, s_max)[posm]                     # (B, W, Hd/2)
     token_mask = jnp.arange(w)[None, :] < true_len[:, None]  # (B, W)
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    group = nh // nkv
-    bi = jnp.arange(b)[:, None]
+    ffn = partial(ffn_block, cfg, token_mask=token_mask, moe_no_drop=True)
 
-    from ..models.lora import gather_slot_adapters, lora_proj
+    from ..models.lora import gather_slot_adapters
 
-    def make_lora(bank_l):
+    def body(h, layer):
+        lw, leaves, bank_l = layer
         # the SAME gather the plain decode step uses (shared helper — the
         # bank layout / zero-adapter convention cannot drift)
-        return gather_slot_adapters(bank_l, aidx, lora_scale, banks)
+        lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
+        h, leaves, _ = decoder_block(
+            cfg, h, lw, freqs, window_attend(cfg, leaves, posm, s_eff), ffn,
+            lora=lora)
+        return h, leaves
 
-    def proj_qkv(lw, h, lora):
-        hn = rmsnorm(h, lw["attn_norm"], cfg.norm_eps)
-        q = lora_proj(hn, lw["wq"], lora, "wq").reshape(b, w, nh, hd)
-        k = lora_proj(hn, lw["wk"], lora, "wk").reshape(b, w, nkv, hd)
-        v = lora_proj(hn, lw["wv"], lora, "wv").reshape(b, w, nkv, hd)
-        return _rope_grid(q, freqs), _rope_grid(k, freqs), v
-
-    def finish(lw, h, attn, lora):
-        h = h + lora_proj(attn, lw["wo"], lora, "wo")
-        hn = rmsnorm(h, lw["ffn_norm"], cfg.norm_eps)
-        return h + ffn_block(cfg, hn, lw, token_mask=token_mask,
-                             moe_no_drop=True)
-
-    def win_mask():
-        return (jnp.arange(s_eff)[None, None, :]
-                <= posm[:, :, None])                        # (B, W, S_eff)
-
-    if quant:
-        def body(carry, layer):
-            lw, kq, ks, vq, vs, bank_l = layer
-            lw = dequant_layer(lw, cfg.dtype)
-            lora = make_lora(bank_l)
-            h = carry
-            q, k, v = proj_qkv(lw, h, lora)
-            k_row, ks_row = quantize_rows(k)
-            v_row, vs_row = quantize_rows(v)
-            # layer slices are head-major (B, NKV, S, ...): the advanced
-            # indices around the head slice put (B, W) first, as the rows are
-            kq = kq.at[bi, :, posm].set(k_row)
-            ks = ks.at[bi, :, posm].set(ks_row)
-            vq = vq.at[bi, :, posm].set(v_row)
-            vs = vs.at[bi, :, posm].set(vs_row)
-            kq_a = lax.slice_in_dim(kq, 0, s_eff, axis=2)
-            ks_a = lax.slice_in_dim(ks, 0, s_eff, axis=2)
-            vq_a = lax.slice_in_dim(vq, 0, s_eff, axis=2)
-            vs_a = lax.slice_in_dim(vs, 0, s_eff, axis=2)
-            qg = q.reshape(b, w, nkv, group, hd).astype(jnp.float32)
-            logits = jnp.einsum("bwkgh,bksh->bkgws", qg,
-                                kq_a.astype(jnp.float32)) * (hd ** -0.5)
-            # fold the K row scales over the S axis: ks_a (B, NKV, S)
-            logits = logits * ks_a[:, :, None, None, :]
-            logits = jnp.where(win_mask()[:, None, None], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1)
-            probs = probs * vs_a[:, :, None, None, :]
-            attn = jnp.einsum("bkgws,bksh->bwkgh", probs,
-                              vq_a.astype(jnp.float32)).reshape(
-                                  b, w, nh * hd).astype(h.dtype)
-            return finish(lw, h, attn, lora), (kq, ks, vq, vs)
-
-        x, leaves = lax.scan(body, x, (params["layers"], cache.kq,
-                                       cache.ks, cache.vq, cache.vs,
-                                       banks or {}))
-        new_cache = QuantKVCache(*leaves)
-    else:
-        def body(carry, layer):
-            lw, ck, cv, bank_l = layer
-            lw = dequant_layer(lw, cfg.dtype)
-            lora = make_lora(bank_l)
-            h = carry
-            q, k, v = proj_qkv(lw, h, lora)
-            ck = ck.at[bi, :, posm].set(k.astype(ck.dtype))
-            cv = cv.at[bi, :, posm].set(v.astype(cv.dtype))
-            ck_a = lax.slice_in_dim(ck, 0, s_eff, axis=2)
-            cv_a = lax.slice_in_dim(cv, 0, s_eff, axis=2)
-            qg = q.reshape(b, w, nkv, group, hd)
-            logits = jnp.einsum("bwkgh,bksh->bkgws", qg,
-                                ck_a).astype(jnp.float32) * (hd ** -0.5)
-            logits = jnp.where(win_mask()[:, None, None], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cv.dtype)
-            attn = jnp.einsum("bkgws,bksh->bwkgh", probs,
-                              cv_a).reshape(b, w, nh * hd)
-            return finish(lw, h, attn, lora), (ck, cv)
-
-        x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k,
-                                         cache.v, banks or {}))
-        new_cache = KVCache(nk, nv)
+    x, leaves = lax.scan(body, x, (params["layers"], tuple(cache),
+                                   banks or {}))
+    new_cache = type(cache)(*leaves)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head_dot(x, params, cfg.dtype)
     return logits, new_cache

@@ -396,8 +396,15 @@ def _decode_block(params, cache, pos, toks, rng, temps, cfg, n_steps: int,
     copies it (tests/test_decode_block_compiles.py holds the compiled
     program to that).
 
+    The engine may queue this block's successor, from the final ``pos`` /
+    ``tok`` returned here, before it has fetched this block's tokens
+    (``GenerationEngine._may_run_ahead``); "between batches" is then the
+    point where every dispatched block has been fetched, not every gap
+    between two blocks on the device.
+
     A slot that retires mid-block (eos/stop/budget) keeps computing garbage
-    for the rest of the block; the host discards those tokens at emit time.
+    for the rest of the block, and for the whole of a successor already in
+    flight; the host discards those tokens at emit time.
     Its overshoot cache writes at positions ≥ S_max are CLAMPED
     (``dynamic_update_slice`` clips an out-of-range start): they rewrite
     the slot's own row S_max−1, after the slot's last kept token was
@@ -524,11 +531,61 @@ def _prefill_suffix(params, tokens, true_len, prefix_k, prefix_v, prefix_len,
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _set_counts_row(counts, slot, row):
+def _set_counts_row(counts, slot, row, first=None):
     """Seed one slot's seen-token counts at admission (prompt + prefix +
     first sampled token); stale rows from prior occupants never matter —
-    zero-penalty slots multiply them by 0."""
+    zero-penalty slots multiply them by 0. ``first`` (1,) is the prefill's
+    first token, counted here on the device: the host has not seen it yet
+    when the row is set."""
+    if first is not None:
+        row = row.at[first[0]].add(1)
     return counts.at[slot].set(row)
+
+
+# The decode carry: ``pos`` and ``tok``, a block's own outputs and its
+# successor's inputs, stay on the device, and so do what a prefill leaves
+# there (its first token, the slot's sampling key). The host sends ONE packed
+# patch a block (uint32 bit patterns, a row a slot): seated or retired since
+# the last block?, ``tok`` from the prefill's first token?, the host's ``pos``
+# and ``tok`` for such a slot, and the per-slot vectors that only the host
+# ever changes, a column each in this order.
+_PATCH_VECTORS = (("temps", jnp.float32), ("aidx", jnp.int32),
+                  ("top_ps", jnp.float32), ("fpen", jnp.float32),
+                  ("ppen", jnp.float32), ("bmask", jnp.float32))
+_PATCH_WIDTH = 4 + len(_PATCH_VECTORS)
+
+
+@jax.jit
+def _seat_first(firsts, skeys, slot, first, key):
+    """What an admission leaves on the device for the block that follows it:
+    the prefill's first token, (1,), into the (SLOTS,) vector the next
+    carry patch reads, and the slot's sampling key into (SLOTS, 2). The
+    host has seen neither when that block is dispatched, and never reads
+    the key."""
+    return firsts.at[slot].set(first[0]), skeys.at[slot].set(key)
+
+
+@jax.jit
+def _patch_carry(pos, tok, firsts, skeys, patch):
+    """A block's inputs from its predecessor's final ``pos`` / ``tok``:
+    kept where the slot decodes on, replaced by the host's values where it
+    was seated or retired since (a newly seated slot's ``tok`` taken from
+    ``firsts``), beside the per-slot vectors unpacked from ``patch``
+    (SLOTS, _PATCH_WIDTH) and ``skeys`` as it came. EVERY block's inputs
+    come out of this function, an empty patch included, so
+    ``_decode_block`` sees one input signature whether or not a slot
+    changed."""
+    def column(col, dtype):
+        return lax.bitcast_convert_type(patch[:, col], dtype)
+
+    changed, from_first = patch[:, 0] != 0, patch[:, 1] != 0
+    out = {"pos": jnp.where(changed, column(2, jnp.int32), pos),
+           "tok": jnp.where(changed, jnp.where(from_first, firsts,
+                                               column(3, jnp.int32)), tok),
+           "skeys": skeys}
+    for col, (name, dtype) in enumerate(_PATCH_VECTORS, start=4):
+        out[name] = column(col, dtype)
+    return out
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -591,11 +648,26 @@ class _Request:
     submitted_at: float = field(default_factory=time.monotonic)
     admitted_at: Optional[float] = None      # popped from the queue
     first_token_at: Optional[float] = None
-    # the engine's phase clock and block count when the request was seated,
-    # and, once it has retired or been cancelled, its life
-    # (GenerationEngine._close_life)
-    seated: Optional[tuple] = None
+    # the engine's phase clock when the request was seated, and, once it has
+    # retired or been cancelled, its life (GenerationEngine._close_life)
+    seated: Optional[Dict[str, float]] = None
     life: Optional[Dict[str, Any]] = None
+    # decode blocks it emitted from, and how many of them were dispatched
+    # one block ahead (before their predecessor had been fetched)
+    blocks: int = 0
+    blocks_ahead: int = 0
+
+
+@dataclass
+class _Flight:
+    """One dispatched decode block whose tokens the host has not fetched:
+    the request each slot held at dispatch (tokens are emitted only where
+    the slot still holds it), the block's (K, SLOTS) tokens and
+    log-probabilities on the device, and whether it was dispatched ahead."""
+    reqs: List[Optional[_Request]]
+    toks: Any
+    lps: Any
+    ahead: bool
 
 
 class RequestHandle:
@@ -709,6 +781,9 @@ class EngineStats:
     # rolling mean time-to-first-token over the last admissions (secs);
     # 0.0 until anything has admitted
     ttft_avg: float = 0.0
+    # decode blocks dispatched before their predecessor's tokens had been
+    # fetched (of decode_steps / decode_block blocks in all)
+    blocks_run_ahead: int = 0
 
 
 class GenerationEngine:
@@ -772,8 +847,9 @@ class GenerationEngine:
         # (its key, its positions) — a request with seed=S decodes the
         # same tokens whatever slot it lands in, whoever its neighbors
         # are, and whatever decode_block is; unseeded requests draw their
-        # key from the engine chain at admission
-        self._skeys = np.zeros((self.slots, 2), np.uint32)
+        # key from the engine chain at admission. On the device only
+        # (_seat_first): reading a key back would wait for the prefill.
+        self._skeys = jnp.zeros((self.slots, 2), jnp.uint32)
         # the ambient mesh is THREAD-LOCAL trace state: capture it at
         # construction and re-install it around every trace site, or an
         # engine driven by its background loop thread (start()/generate(),
@@ -796,6 +872,9 @@ class GenerationEngine:
             # grid lives sharded from step 0 (slots over data axes, the
             # sequence dim over context, heads over tensor)
             self._cache = jax.device_put(self._cache, shardings)
+        # the per-slot vectors below are the HOST's mirrors (written at
+        # emit, seat and retire; SpeculativeEngine decodes from them). The
+        # decode loop's own copy is the device carry, further down.
         self._pos = np.zeros(self.slots, np.int32)     # next write position
         self._tok = np.zeros(self.slots, np.int32)     # next decode input
         self._slot_req: List[Optional[_Request]] = [None] * self.slots
@@ -836,6 +915,21 @@ class GenerationEngine:
         self._adapter_ids = itertools.count(1)
         self._aidx = np.zeros(self.slots, np.int32)
         self._admitting: Optional[_Request] = None   # cancel() window
+        # the decode carry on the device: the newest block's final (pos,
+        # tok) and the prefills' first tokens beside them, and the slots
+        # seated or retired since the last dispatch, which the next patch
+        # (_patch_carry) overwrites. Stepping thread only.
+        self._carry = (jnp.zeros(self.slots, jnp.int32),
+                       jnp.zeros(self.slots, jnp.int32))
+        self._firsts = jnp.zeros(self.slots, jnp.int32)
+        self._dirty: set = set()
+        # admissions of this boundary whose first token is still on the
+        # device only: (req, slot, first, logprob), in admission order
+        self._seating: List[tuple] = []
+        # dispatched blocks not yet fetched, oldest first: at most the
+        # running one and one queued behind it
+        self._inflight: "deque[_Flight]" = deque()
+        self._blocks_ahead = 0
         self._rng = jax.random.PRNGKey(seed)
         self._rid = itertools.count()
         self._lock = threading.Lock()
@@ -1232,6 +1326,7 @@ class GenerationEngine:
         self._fpen[slot] = 0.0
         self._ppen[slot] = 0.0
         self._aidx[slot] = 0
+        self._dirty.add(slot)
         self._finished += 1
         self._free_slot_ledgers(slot)
 
@@ -1250,9 +1345,9 @@ class GenerationEngine:
             life["prefill_s"] = req.first_token_at - req.admitted_at
             life["decode_s"] = now - req.first_token_at
         if req.seated is not None:
-            phases, blocks = req.seated
-            life["blocks"] = self._phases.blocks - blocks
-            life.update(self._phases.since(phases))
+            life["blocks"] = req.blocks
+            life["blocks_ahead"] = req.blocks_ahead
+            life.update(self._phases.since(req.seated))
         req.life = life
 
     def _reap_cancelled(self) -> None:
@@ -1274,10 +1369,17 @@ class GenerationEngine:
         — above all the live weight hot swap (``serve/rollout.py``, the
         only sanctioned ``engine.params`` writer after construction): no
         decode dispatch is in flight when the hook runs, so donated
-        buffers can be freed and replaced without racing a jit. Blocks the
+        buffers can be freed and replaced without racing a jit. "Between
+        batches" means that every block dispatched so far has been fetched
+        and emitted, also one that was dispatched ahead: a queued hook
+        stops the run-ahead (:meth:`_may_run_ahead`), the loop fetches and
+        emits what is in flight (two blocks at most) and then runs the
+        hook, so the counters it reads count exactly the tokens made so
+        far and the next token comes from what it left behind. Blocks the
         CALLER until the hook has run (the decode loop itself never
         blocks on anything but the device); with no loop thread running,
-        runs inline under the engine's mesh scope — the caller is the
+        runs inline under the engine's mesh scope, after fetching and
+        emitting whatever ``step()`` left in flight — the caller is the
         de-facto stepping thread. Exceptions propagate to the caller,
         never into the decode loop. Returns ``fn()``'s result."""
         with self._lifecycle:
@@ -1285,6 +1387,8 @@ class GenerationEngine:
         running = thread is not None and thread.is_alive()
         if not running or threading.current_thread() is thread:
             with self._mesh_scope():
+                if not running:
+                    self._drain()
                 return fn()
         box: Dict[str, Any] = {"done": threading.Event()}
         self._boundary_hooks.append((fn, box))
@@ -1464,7 +1568,7 @@ class GenerationEngine:
             with phase("admit.setup"):
                 temp, temps, tp, pkw, row, bias_vec = self._sampling_setup(
                     req, pref_toks)
-                key = self._request_prefill_key(req, frontier + take)
+                key, skey = self._request_keys(req, frontier + take)
             with phase("admit.prefill"):
                 first, k_new, v_new, flp = _prefill_suffix(
                     self.params, jnp.asarray(padded), jnp.int32(take),
@@ -1475,7 +1579,7 @@ class GenerationEngine:
                                    k_new[:, :, :self.max_len],
                                    v_new[:, :, :self.max_len],
                                    frontier + take, temp, tp, row, aidx,
-                                   bias_vec=bias_vec)
+                                   skey, bias_vec=bias_vec)
         except Exception as e:   # noqa: BLE001 — fail THIS request only
             self._chunking = None
             req.error = e
@@ -1542,48 +1646,53 @@ class GenerationEngine:
                               - jnp.asarray(bias_vec))
         return temp, temps, tp, pkw, row, bias_vec
 
-    def _request_prefill_key(self, req: _Request, start: int):
-        """Sampling key for the admission prefill (the FIRST token, placed
-        at position ``start``): seeded requests fold their own base key by
-        ``start - 1`` — disjoint from the decode folds at start, start+1,
-        … — and draw nothing from the engine chain."""
+    def _request_keys(self, req: _Request, start: int):
+        """Sampling keys of an admission, both on the device: the prefill's
+        (the FIRST token, placed at position ``start``) and the slot's.
+        Seeded requests fold their own base key by ``start - 1`` for the
+        prefill — disjoint from the decode folds at start, start+1, … —
+        and draw nothing from the engine chain; unseeded ones draw both
+        from it, in this order."""
         if req.seed is None:
-            return self._next_key()
-        return jax.random.fold_in(jax.random.PRNGKey(req.seed), start - 1)
+            return self._next_key(), self._next_key()
+        base = jax.random.PRNGKey(req.seed)
+        return jax.random.fold_in(base, start - 1), base
 
     def _finish_admission(self, req: _Request, slot: int, first, flp,
                           k_new, v_new, start: int, temp: float, tp: float,
-                          row, aidx: int, bias_vec=None) -> None:
+                          row, aidx: int, skey, bias_vec=None) -> None:
         """Post-prefill slot bookkeeping shared by one-shot and chunked
-        admission: splice the K/V rows, seat the request, seed ledgers,
-        re-check the adapter mapping, emit the first sampled token."""
-        phase = self._phases.phase
-        with phase("admit.seat"):
-            self._cache = _splice_slot(self._cache, jnp.int32(slot),
-                                       k_new, v_new)
-        with phase("admit.prefill"):
-            first_tok = int(first[0])     # the wait for the prefill
-        with phase("admit.seat"):
+        admission: queue the splice of the K/V rows behind the prefill,
+        seat the request, seed ledgers, re-check the adapter mapping.
+        Nothing here reads the device back: the slot's key and the first
+        sampled token stay there (``_seat_first``; the penalty row counts
+        the token there too), the decode block is dispatched behind the
+        prefill before the host waits for it, and :meth:`_emit_firsts`
+        emits it."""
+        with self._phases.phase("admit.seat"):
+            slot_i = jnp.int32(slot)
+            self._cache = _splice_slot(self._cache, slot_i, k_new, v_new)
+            self._firsts, self._skeys = _seat_first(
+                self._firsts, self._skeys, slot_i, first, skey)
+            # the copies to the host start when the prefill ends, not when
+            # _emit_firsts asks
+            first.copy_to_host_async()
+            flp.copy_to_host_async()
             self._slot_req[slot] = req
-            req.seated = (self._phases.snapshot(), self._phases.blocks)
-            self._skeys[slot] = np.asarray(
-                jax.random.PRNGKey(req.seed) if req.seed is not None
-                else self._next_key(), np.uint32)
+            req.seated = self._phases.snapshot()
             self._pos[slot] = start
-            self._tok[slot] = first_tok
             self._temps[slot] = temp
             self._top_ps[slot] = tp
             self._fpen[slot] = req.frequency_penalty
             self._ppen[slot] = req.presence_penalty
             if row is not None:
-                row[first_tok] += 1
                 self._counts = _set_counts_row(
-                    self._counts, jnp.int32(slot), jnp.asarray(row))
+                    self._counts, slot_i, jnp.asarray(row), first)
             if bias_vec is not None:
                 if self._bias is None:
                     self._bias = jnp.zeros(
                         (self.slots, self.cfg.vocab_size), jnp.float32)
-                self._bias = _set_counts_row(self._bias, jnp.int32(slot),
+                self._bias = _set_counts_row(self._bias, slot_i,
                                              jnp.asarray(bias_vec))
                 self._bmask[slot] = 1.0
             with self._lock:
@@ -1596,11 +1705,36 @@ class GenerationEngine:
                         and self._adapter_slots.get(req.adapter_id) != aidx):
                     aidx = 0
                 self._aidx[slot] = aidx
+            self._dirty.add(slot)
             self._admitted += 1
-            self._emit(slot, first_tok, float(flp[0]))
-            # TTFT sample at the only place it's defined: the first emit
-            if req.first_token_at is not None:
-                self._ttfts.append(req.first_token_at - req.submitted_at)
+            self._seating.append((req, slot, first, flp))
+
+    def _emit_firsts(self) -> None:
+        """Wait for the first token of each request seated at this boundary
+        and emit it, in admission order. The decode block that follows the
+        prefills is already queued behind them; a request whose first token
+        ends it retires here, and its slot's share of that block is dropped
+        like any other garbage."""
+        phase = self._phases.phase
+        seating, self._seating = self._seating, []
+        for req, slot, first, flp in seating:
+            try:
+                with phase("admit.prefill"):
+                    # the wait for the prefill
+                    first_tok = int(np.asarray(first)[0])
+                    logprob = float(np.asarray(flp)[0])
+            except Exception as e:   # noqa: BLE001 — fail THIS request only
+                req.error = e
+                if self._slot_req[slot] is req:
+                    self._retire_slot(slot)
+                continue
+            with phase("admit.seat"):
+                self._tok[slot] = first_tok
+                self._emit(slot, first_tok, logprob)
+                # TTFT sample at the only place it's defined: the first emit
+                if req.first_token_at is not None:
+                    self._ttfts.append(
+                        req.first_token_at - req.submitted_at)
 
     def _admit_one(self, req: _Request, slot: int) -> None:
         phase = self._phases.phase
@@ -1629,9 +1763,10 @@ class GenerationEngine:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :t] = req.prompt
             tokens, true_len = jnp.asarray(padded), jnp.int32(t)
-            key = self._request_prefill_key(req, start)
-        # the dispatch; _finish_admission waits for the first token under
-        # the same phase, after it has queued the splice behind the prefill
+            key, skey = self._request_keys(req, start)
+        # the dispatch only: _finish_admission queues the splice behind it and
+        # _emit_firsts waits for the first token, under the same phase, once
+        # the decode block has been queued behind both
         with phase("admit.prefill"):
             if req.prefix_id is not None:
                 first, k_new, v_new, flp = _prefill_suffix(
@@ -1654,7 +1789,7 @@ class GenerationEngine:
                         self.params, tokens, true_len, key, temps, self.cfg,
                         top_k=self.top_k, **lkw, **pkw)
         self._finish_admission(req, slot, first, flp, k_new, v_new, start,
-                               temp, tp, row, aidx, bias_vec=bias_vec)
+                               temp, tp, row, aidx, skey, bias_vec=bias_vec)
 
     def _emit(self, slot: int, tok: int,
               logprob: Optional[float] = None) -> None:
@@ -1683,107 +1818,203 @@ class GenerationEngine:
             self._retire_slot(slot)
 
     def step(self) -> int:
-        """Admit pending requests, then decode one BLOCK of tokens
-        (``decode_block`` device steps, default 1) for every active slot.
-        Returns the remaining work — active slots plus queued requests — so
-        ``while eng.step(): ...`` runs the backlog dry even when a step
-        retires every active slot with the queue non-empty."""
+        """One pass of the engine loop: at most one decode block dispatched
+        (``decode_block`` device steps, default 1, for every slot) and at
+        most one fetched and emitted. With nothing in flight the pass is a
+        batch boundary: hooks run, pending requests are admitted, the block
+        is dispatched behind their prefills and their first tokens emitted.
+        Then the block is fetched and emitted — unless its successor may
+        run ahead (:meth:`_may_run_ahead`), in which case it stays in
+        flight and the NEXT pass dispatches the successor from its device
+        carry before fetching it, and so on, one block ahead, until
+        something could be seated at a boundary. Returns the remaining work
+        — active slots plus queued requests, or the blocks still in flight
+        when there is no other — so ``while eng.step(): ...`` runs the
+        backlog dry and ends with nothing in flight."""
         with self._mesh_scope():
             return self._step_once()
 
+    def _may_run_ahead(self) -> bool:
+        """May the successor of the one block in flight be dispatched before
+        that block is fetched? Only when nothing could be seated at the
+        boundary between them and the boundary is owed to no one: every
+        slot holds a live request, none is pending or mid-chunked-admission,
+        no hook is queued, the engine is not stopping — and some request's
+        budget outlasts the block in flight (else the successor is garbage
+        for every slot, and a request arriving meanwhile would wait for
+        it). All of it is state the engine sees now; anything else keeps
+        the order fetch, emit, admit, dispatch."""
+        ahead = self.decode_block * len(self._inflight)
+        return (not self._stop.is_set() and not self._boundary_hooks
+                and self._chunking is None and not self._pending
+                and all(r is not None and not r.cancelled
+                        for r in self._slot_req)
+                and any(r.max_new_tokens - r.generated > ahead
+                        for r in self._slot_req))
+
     def _step_once(self) -> int:
         phase = self._phases.phase
-        # boundary hooks first: we are BETWEEN decode batches here (the
-        # previous dispatch retired at the end of the last _step_once), so
-        # a weight swap scheduled via at_batch_boundary never overlaps a
-        # decode dispatch on the old params
+        boundary = not self._inflight
+        # boundary hooks only BETWEEN decode batches: every dispatched
+        # block has been fetched and emitted and nothing is queued on the
+        # device, so a weight swap scheduled via at_batch_boundary never
+        # overlaps a decode dispatch on the old params. A queued hook stops
+        # the run-ahead, so the next pass but one at the latest is here.
         with phase("hooks"):
-            self._run_boundary_hooks()
+            if boundary:
+                self._run_boundary_hooks()
             self._reap_cancelled()
-        self._admit()
-        active = [i for i, r in enumerate(self._slot_req) if r is not None]
-        if active:
-            self._phases.blocks += 1
-            with phase("upload"):
-                with self._lock:
-                    banks = self._banks
-                # once a bank exists every step pays the per-slot gather,
-                # base traffic included (aidx 0 = the zero adapter) — the
-                # price of one shared compiled step
-                lkw = ({"banks": banks, "aidx": jnp.asarray(self._aidx),
-                        "lora_scale": self._lora_cfg.scale} if banks else {})
-                if self._nucleus:
-                    lkw["top_ps"] = jnp.asarray(self._top_ps)
-                if self._counts is not None:
-                    lkw.update(counts=self._counts,
-                               fpen=jnp.asarray(self._fpen),
-                               ppen=jnp.asarray(self._ppen))
-                if self._bias is not None:
-                    lkw.update(bias=self._bias,
-                               bmask=jnp.asarray(self._bmask))
-                lkw["skeys"] = jnp.asarray(self._skeys)
-                pos, tok = jnp.asarray(self._pos), jnp.asarray(self._tok)
-                key, temps = self._next_key(), jnp.asarray(self._temps)
-            # always the FULL configured block — never a tail-sized one:
-            # n_steps is a static argname, so a variable tail would compile
-            # a fresh variant mid-serving (a multi-second stall for every
-            # concurrent stream) to save at most K-1 ~ms-scale garbage
-            # steps on the final dispatch of a draining backlog
-            k = self.decode_block
-            # common decode signature (lkw is exactly {skeys}: no banks,
-            # nucleus, penalties, or bias): the warm AOT executable takes
-            # the dispatch; sticky features fall back to the traced jits
-            aot = (self._aot_exec.get(("decode", k))
-                   if set(lkw) == {"skeys"} else None)
-            with phase("dispatch"):
-                if k > 1:
-                    if aot is not None:
-                        (self._cache, _fp, _ft, toks_k, lps_k,
-                         counts) = aot(
-                            self.params, self._cache, pos, tok, key, temps,
-                            skeys=lkw["skeys"])
-                    else:
-                        (self._cache, _fp, _ft, toks_k, lps_k,
-                         counts) = _decode_block(
-                            self.params, self._cache, pos, tok, key, temps,
-                            self.cfg, n_steps=k, top_k=self.top_k, **lkw)
-                    if self._counts is not None:
-                        self._counts = counts
-                else:
-                    if aot is not None:
-                        out = aot(
-                            self.params, self._cache, pos, tok, key, temps,
-                            skeys=lkw["skeys"])
-                    else:
-                        out = _decode_step(
-                            self.params, self._cache, pos, tok, key, temps,
-                            self.cfg, top_k=self.top_k, **lkw)
-                    if self._counts is not None:
-                        self._cache, nxt, lps, self._counts = out
-                    else:
-                        self._cache, nxt, lps = out
-                    toks_k, lps_k = nxt[None], lps[None]    # (1, B)
-            with phase("fetch"):
-                toks_k, lps_k = np.asarray(toks_k), np.asarray(lps_k)
-            self._steps += k
-            with phase("emit"):
-                for i in range(k):
-                    for slot in active:
-                        # a slot retired at emit i' < i skips the rest of
-                        # its block (garbage past the stop point). Each
-                        # emitted token consumed position _pos[slot]; the
-                        # next feeds back one position later.
-                        if self._slot_req[slot] is None:
-                            continue
-                        self._pos[slot] += 1
-                        self._tok[slot] = int(toks_k[i, slot])
-                        self._emit(slot, int(toks_k[i, slot]),
-                                   float(lps_k[i, slot]))
+        if boundary:
+            self._admit()
+            if any(r is not None for r in self._slot_req):
+                self._dispatch(ahead=False)
+            self._emit_firsts()
+            # depth 0, today's order, unless the successor may run ahead:
+            # then this block stays in flight for the next pass
+            if self._inflight and not self._may_run_ahead():
+                self._collect()
+        else:
+            if self._may_run_ahead():
+                self._dispatch(ahead=True)
+            self._collect()
         self._phases.end_block()
         with self._lock:
             queued = len(self._pending)
         return (sum(r is not None for r in self._slot_req) + queued
-                + (1 if self._chunking is not None else 0))
+                + (1 if self._chunking is not None else 0)
+                or len(self._inflight))
+
+    def _pack_patch(self) -> np.ndarray:
+        """The host's mirrors as one carry patch (``_patch_carry``), marked
+        for the slots seated or retired since the last one: one upload a
+        block, whatever changed."""
+        patch = np.zeros((self.slots, _PATCH_WIDTH), np.uint32)
+        patch[sorted(self._dirty), 0] = 1
+        patch[[slot for _req, slot, _first, _lp in self._seating], 1] = 1
+        names = ("pos", "tok", *(name for name, _ in _PATCH_VECTORS))
+        for col, name in enumerate(names, start=2):
+            patch[:, col] = getattr(self, "_" + name).view(np.uint32)
+        return patch
+
+    def _dispatch(self, ahead: bool) -> None:
+        """Queue one decode block on the device, from the newest block's
+        device carry patched with the slots the host seated or retired since
+        (none, when it runs ahead), and record it in flight. ``self._cache``
+        and ``self._counts`` are reassigned to the block's donated outputs,
+        so two blocks in flight chain them output to input."""
+        phase = self._phases.phase
+        self._phases.blocks += 1
+        with phase("upload"):
+            with self._lock:
+                banks = self._banks
+            carry = _patch_carry(*self._carry, self._firsts, self._skeys,
+                                 self._pack_patch())
+            self._dirty.clear()
+            # once a bank exists every step pays the per-slot gather,
+            # base traffic included (aidx 0 = the zero adapter) — the
+            # price of one shared compiled step
+            lkw = ({"banks": banks, "aidx": carry["aidx"],
+                    "lora_scale": self._lora_cfg.scale} if banks else {})
+            if self._nucleus:
+                lkw["top_ps"] = carry["top_ps"]
+            if self._counts is not None:
+                lkw.update(counts=self._counts, fpen=carry["fpen"],
+                           ppen=carry["ppen"])
+            if self._bias is not None:
+                lkw.update(bias=self._bias, bmask=carry["bmask"])
+            lkw["skeys"] = carry["skeys"]
+            pos, tok, temps = carry["pos"], carry["tok"], carry["temps"]
+            key = self._next_key()
+        # always the FULL configured block — never a tail-sized one:
+        # n_steps is a static argname, so a variable tail would compile
+        # a fresh variant mid-serving (a multi-second stall for every
+        # concurrent stream) to save at most K-1 ~ms-scale garbage
+        # steps on the final dispatch of a draining backlog
+        k = self.decode_block
+        # common decode signature (lkw is exactly {skeys}: no banks,
+        # nucleus, penalties, or bias): the warm AOT executable takes
+        # the dispatch; sticky features fall back to the traced jits
+        aot = (self._aot_exec.get(("decode", k))
+               if set(lkw) == {"skeys"} else None)
+        with phase("dispatch"):
+            if k > 1:
+                if aot is not None:
+                    (self._cache, pos, tok, toks_k, lps_k, counts) = aot(
+                        self.params, self._cache, pos, tok, key, temps,
+                        skeys=lkw["skeys"])
+                else:
+                    (self._cache, pos, tok, toks_k, lps_k,
+                     counts) = _decode_block(
+                        self.params, self._cache, pos, tok, key, temps,
+                        self.cfg, n_steps=k, top_k=self.top_k, **lkw)
+                if self._counts is not None:
+                    self._counts = counts
+            else:
+                if aot is not None:
+                    out = aot(
+                        self.params, self._cache, pos, tok, key, temps,
+                        skeys=lkw["skeys"])
+                else:
+                    out = _decode_step(
+                        self.params, self._cache, pos, tok, key, temps,
+                        self.cfg, top_k=self.top_k, **lkw)
+                if self._counts is not None:
+                    self._cache, tok, lps, self._counts = out
+                else:
+                    self._cache, tok, lps = out
+                pos = pos + 1
+                toks_k, lps_k = tok[None], lps[None]    # (1, B)
+            # the block's own final pos / tok are its successor's inputs
+            self._carry = (pos, tok)
+            toks_k.copy_to_host_async()
+            lps_k.copy_to_host_async()
+        self._steps += k
+        self._blocks_ahead += ahead
+        self._inflight.append(
+            _Flight(list(self._slot_req), toks_k, lps_k, ahead))
+
+    def _collect(self) -> None:
+        """Fetch the oldest block in flight and emit its tokens where the
+        slot still holds the request it held at dispatch: a slot that was
+        retired, cancelled or reseated since computed garbage there, as one
+        that retires mid-block does (``_decode_block``). A device error
+        surfacing here fails the requests seated in the block and leaves
+        the loop alive."""
+        phase = self._phases.phase
+        flight = self._inflight.popleft()
+
+        def live():
+            return [(slot, req) for slot, req in enumerate(flight.reqs)
+                    if req is not None and self._slot_req[slot] is req]
+
+        try:
+            with phase("fetch"):
+                toks_k, lps_k = np.asarray(flight.toks), np.asarray(flight.lps)
+        except Exception as e:   # noqa: BLE001 — per-block failure
+            for slot, req in live():
+                req.error = e
+                self._retire_slot(slot)
+            return
+        with phase("emit"):
+            for _slot, req in live():
+                req.blocks += 1
+                req.blocks_ahead += flight.ahead
+            for i in range(toks_k.shape[0]):
+                # a slot retired at emit i' < i skips the rest of its
+                # block (garbage past the stop point). Each emitted token
+                # consumed position _pos[slot]; the next feeds back one
+                # position later.
+                for slot, _req in live():
+                    self._pos[slot] += 1
+                    self._tok[slot] = int(toks_k[i, slot])
+                    self._emit(slot, int(toks_k[i, slot]),
+                               float(lps_k[i, slot]))
+
+    def _drain(self) -> None:
+        """Fetch and emit every block in flight (stepping thread, or the
+        caller that stands in for it)."""
+        while self._inflight:
+            self._collect()
 
     def _run(self) -> None:
         while not self._stop.is_set():
@@ -1791,6 +2022,10 @@ class GenerationEngine:
             if n == 0 and not self._pending:
                 self._work.clear()
                 self._work.wait(timeout=0.5)
+        # a stopped engine has nothing in flight: its seated requests keep
+        # every token that was dispatched for them
+        with self._mesh_scope():
+            self._drain()
 
     def start(self) -> "GenerationEngine":
         with self._lifecycle:
@@ -1856,7 +2091,8 @@ class GenerationEngine:
             decode_steps=self._steps,
             tokens_per_sec=self._tokens / dt,
             ttft_avg=(sum(self._ttfts) / len(self._ttfts)
-                      if self._ttfts else 0.0))
+                      if self._ttfts else 0.0),
+            blocks_run_ahead=self._blocks_ahead)
 
     def __kt_metrics__(self) -> Dict[str, float]:
         """Pod-scrape hook (``serving.process_worker`` — the
@@ -1874,6 +2110,7 @@ class GenerationEngine:
                "engine_finished_total": float(s.finished_total),
                "engine_tokens_generated": float(s.tokens_generated),
                "engine_decode_steps": float(s.decode_steps),
+               "engine_blocks_run_ahead_total": float(s.blocks_run_ahead),
                "engine_tokens_per_sec": float(s.tokens_per_sec),
                "engine_ttft_avg_seconds": float(s.ttft_avg),
                "engine_prefix_hits": float(self._prefix_hits)}

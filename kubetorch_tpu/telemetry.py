@@ -927,8 +927,9 @@ def engine_timing(life: Dict[str, Any]) -> Dict[str, Any]:
     for key in ("queue", "prefill", "decode", "host", "wait"):
         if life.get(key + "_s") is not None:
             out["engine." + key] = life[key + "_s"]
-    if life.get("blocks") is not None:
-        out["engine.blocks"] = int(life["blocks"])
+    for key in ("blocks", "blocks_ahead"):
+        if life.get(key) is not None:
+            out["engine." + key] = int(life[key])
     for key, value in life.items():
         if key.startswith(("host.", "wait.")):
             out["engine." + key[:-2]] = value       # strip the ``_s``

@@ -48,6 +48,9 @@ def test_request_timeline_adds_up(dense):
         assert abs(tl["queue_s"] + tl["prefill_s"]
                    - h.time_to_first_token()) < 1e-3
         assert tl["blocks"] >= 1 and tl["tokens"] == len(h.result(0))
+        # of the blocks it sat in, those dispatched ahead of their
+        # predecessor's fetch; fetch then spans a block and is still a wait
+        assert 0 <= tl["blocks_ahead"] <= tl["blocks"]
         assert tl["queue_s"] >= 0 and tl["prefill_s"] > 0
         assert tl["host_s"] > 0 and tl["wait_s"] >= 0
         assert tl["host_s"] + tl["wait_s"] \
@@ -62,6 +65,10 @@ def test_request_timeline_adds_up(dense):
         assert not tl["cancelled"]
     waited = handles[2].timeline()
     assert waited["queue_s"] > handles[0].timeline()["queue_s"]
+    # both slots seated and no one waiting: the engine ran ahead, and the
+    # requests that sat in those blocks say so
+    assert eng.stats().blocks_run_ahead > 0
+    assert sum(h.timeline()["blocks_ahead"] for h in handles) > 0
 
 
 def test_phase_counters_cover_the_stepping_thread(dense):
@@ -141,6 +148,9 @@ def test_result_reports_once_on_the_callers_span(dense, monkeypatch):
     sent = tel.parse_timing(tel.format_timing(
         tel.engine_timing(events[0]["attrs"])))
     assert sent["engine.blocks"] == h.timeline()["blocks"]
+    assert sent["engine.blocks_ahead"] == h.timeline()["blocks_ahead"]
+    assert isinstance(tel.engine_timing(h.timeline())["engine.blocks_ahead"],
+                      int)
     assert sent["engine.queue_ms"] == pytest.approx(
         1e3 * h.timeline()["queue_s"], abs=1e-3)
     assert any(k.startswith("engine.host.") for k in sent)

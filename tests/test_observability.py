@@ -771,6 +771,7 @@ class TestTimelineBackToTheCaller:
         assert sum(stages.values()) <= 2 * took_ms
         # the engine's account of the request, from inside
         assert attrs["engine.blocks"] >= 1
+        assert 0 <= attrs["engine.blocks_ahead"] <= attrs["engine.blocks"]
         for key in ("engine.queue_ms", "engine.prefill_ms",
                     "engine.decode_ms", "engine.host_ms", "engine.wait_ms"):
             assert 0.0 <= attrs[key] <= attrs["rank.execute_ms"], key

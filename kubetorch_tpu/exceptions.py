@@ -206,6 +206,27 @@ class RolloutError(KubetorchError):
         self.actual = actual
 
 
+class UnsupportedMechanismError(KubetorchError, NotImplementedError):
+    """A serving mechanism that a model family's cache kind does not carry
+    yet (ISSUE 33).
+
+    Raised where the mechanism is asked for — at ``GenerationEngine``
+    construction for an option (``quantize_kv``, ``prefill_chunk``,
+    ``auto_prefix``, an AOT cache, a sharded mesh, ``SpeculativeEngine``),
+    at the call for a method (``register_prefix``, ``register_adapter``,
+    ``models.generate.generate``) — so nothing runs silently wrong.
+    ``mechanism`` names what was asked for and ``cache_kind`` the cache that
+    lacks it (``"latent"``: the MLA family of ``models.mla``)."""
+
+    def __init__(self, mechanism: str, cache_kind: str = "latent",
+                 hint: str = ""):
+        super().__init__(
+            f"{mechanism} is not served over a {cache_kind} attention cache "
+            f"yet{': ' + hint if hint else ''}")
+        self.mechanism = mechanism
+        self.cache_kind = cache_kind
+
+
 class AOTCacheMissError(KubetorchError):
     """The persistent AOT compile cache holds no entry for this key
     (ISSUE 16).

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .block import decoder_block, dense_ffn, rmsnorm
+from .block import decoder_block, dense_ffn, qkv_attend, rmsnorm
 from .llama import LlamaConfig, rope_freqs
 from .moe import MoeConfig, moe_ffn, moe_ffn_decode
 
@@ -45,6 +45,11 @@ class KVCache(NamedTuple):
 def init_cache(cfg: "LlamaConfig | MoeConfig", batch: int, max_len: int,
                dtype=None) -> KVCache:
     """Zeroed row-major cache (L, B, S_max, NKV, Hd)."""
+    if getattr(cfg, "cache_kind", "kv") != "kv":
+        from ..exceptions import UnsupportedMechanismError
+        raise UnsupportedMechanismError(
+            "the scanned generate path (models.generate)", cfg.cache_kind,
+            "serve it through serve.GenerationEngine")
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     dtype = dtype or cfg.dtype
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
@@ -190,10 +195,11 @@ def _layer_step(cfg, x, lw, layer_cache_k, layer_cache_v, q_pos, freqs_full,
     this LAYER's factors per target — the unmerged activation-path adapters
     multi-LoRA serving runs."""
     x, (layer_cache_k, layer_cache_v), _ = decoder_block(
-        cfg, x, lw, freqs_full[q_pos],
-        cache_attend(cfg, layer_cache_k, layer_cache_v, q_pos,
-                     flash_prefill=flash_prefill,
-                     causal_prefill=causal_prefill),
+        cfg, x, lw,
+        qkv_attend(cfg, freqs_full[q_pos],
+                   cache_attend(cfg, layer_cache_k, layer_cache_v, q_pos,
+                                flash_prefill=flash_prefill,
+                                causal_prefill=causal_prefill)),
         partial(ffn_block, cfg, token_mask=token_mask,
                 keep_capacity=keep_capacity, moe_no_drop=moe_no_drop),
         lora=lora)
@@ -202,7 +208,7 @@ def _layer_step(cfg, x, lw, layer_cache_k, layer_cache_v, q_pos, freqs_full,
 
 def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],
               token_mask=None, keep_capacity=None,
-              moe_no_drop: bool = False):
+              moe_no_drop: bool = False, banks=None):
     """The block's FFN operation for a decode/prefill layer, (out, aux) —
     dense SwiGLU, or the MoE dispatch when the layer carries a ``router``
     leaf. Shared by the scanned ``generate`` path and the continuous-batching
@@ -221,8 +227,17 @@ def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],
     and re-reads them in the einsum (~2x beyond the read), so it must beat
     the dispatch path's single stream of all E experts with margin — hence
     2*B*K <= E, not B*K <= E. All inputs are static at trace time ⇒ the
-    choice is fixed per compile."""
+    choice is fixed per compile.
+
+    ``banks``: where the stack keeps a run's expert weights out of the
+    scanned leaves, (the whole of them, this layer's index in the run), bound
+    by ``block.with_banks``."""
     b, t = h.shape[0], h.shape[1]
+    if "router_bias" in lw:
+        # sigmoid bias-corrected routing, shared experts, no capacity
+        from .mla import moe_ffn_dropless
+        return moe_ffn_dropless(cfg, h, lw, token_mask=token_mask,
+                                banks=banks)
     if "router" in lw:
         from ..parallel.mesh import AXIS_EXPERT
         from ..parallel.mesh_context import axis_size, current_mesh

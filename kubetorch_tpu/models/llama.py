@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .block import (apply_rope,  # noqa: F401  (its importers' home)
-                    decoder_block, dense_ffn, rmsnorm)
+                    decoder_block, dense_ffn, qkv_attend, rmsnorm)
 
 
 @dataclass(frozen=True)
@@ -122,8 +122,11 @@ def llama_init(rng: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
 
 
 def rope_freqs(cfg: LlamaConfig, seq_len: int) -> jax.Array:
-    """(S, Hd/2) complex rotation table, fp32."""
-    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim))
+    """(S, Hd/2) complex rotation table, fp32. Hd is the head's width, or
+    the config's ``rope_dim`` where only a part of a head is rotated
+    (``models.mla``)."""
+    hd = getattr(cfg, "rope_dim", None) or cfg.head_dim
+    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     # getattr: callers pass MoeConfig here too (no rope_scaling field)
     rs = getattr(cfg, "rope_scaling", None)
     if rs is not None:
@@ -233,7 +236,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lw: Dict[str, jax.Array],
     shard_map (``models.block.decoder_block`` takes the head counts from the
     local shapes, so one body serves both paths)."""
     psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else None
-    x, _, _ = decoder_block(cfg, x, lw, freqs, self_attend(cfg),
+    x, _, _ = decoder_block(cfg, x, lw, qkv_attend(cfg, freqs, self_attend(cfg)),
                             partial(dense_ffn, reduce=psum), reduce=psum)
     return x
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .block import decoder_block, rmsnorm
+from .block import decoder_block, qkv_attend, rmsnorm
 from .llama import LlamaConfig, rope_freqs, self_attend
 
 
@@ -322,7 +322,7 @@ def _moe_layer(cfg: MoeConfig, carry, lw: Dict[str, jax.Array], freqs,
     x, aux_sum = carry
     psum = (lambda y: lax.psum(y, tp_axis)) if tp_axis else None
     x, _, aux = decoder_block(
-        cfg, x, lw, freqs, self_attend(cfg._llama_view()),
+        cfg, x, lw, qkv_attend(cfg, freqs, self_attend(cfg._llama_view())),
         partial(moe_ffn, cfg, ep_axis=ep_axis, tp_axis=tp_axis), reduce=psum)
     return (x, aux_sum + aux)
 

@@ -94,7 +94,7 @@ class AOTKey:
         import jax
         import jaxlib
 
-        from .engine import GRID_LAYOUT
+        from .engine import _cache_ops
 
         mesh = getattr(engine, "_mesh", None)
         mesh_shape = (tuple(sorted(dict(mesh.shape).items()))
@@ -108,7 +108,7 @@ class AOTKey:
             quantize_kv=engine.quantize_kv,
             decode_block=engine.decode_block,
             top_k=engine.top_k,
-            grid_layout=GRID_LAYOUT,
+            grid_layout=_cache_ops(engine.cfg).layout,
             jax_version=jax.__version__,
             jaxlib_version=getattr(jaxlib, "__version__", ""),
             backend=jax.default_backend(),

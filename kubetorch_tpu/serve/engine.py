@@ -48,7 +48,8 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence)
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +57,10 @@ import numpy as np
 from jax import lax
 
 from .. import telemetry
-from ..models.block import decoder_block, rmsnorm
-from ..models.generate import (KVCache, _layer_step, ffn_block, init_cache,
-                               rope_freqs)
+from ..models.block import (decoder_block, layer_stacks, qkv_attend,
+                            rmsnorm, with_banks)
+from ..models.generate import (KVCache, _layer_step, cache_attend, ffn_block,
+                               init_cache, rope_freqs)
 from ..models.moe import moe_prefill_keep_capacity as _moe_keep_capacity
 from ..models.quant import lm_head_dot
 
@@ -143,6 +145,39 @@ def init_grid_cache(cfg, slots: int, max_len: int) -> KVCache:
     shape = (cfg.n_layers, slots, cfg.n_kv_heads, max_len, cfg.head_dim)
     return KVCache(k=jnp.zeros(shape, cfg.dtype),
                    v=jnp.zeros(shape, cfg.dtype))
+
+
+class _CacheOps(NamedTuple):
+    """What a cache kind supplies to the engine's one prefill, one decode
+    step and one cache manager: the grid, a prompt's row-major rows, and the
+    block's mixing operation over each (``models.block.decoder_block``).
+    Everything else — the in-place row writes, the splice, the sharding
+    pin, the donated carry, the host loop — is the engine's, for every
+    kind."""
+    layout: str            # part of ``aot_cache.AOTKey``
+    init_grid: Callable    # (cfg, slots, max_len) -> grid
+    init_rows: Callable    # (cfg, batch, t) -> a prompt's rows, row-major
+    rows_mix: Callable     # (cfg, layer_rows, q_pos, freqs_full, **kw)
+    grid_mix: Callable     # (cfg, grid, layer, pos, freqs)
+
+
+_KV_OPS = _CacheOps(
+    GRID_LAYOUT, init_grid_cache, init_cache,
+    lambda cfg, rows, q_pos, freqs_full, **kw: qkv_attend(
+        cfg, freqs_full[q_pos], cache_attend(cfg, *rows, q_pos, **kw)),
+    lambda cfg, grid, layer, pos, freqs: qkv_attend(
+        cfg, freqs, grid_attend(cfg, grid, layer, pos)))
+
+
+def _cache_ops(cfg) -> _CacheOps:
+    """Per-head K/V rows unless the config names another cache kind
+    (``models.mla.MlaMoeConfig.cache_kind``: latent rows, whose module is
+    imported here and nowhere earlier)."""
+    if getattr(cfg, "cache_kind", "kv") == "latent":
+        from . import latent_cache as lc
+        return _CacheOps(lc.GRID_LAYOUT, lc.init_grid, lc.init_rows,
+                         lc.rows_mix, lc.grid_mix)
+    return _KV_OPS
 
 
 def _write_rows(grid, layer, pos, rows):
@@ -296,7 +331,7 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
                       top_k: Optional[int] = None, banks=None, aidx=None,
                       lora_scale: float = 1.0, top_ps=None,
                       counts=None, fpen=None, ppen=None,
-                      bias=None, bmask=None, skeys=None):
+                      bias=None, bmask=None, skeys=None, live=None):
     """Single-step decode math shared by the jitted one-step
     :func:`_decode_step` and the scanned K-step :func:`_decode_block`.
     ``bias`` (SLOTS, V) + ``bmask`` (SLOTS,): per-slot OpenAI logit_bias,
@@ -307,9 +342,15 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     of (its key, its positions), independent of neighbors, step batching,
     and the engine-wide chain (what makes per-request ``seed`` exact and
     block decode bit-equal to one-step even when sampling).
-    Always returns the 4-tuple (cache', next_tok, logprobs, counts') —
-    ``counts'`` is None when ``counts`` is."""
-    n_layers, s_max = cache[0].shape[0], cache[0].shape[3]
+    ``live`` (SLOTS,) int32, given only where the engine keeps a routing
+    tally: non-zero for the slots that hold a request (a column of the
+    carry patch); the others' tokens claim no expert
+    and count nothing, and the expert layers' tallies come back stacked.
+    Always returns the 5-tuple (cache', next_tok, logprobs, counts',
+    routed) — ``counts'`` is None when ``counts`` is, ``routed`` when
+    ``live`` is."""
+    s_max = cache[0].shape[3]
+    ops = _cache_ops(cfg)
     x = params["embed"][toks[:, None]].astype(cfg.dtype)   # (B, 1, D)
     freqs = rope_freqs(cfg, s_max)[pos][:, None]            # (B, 1, Hd/2)
 
@@ -318,20 +359,28 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     # the grid rides in the CARRY, the layer index beside the weights:
     # scanned as ``xs``/``ys``, XLA builds a second grid every step and
     # slices every layer out of one and writes it back into the other
-    def body(carry, layer):
+    def body(whole, start, carry, layer):
         h, grid = carry
         lw, l, bank_l = layer
         # per-slot adapters gathered to (B, D, R)/(B, R, O) per target
         # (multi-LoRA serving — see ``GenerationEngine`` docs)
         lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
-        h, grid, _ = decoder_block(cfg, h, lw, freqs,
-                                   grid_attend(cfg, grid, l, pos),
-                                   partial(ffn_block, cfg), lora=lora)
-        return (h, grid), None
+        h, grid, aux = decoder_block(cfg, h, lw,
+                                     ops.grid_mix(cfg, grid, l, pos, freqs),
+                                     with_banks(ffn, whole, l - start),
+                                     lora=lora)
+        return (h, grid), (None if live is None else aux)
 
-    (x, new_cache), _ = lax.scan(
-        body, (x, cache),
-        (params["layers"], jnp.arange(n_layers), banks or {}))
+    ffn = (partial(ffn_block, cfg) if live is None
+           else partial(ffn_block, cfg, token_mask=(live != 0)[:, None]))
+    carry, routed = (x, cache), None
+    for stack, whole, start, n in layer_stacks(cfg, params):
+        # adapter banks are stacked over one run of layers
+        carry, aux = lax.scan(
+            partial(body, whole, start), carry,
+            (stack, jnp.arange(start, start + n), banks or {}))
+        routed = aux if aux is not None else routed
+    x, new_cache = carry
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head_dot(x[:, 0], params, cfg.dtype)
     raw_logits = logits
@@ -351,7 +400,7 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
                              lp_logits=raw_logits, keys=step_keys)
     if counts is not None:
         counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(1)
-    return _constrain_cache(new_cache), nxt, lps, counts
+    return _constrain_cache(new_cache), nxt, lps, counts, routed
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "lora_scale"),
@@ -360,22 +409,26 @@ def _decode_step(params, cache, pos, toks, rng, temps, cfg,
                  top_k: Optional[int] = None, banks=None, aidx=None,
                  lora_scale: float = 1.0, top_ps=None,
                  counts=None, fpen=None, ppen=None,
-                 bias=None, bmask=None, skeys=None):
+                 bias=None, bmask=None, skeys=None, tally=None, live=None):
     """Advance EVERY slot one token. toks (B,) is each slot's current input
     token; pos (B,) its absolute position; temps (B,) its sampling
     temperature. ``banks`` (target → (A (L,N,D,R), B (L,N,R,O))) + ``aidx``
     (B,) select each slot's LoRA adapter (index 0 = the zero adapter =
     base model). ``cache`` is the head-major grid, a ``KVCache`` or an int8
     ``QuantKVCache`` (``kv_quant``) — the pytree structure keys the jit, so
-    each engine compiles exactly one of the two bodies. Returns
-    (cache', next_tok)."""
-    cache, nxt, lps, counts = _decode_step_impl(
+    each engine compiles exactly one of the bodies. Returns
+    (cache', next_tok, logprobs), then ``counts'`` and ``tally'`` (the
+    routing tally; ``live`` comes with it) where they were given."""
+    cache, nxt, lps, counts, routed = _decode_step_impl(
         params, cache, pos, toks, rng, temps, cfg, top_k=top_k, banks=banks,
         aidx=aidx, lora_scale=lora_scale, top_ps=top_ps, counts=counts,
-        fpen=fpen, ppen=ppen, bias=bias, bmask=bmask, skeys=skeys)
+        fpen=fpen, ppen=ppen, bias=bias, bmask=bmask, skeys=skeys, live=live)
+    out = (cache, nxt, lps)
     if counts is not None:
-        return cache, nxt, lps, counts
-    return cache, nxt, lps
+        out += (counts,)
+    if tally is not None:
+        out += (tally + routed,)
+    return out
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "lora_scale", "n_steps"),
@@ -384,7 +437,7 @@ def _decode_block(params, cache, pos, toks, rng, temps, cfg, n_steps: int,
                   top_k: Optional[int] = None, banks=None, aidx=None,
                   lora_scale: float = 1.0, top_ps=None,
                   counts=None, fpen=None, ppen=None,
-                  bias=None, bmask=None, skeys=None):
+                  bias=None, bmask=None, skeys=None, tally=None, live=None):
     """Advance every slot ``n_steps`` tokens in ONE dispatch: a ``lax.scan``
     over :func:`_decode_step_impl`, so the host pays the dispatch/sync
     overhead once per block instead of once per token — the difference
@@ -411,22 +464,29 @@ def _decode_block(params, cache, pos, toks, rng, temps, cfg, n_steps: int,
     computed (a request's budget ends at or before that row). Rows past a
     retired frontier are never attended before being rewritten, and the
     next occupant writes every row before it attends it — so the garbage
-    is unobservable. Returns
-    (cache', final_pos, final_tok, toks (K, B), logprobs (K, B), counts')."""
+    is unobservable. ``tally`` (L_moe, 2, E) int32, where the engine keeps
+    one: the routed (token, choice) pairs of the ``live`` slots an expert
+    got, and the steps in which it got any, accumulated in this scan's carry
+    and read back only when someone reads ``stats()``. Returns
+    (cache', final_pos, final_tok, toks (K, B), logprobs (K, B), counts'),
+    and ``tally'`` behind them where one was given."""
 
     def step_fn(carry, k):
-        cache, pos, toks, counts = carry
+        cache, pos, toks, counts, tally = carry
         key = jax.random.fold_in(rng, k)
-        cache, nxt, lps, counts = _decode_step_impl(
+        cache, nxt, lps, counts, routed = _decode_step_impl(
             params, cache, pos, toks, key, temps, cfg, top_k=top_k,
             banks=banks, aidx=aidx, lora_scale=lora_scale, top_ps=top_ps,
             counts=counts, fpen=fpen, ppen=ppen, bias=bias, bmask=bmask,
-            skeys=skeys)
-        return (cache, pos + 1, nxt, counts), (nxt, lps)
+            skeys=skeys, live=live)
+        if tally is not None:
+            tally = tally + routed
+        return (cache, pos + 1, nxt, counts, tally), (nxt, lps)
 
-    (cache, pos, toks, counts), (toks_k, lps_k) = lax.scan(
-        step_fn, (cache, pos, toks, counts), jnp.arange(n_steps))
-    return cache, pos, toks, toks_k, lps_k, counts
+    (cache, pos, toks, counts, tally), (toks_k, lps_k) = lax.scan(
+        step_fn, (cache, pos, toks, counts, tally), jnp.arange(n_steps))
+    out = (cache, pos, toks, toks_k, lps_k, counts)
+    return out if tally is None else out + (tally,)
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "lora_scale"))
@@ -436,15 +496,18 @@ def _prefill(params, tokens, true_len, rng, temps, cfg,
     """Prompt pass at one bucket length. tokens (1, T_bucket) right-padded;
     logits are taken at the REAL last position ``true_len - 1`` (padding
     rows only pollute their own cache rows, which decode overwrites before
-    ever attending to them). Returns (first_token (1,), k, v) with k/v
-    (L, 1, T_bucket, NKV, Hd)."""
+    ever attending to them). Returns (first_token (1,), k, v, logprobs)
+    with k/v the prompt's rows, row-major (L, 1, T_bucket, NKV, Hd); a
+    latent cache's rows come as ``k`` (L, 1, T_bucket, 1, C) and ``v`` is
+    None."""
     b, t = tokens.shape
     x = params["embed"][tokens].astype(cfg.dtype)
     freqs_full = rope_freqs(cfg, t)
     q_pos = jnp.arange(t)
     from ..models.generate import _flash_prefill_wanted
     flash = _flash_prefill_wanted(cfg, t)
-    cache = init_cache(cfg, b, t)
+    ops = _cache_ops(cfg)
+    rows = tuple(ops.init_rows(cfg, b, t))
     # Padding must not perturb MoE routing: masked tokens never claim a
     # capacity slot, and the overflow-drop threshold is the REAL length's
     # capacity (the static buffer stays bucket-sized) — so a bucketed
@@ -452,17 +515,29 @@ def _prefill(params, tokens, true_len, rng, temps, cfg,
     token_mask = (q_pos < true_len)[None, :]
     keep_capacity = _moe_keep_capacity(cfg, true_len)
 
-    def body(carry, layer):
-        lw, ck, cv, ad_l = layer
-        lora = (ad_l, lora_scale) if adapter else None
-        h, ck, cv = _layer_step(cfg, carry, lw, ck, cv, q_pos, freqs_full,
-                                flash_prefill=flash, token_mask=token_mask,
-                                keep_capacity=keep_capacity, lora=lora,
-                                causal_prefill=True)
-        return h, (ck, cv)
+    ffn = partial(ffn_block, cfg, token_mask=token_mask,
+                  keep_capacity=keep_capacity)
 
-    x, (nk, nv) = lax.scan(body, x, (params["layers"], cache.k, cache.v,
-                                     adapter or {}))
+    def body(whole, carry, layer):
+        lw, l, rows_l, ad_l = layer
+        lora = (ad_l, lora_scale) if adapter else None
+        h, rows_l, _ = decoder_block(
+            cfg, carry, lw,
+            ops.rows_mix(cfg, rows_l, q_pos, freqs_full, flash_prefill=flash,
+                         causal_prefill=True),
+            with_banks(ffn, whole, l), lora=lora)
+        return h, tuple(rows_l)
+
+    # a run of layers a scan (adapters are stacked over one run only)
+    done = []
+    for stack, whole, start, n in layer_stacks(cfg, params):
+        x, out = lax.scan(partial(body, whole), x, (
+            stack, jnp.arange(n), tuple(r[start:start + n] for r in rows),
+            adapter or {}))
+        done.append(out)
+    rows = (done[0] if len(done) == 1
+            else tuple(jnp.concatenate(r, axis=0) for r in zip(*done)))
+    nk, nv = (*rows, None)[:2]
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     h_last = x[jnp.arange(b), true_len - 1]                 # (1, D)
     logits = lm_head_dot(h_last, params, cfg.dtype)
@@ -548,10 +623,12 @@ def _set_counts_row(counts, slot, row, first=None):
 # patch a block (uint32 bit patterns, a row a slot): seated or retired since
 # the last block?, ``tok`` from the prefill's first token?, the host's ``pos``
 # and ``tok`` for such a slot, and the per-slot vectors that only the host
-# ever changes, a column each in this order.
+# ever changes, a column each in this order (``live``: does the slot hold a
+# request; read by the expert layers' routing tally alone).
 _PATCH_VECTORS = (("temps", jnp.float32), ("aidx", jnp.int32),
                   ("top_ps", jnp.float32), ("fpen", jnp.float32),
-                  ("ppen", jnp.float32), ("bmask", jnp.float32))
+                  ("ppen", jnp.float32), ("bmask", jnp.float32),
+                  ("live", jnp.int32))
 _PATCH_WIDTH = 4 + len(_PATCH_VECTORS)
 
 
@@ -784,6 +861,13 @@ class EngineStats:
     # decode blocks dispatched before their predecessor's tokens had been
     # fetched (of decode_steps / decode_block blocks in all)
     blocks_run_ahead: int = 0
+    # expert layers only (None elsewhere), (L_moe, E) int64 each: the routed
+    # (token, choice) pairs of live slots an expert got in decode steps, and
+    # the decode steps in which it got any. Accumulated on the device;
+    # fetched when this is read at a batch boundary (``at_batch_boundary``),
+    # else the last reading
+    moe_routed_pairs: Any = None
+    moe_expert_hits: Any = None
 
 
 class GenerationEngine:
@@ -859,6 +943,23 @@ class GenerationEngine:
         self._mesh = current_mesh()
         self._buckets = sorted({min(b, self.max_len)
                                 for b in prefill_buckets} | {self.max_len})
+        # the cache kind's operations; a kind other than per-head K/V rows
+        # refuses, here and by name, the mechanisms it does not carry yet
+        self._ops = _cache_ops(cfg)
+        self._refuse(quantize_kv and "int8 KV rows (quantize_kv)",
+                     prefill_chunk is not None
+                     and "chunked prefill (prefill_chunk)",
+                     auto_prefix and "the prefix store (auto_prefix)",
+                     aot_cache is not None
+                     and "the AOT executable cache (aot_cache)",
+                     self._mesh is not None and "a sharded mesh")
+        # routing tally of the expert layers (families that keep one), on
+        # the device; the host's copy is refreshed when stats() is read at a
+        # batch boundary
+        shape = getattr(cfg, "routed_tally_shape", None)
+        self._tally = None if shape is None else jnp.zeros(shape, jnp.int32)
+        self._tally_host = None if shape is None else np.zeros(shape,
+                                                               np.int64)
         if self.quantize_kv:
             # int8 grid (kv_quant): halves the decode HBM stream + cache
             # footprint; prefill/prefix math stays full-precision, rows
@@ -866,7 +967,7 @@ class GenerationEngine:
             from .kv_quant import init_quant_cache
             self._cache = init_quant_cache(cfg, self.slots, self.max_len)
         else:
-            self._cache = init_grid_cache(cfg, self.slots, self.max_len)
+            self._cache = self._ops.init_grid(cfg, self.slots, self.max_len)
         shardings = _cache_shardings(self._cache)
         if shardings is not None:
             # grid lives sharded from step 0 (slots over data axes, the
@@ -975,6 +1076,19 @@ class GenerationEngine:
             from .aot_cache import warm_engine
             self._aot_exec = warm_engine(self, aot_cache)
 
+    def _refuse(self, *mechanisms) -> None:
+        """Raise the typed error for the first named mechanism asked of a
+        cache kind that does not carry it (each argument: the mechanism's
+        name if it was asked for, else falsy). Per-head K/V rows carry
+        all of them."""
+        if self._ops is _KV_OPS:
+            return
+        for m in mechanisms:
+            if m:
+                from ..exceptions import UnsupportedMechanismError
+                raise UnsupportedMechanismError(
+                    m, getattr(self.cfg, "cache_kind", "?"))
+
     # -- adapters -----------------------------------------------------------
 
     def register_adapter(self, adapters: Dict[str, Any], lora_cfg) -> int:
@@ -991,6 +1105,8 @@ class GenerationEngine:
         decode step's shapes — one recompile; prefer registering the fleet
         up front. Freed slots (:meth:`unregister_adapter`) are reused
         without recompiling."""
+        self._refuse("LoRA adapters on the attention projections "
+                     "(register_adapter)")
         layers = adapters.get("layers", adapters)
         served = {"wq", "wk", "wv", "wo"}
         extra = set(lora_cfg.targets) - served
@@ -1181,6 +1297,7 @@ class GenerationEngine:
         computes the prefix K/V through that adapter — pair it with
         requests running the SAME adapter, or the cached rows won't match
         what a solo run would have produced."""
+        self._refuse("the prefix store (register_prefix)")
         tokens = [int(t) for t in tokens]
         if not tokens:
             raise ValueError("empty prefix")
@@ -1667,8 +1784,8 @@ class GenerationEngine:
         Nothing here reads the device back: the slot's key and the first
         sampled token stay there (``_seat_first``; the penalty row counts
         the token there too), the decode block is dispatched behind the
-        prefill before the host waits for it, and :meth:`_emit_firsts`
-        emits it."""
+        prefill before the host waits for it (unless a slot is still free:
+        :meth:`_admit_late`), and :meth:`_emit_firsts` emits it."""
         with self._phases.phase("admit.seat"):
             slot_i = jnp.int32(slot)
             self._cache = _splice_slot(self._cache, slot_i, k_new, v_new)
@@ -1735,6 +1852,28 @@ class GenerationEngine:
                 if req.first_token_at is not None:
                     self._ttfts.append(
                         req.first_token_at - req.submitted_at)
+
+    def _admit_late(self) -> None:
+        """Keep the boundary's admission open while a prefill it dispatched
+        is still running and a slot is still free. A slot freed by the block
+        just fetched is refilled by its caller a fabric round trip later
+        (7-14 ms in the benchmark's closed loop), and ``_admit`` alone closes
+        the moment it finds nothing pending: whether that caller was seated
+        at once or a whole block later then hung on which of two host paths
+        of about equal length was the quicker on that machine. The device is
+        busy with the prefill either way, so the wait costs nothing until it
+        ends; the decode block is then dispatched behind the late prefills,
+        or, when nobody came, after this boundary's first tokens instead of
+        behind them (the time of one dispatch, on these boundaries only).
+        With no free slot, or nothing seated here, nothing could be gained
+        and the order is the usual one."""
+        while (self._seating and self._chunking is None
+               and self._free_slots()):
+            self._emit_firsts()
+            with self._lock:
+                if not self._pending:
+                    return
+            self._admit()
 
     def _admit_one(self, req: _Request, slot: int) -> None:
         phase = self._phases.phase
@@ -1821,8 +1960,10 @@ class GenerationEngine:
         """One pass of the engine loop: at most one decode block dispatched
         (``decode_block`` device steps, default 1, for every slot) and at
         most one fetched and emitted. With nothing in flight the pass is a
-        batch boundary: hooks run, pending requests are admitted, the block
-        is dispatched behind their prefills and their first tokens emitted.
+        batch boundary: hooks run, pending requests are admitted (and, while
+        a slot is still free, whoever arrives before their prefills end:
+        :meth:`_admit_late`), the block is dispatched behind the prefills
+        and their first tokens emitted.
         Then the block is fetched and emitted — unless its successor may
         run ahead (:meth:`_may_run_ahead`), in which case it stays in
         flight and the NEXT pass dispatches the successor from its device
@@ -1866,6 +2007,7 @@ class GenerationEngine:
             self._reap_cancelled()
         if boundary:
             self._admit()
+            self._admit_late()
             if any(r is not None for r in self._slot_req):
                 self._dispatch(ahead=False)
             self._emit_firsts()
@@ -1883,6 +2025,11 @@ class GenerationEngine:
         return (sum(r is not None for r in self._slot_req) + queued
                 + (1 if self._chunking is not None else 0)
                 or len(self._inflight))
+
+    @property
+    def _live(self) -> np.ndarray:
+        """(SLOTS,) int32: 1 where the slot holds a request."""
+        return np.array([r is not None for r in self._slot_req], np.int32)
 
     def _pack_patch(self) -> np.ndarray:
         """The host's mirrors as one carry patch (``_patch_carry``), marked
@@ -1923,6 +2070,8 @@ class GenerationEngine:
             if self._bias is not None:
                 lkw.update(bias=self._bias, bmask=carry["bmask"])
             lkw["skeys"] = carry["skeys"]
+            if self._tally is not None:
+                lkw.update(tally=self._tally, live=carry["live"])
             pos, tok, temps = carry["pos"], carry["tok"], carry["temps"]
             key = self._next_key()
         # always the FULL configured block — never a tail-sized one:
@@ -1943,10 +2092,12 @@ class GenerationEngine:
                         self.params, self._cache, pos, tok, key, temps,
                         skeys=lkw["skeys"])
                 else:
-                    (self._cache, pos, tok, toks_k, lps_k,
-                     counts) = _decode_block(
+                    (self._cache, pos, tok, toks_k, lps_k, counts,
+                     *tally) = _decode_block(
                         self.params, self._cache, pos, tok, key, temps,
                         self.cfg, n_steps=k, top_k=self.top_k, **lkw)
+                    if tally:
+                        self._tally = tally[0]
                 if self._counts is not None:
                     self._counts = counts
             else:
@@ -1958,6 +2109,8 @@ class GenerationEngine:
                     out = _decode_step(
                         self.params, self._cache, pos, tok, key, temps,
                         self.cfg, top_k=self.top_k, **lkw)
+                if self._tally is not None:
+                    *out, self._tally = out
                 if self._counts is not None:
                     self._cache, tok, lps, self._counts = out
                 else:
@@ -2075,8 +2228,23 @@ class GenerationEngine:
         return {"seconds": self._phases.snapshot(),
                 "blocks": self._phases.blocks}
 
+    def _read_tally(self):
+        """The routing tally as (pairs, hits), each (L_moe, E), or (None,
+        None). The device's is fetched only where nothing is in flight (a
+        batch boundary: ``at_batch_boundary`` runs its hook there, on the
+        stepping thread or inline); any other reader — the metrics scrape —
+        gets the last reading and never waits for a block."""
+        if self._tally is None:
+            return None, None
+        thread = self._thread
+        if not self._inflight and (thread is None or not thread.is_alive()
+                                   or threading.current_thread() is thread):
+            self._tally_host = np.asarray(self._tally).astype(np.int64)
+        return self._tally_host[:, 0], self._tally_host[:, 1]
+
     def stats(self) -> EngineStats:
         dt = max(time.monotonic() - self._t0, 1e-9)
+        pairs, hits = self._read_tally()
         return EngineStats(
             slots=self.slots,
             active=sum(r is not None for r in self._slot_req),
@@ -2092,7 +2260,8 @@ class GenerationEngine:
             tokens_per_sec=self._tokens / dt,
             ttft_avg=(sum(self._ttfts) / len(self._ttfts)
                       if self._ttfts else 0.0),
-            blocks_run_ahead=self._blocks_ahead)
+            blocks_run_ahead=self._blocks_ahead,
+            moe_routed_pairs=pairs, moe_expert_hits=hits)
 
     def __kt_metrics__(self) -> Dict[str, float]:
         """Pod-scrape hook (``serving.process_worker`` — the
@@ -2114,6 +2283,14 @@ class GenerationEngine:
                "engine_tokens_per_sec": float(s.tokens_per_sec),
                "engine_ttft_avg_seconds": float(s.ttft_avg),
                "engine_prefix_hits": float(self._prefix_hits)}
+        if s.moe_routed_pairs is not None:
+            out["engine_moe_routed_pairs_total"] = float(
+                s.moe_routed_pairs.sum())
+            out["engine_moe_expert_hits_total"] = float(
+                s.moe_expert_hits.sum())
+            for layer, row in enumerate(s.moe_routed_pairs):
+                out[f"engine_moe_layer{layer}_load_max_over_mean"] = float(
+                    row.max() / max(row.mean(), 1e-9))
         spec = getattr(self, "spec_stats", None)
         if spec is not None:
             out["engine_spec_rounds"] = float(spec.rounds)

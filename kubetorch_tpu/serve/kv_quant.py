@@ -70,11 +70,13 @@ def quantize_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def grid_rows(grid, k: jax.Array, v: jax.Array) -> tuple:
-    """New K/V rows as ``grid``'s leaves take them: (k, v) for a
-    full-precision grid, (kq, ks, vq, vs) for an int8 one (four leaves)."""
+    """New rows as ``grid``'s leaves take them: (k, v) for a
+    full-precision K/V grid, (kq, ks, vq, vs) for an int8 one (four leaves),
+    (k,) for a latent grid (``latent_cache``: one leaf, and its prefill
+    hands back no ``v``)."""
     if len(grid) == 4:
         return (*quantize_rows(k), *quantize_rows(v))
-    return k, v
+    return (k,) if v is None else (k, v)
 
 
 def dequantize_rows(q: jax.Array, scale: jax.Array) -> jax.Array:

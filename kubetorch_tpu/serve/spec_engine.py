@@ -40,7 +40,7 @@ from jax import lax
 
 from .. import telemetry
 
-from ..models.block import decoder_block, rmsnorm
+from ..models.block import decoder_block, qkv_attend, rmsnorm
 from ..models.generate import ffn_block, rope_freqs
 from ..models.quant import lm_head_dot
 from .engine import (GenerationEngine, _decode_block, _einsum_attention,
@@ -122,8 +122,9 @@ def _grid_ingest(params, cache, blocks, start, true_len, cfg,
         # bank layout / zero-adapter convention cannot drift)
         lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
         h, leaves, _ = decoder_block(
-            cfg, h, lw, freqs, window_attend(cfg, leaves, posm, s_eff), ffn,
-            lora=lora)
+            cfg, h, lw,
+            qkv_attend(cfg, freqs, window_attend(cfg, leaves, posm, s_eff)),
+            ffn, lora=lora)
         return h, leaves
 
     x, leaves = lax.scan(body, x, (params["layers"], tuple(cache),
@@ -175,6 +176,11 @@ class SpeculativeEngine(GenerationEngine):
                              "speculation — pass prefix_id explicitly")
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        for c in (cfg, draft_cfg):
+            if getattr(c, "cache_kind", "kv") != "kv":
+                from ..exceptions import UnsupportedMechanismError
+                raise UnsupportedMechanismError(
+                    "speculative decoding (SpeculativeEngine)", c.cache_kind)
         super().__init__(params, cfg, **kwargs)
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg

@@ -181,6 +181,50 @@ def test_four_attend_operations_agree_through_one_block(kind):
         atol=0.05)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_mix_operations_agree_through_one_block(dtype):
+    """The second mixing operation (``models.mla``), three ways through the
+    one block: attention over expanded heads (the plain forward), a bucketed
+    prefill that hands back latent rows, and a token a step into the latent
+    grid with the up-projection absorbed."""
+    from kubetorch_tpu.models.mla import (MlaMoeConfig, mla_moe_forward,
+                                          mla_moe_init)
+    cfg = MlaMoeConfig.tiny(dtype=jnp.dtype(dtype))
+    params = mla_moe_init(jax.random.PRNGKey(0), cfg)
+    t = len(SEQ)
+    tokens = jnp.asarray([SEQ], jnp.int32)
+    cfg32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    params32 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params)
+    want = np.asarray(mla_moe_forward(params32, tokens, cfg32)[0, -1])
+    want_lp = want - want.max() - np.log(np.exp(want - want.max()).sum())
+    tol = 1e-3 if dtype == "float32" else 0.12
+
+    got = np.asarray(mla_moe_forward(params, tokens, cfg)[0, -1], np.float32)
+    assert np.max(np.abs(got - want)) < tol
+    padded = np.zeros((1, S_MAX), np.int32)
+    padded[0, :t] = SEQ
+    first, rows, _v, flp = E._prefill(
+        params, jnp.asarray(padded), jnp.int32(t), jax.random.PRNGKey(0),
+        jnp.zeros((1,), jnp.float32), cfg)
+    assert abs(float(flp[0]) - want_lp[int(first[0])]) < tol
+    assert want.max() - want[int(first[0])] < 2 * tol
+
+    grid = E._cache_ops(cfg).init_grid(cfg, SLOTS, S_MAX)
+    assert grid.c.shape == (cfg.n_layers, SLOTS, 1, S_MAX, cfg.latent_dim)
+    temps = jnp.zeros((SLOTS,), jnp.float32)
+    for i, tok in enumerate(SEQ):
+        grid, nxt, lps = E._decode_step(
+            params, grid, jnp.asarray([i, 0], jnp.int32),
+            jnp.asarray([tok, 0], jnp.int32), jax.random.PRNGKey(2), temps,
+            cfg)
+    assert abs(float(lps[0]) - want_lp[int(nxt[0])]) < tol
+    assert want.max() - want[int(nxt[0])] < 2 * tol
+    # the step-by-step grid holds the prefill's rows, one head of them
+    np.testing.assert_allclose(
+        np.asarray(grid.c[:, 0, 0, :t], np.float32),
+        np.asarray(rows[:, 0, :t, 0], np.float32), atol=tol)
+
+
 # --- a layer is spelled out in one file --------------------------------------
 
 def test_a_layers_norms_are_applied_in_block_py_only():

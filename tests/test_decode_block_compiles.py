@@ -149,6 +149,12 @@ def test_decode_block_leaves_the_grid_where_it_lies(compile_block, model):
     assert all(by_name[o][1] == "dynamic-update-slice" for o in grids), grids
 
     # both grids donated: each output grid aliases its parameter
+    assert _aliased_cache_params(text, 2)
+
+
+def _aliased_cache_params(text, n):
+    """The ``n`` cache parameters of the program, each aliased to an output
+    (the block donates them)."""
     header = text.split("\n", 1)[0]
     aliased = {int(p) for p in re.findall(
         r"\{\d+\}: \((\d+), \{\}", header.split("input_output_alias=")[1]
@@ -156,5 +162,65 @@ def test_decode_block_leaves_the_grid_where_it_lies(compile_block, model):
     cache_params = {int(re.match(r"(\d+)\)", rest).group(1))
                     for name, dims, op, rest in _instructions(text)
                     if op == "parameter" and name.startswith("cache_")}
-    assert len(cache_params) == 2 and cache_params <= aliased, (
+    assert len(cache_params) == n and cache_params <= aliased, (
         cache_params, aliased)
+    return True
+
+
+def test_latent_decode_block_writes_its_rows_in_place(one_chip, compile_block):
+    """The third configuration (``kimi-vl-a3b-l9``: latent rows of 576, a
+    dense layer before the expert layers, 64 experts of 1,408) at the cell's
+    widths: the latent grid is updated in place and aliased, nothing copies,
+    transposes or scatters a layer of it or the whole of it, and the expert
+    layers are ONE scan body (three products over the banks, not three a
+    layer) that reads a layer's banks where they lie."""
+    from kubetorch_tpu.models.mla import MlaMoeConfig, mla_moe_init
+    from kubetorch_tpu.serve import engine as E
+    layers = 4                                       # 1 dense + 3 expert
+    cfg = MlaMoeConfig(n_layers=layers, max_seq_len=S_MAX)
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(
+        lambda: mla_moe_init(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: E._cache_ops(cfg).init_grid(cfg, SLOTS, S_MAX)))
+    text = E._decode_block.lower(
+        params, cache, arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.int32),
+        arg((2,), jnp.uint32), arg((SLOTS,), jnp.float32), cfg,
+        n_steps=BLOCK, skeys=arg((SLOTS, 2), jnp.uint32),
+        tally=arg(cfg.routed_tally_shape, jnp.int32),
+        live=arg((SLOTS,), jnp.bool_)).compile().as_text()
+
+    layer_dims = sorted((SLOTS, S_MAX, cfg.latent_dim))
+    grid_dims = sorted((layers, SLOTS, S_MAX, cfg.latent_dim))
+    moving = ("copy", "copy-start", "transpose", "scatter", "gather",
+              "concatenate", "pad", "select", "broadcast")
+    passing = ("parameter", "get-tuple-element", "bitcast",
+               "dynamic-update-slice")
+    bad = [f"{op} {name}" for name, dims, op, _ in _instructions(text)
+           if (dims in (layer_dims, grid_dims) and op in moving)
+           or (dims == grid_dims and op not in passing)]
+    assert bad == []
+    updates = [n for n, dims, op, _ in _instructions(text)
+               if dims == grid_dims and op == "dynamic-update-slice"]
+    assert len(updates) >= SLOTS, updates        # a row a slot, in place
+    assert _aliased_cache_params(text, 1)
+    # sixteen rows go through every expert as plain products (gate, up and
+    # down over (E, 16, ·)): one scan body, so three of them and not three a
+    # layer; the grouped kernel is a prompt's, and no bank is copied for it
+    products = [n for n, dims, op, _ in _instructions(text)
+                if op == "convolution" and dims in (
+                    sorted((cfg.n_experts, SLOTS, cfg.moe_ffn_dim)),
+                    sorted((cfg.n_experts, SLOTS, cfg.dim)))]
+    assert len(products) == 3, products
+    assert "ragged-dot" not in text
+    bank = sorted((cfg.n_experts, cfg.dim, cfg.moe_ffn_dim))
+    assert [n for n, dims, op, _ in _instructions(text)
+            if dims == bank and op in moving] == []

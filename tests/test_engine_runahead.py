@@ -31,6 +31,15 @@ def dense():
     return llama_init(jax.random.PRNGKey(0), cfg), cfg
 
 
+@pytest.fixture(scope="module")
+def latent():
+    """The second cache kind (``serve.latent_cache``): latent rows, a dense
+    layer before the expert layers, the routing tally in the carry."""
+    from kubetorch_tpu.models.mla import MlaMoeConfig, mla_moe_init
+    cfg = MlaMoeConfig.tiny(dtype=jnp.float32)
+    return mla_moe_init(jax.random.PRNGKey(0), cfg), cfg
+
+
 def _engine(dense, **kw):
     params, cfg = dense
     kw = {"slots": 2, "max_len": 64, "prefill_buckets": (8,), **kw}
@@ -102,6 +111,40 @@ def test_slot_retired_under_a_successor_in_flight(dense, k, mode):
     assert got == want
 
 
+@pytest.mark.parametrize("mode", ("greedy", "seeded"))
+@pytest.mark.parametrize("k", BLOCKS)
+def test_latent_cache_streams_equal_the_one_step_reference(latent, k, mode):
+    """Run-ahead on and off give the same tokens through the latent cache,
+    and the routing tally rides the carry of blocks dispatched ahead."""
+    submits = [(p, {"max_new_tokens": 30 + 3 * i, **_sampling(mode, i)})
+               for i, p in enumerate(PROMPTS[:2])]
+    want = _reference(latent, submits)
+    eng = _engine(latent, decode_block=k)
+    handles = [eng.submit(p, **s) for p, s in submits]
+    _drive(eng)
+    assert eng.stats().blocks_run_ahead > 0
+    assert [(h.result(0), h.logprobs) for h in handles] == want
+    s, cfg = eng.stats(), latent[1]
+    assert s.moe_routed_pairs.sum() <= (
+        s.decode_steps * 2 * cfg.experts_per_token * cfg.n_moe_layers)
+    assert s.moe_routed_pairs.sum() >= (
+        (s.tokens_generated - 2) * cfg.experts_per_token * cfg.n_moe_layers)
+
+
+@pytest.mark.parametrize("k", BLOCKS[1:])
+def test_latent_cache_slot_retired_under_a_successor_in_flight(latent, k):
+    subs = [(p, {"max_new_tokens": n})
+            for p, n in zip(PROMPTS, (4 * k + 2, 50, 21))]
+    want = [_reference(latent, [s])[0] for s in subs]
+    eng = _engine(latent, decode_block=k)
+    short, long_ = (eng.submit(p, **s) for p, s in subs[:2])
+    while eng.stats().finished_total == 0:
+        eng.step()
+    late = eng.submit(subs[2][0], **subs[2][1])
+    _drive(eng)
+    assert [(h.result(0), h.logprobs) for h in (short, long_, late)] == want
+
+
 # -- (b) FIFO; a free slot is filled at the next boundary ---------------------
 
 @pytest.mark.parametrize("k", BLOCKS)
@@ -134,6 +177,53 @@ def test_no_run_ahead_while_a_slot_is_free_or_a_request_waits(dense, k):
     admitted = [h._req.admitted_at for h in handles]
     assert admitted == sorted(admitted)
     assert [len(h.result(0)) for h in handles] == [6, 8, 10, 12, 14]
+
+
+@pytest.mark.parametrize("family", ("dense", "latent"))
+@pytest.mark.parametrize("k", BLOCKS[1:])
+def test_arrival_during_a_boundarys_prefill_is_seated_behind_it(
+        family, k, request):
+    """A slot is free and a prefill of this boundary is running: a request
+    that arrives before that prefill's first token is out is seated at the
+    same boundary (``_admit_late``), not a block later, the decode block is
+    dispatched once, behind it, and every stream reads its own tokens. With
+    no slot free the boundary does not wait."""
+    model = request.getfixturevalue(family)
+    eng = _engine(model, slots=3, decode_block=k)
+    early = eng.submit(PROMPTS[0], max_new_tokens=12)
+    late, dispatched = [], []
+    emit_firsts, dispatch = eng._emit_firsts, eng._dispatch
+
+    def arrives_meanwhile():
+        if not late:                # the caller's next request, mid-prefill
+            assert not dispatched and eng._seating
+            late.append(eng.submit(PROMPTS[1], max_new_tokens=10))
+        emit_firsts()
+
+    eng._emit_firsts = arrives_meanwhile
+    eng._dispatch = lambda ahead: (dispatched.append(ahead), dispatch(ahead))
+    eng.step()
+    assert early.time_to_first_token() is not None
+    assert late[0].time_to_first_token() is not None    # the same boundary
+    assert dispatched == [False] and not eng._pending
+    assert late[0]._req in eng._slot_req
+    _drive(eng)
+    want = _reference(model, [(PROMPTS[0], {"max_new_tokens": 12}),
+                              (PROMPTS[1], {"max_new_tokens": 10})])
+    assert [(h.result(0), h.logprobs) for h in (early, late[0])] == want
+
+    # no slot left after the admission: nothing could be seated, so the
+    # block is dispatched behind the prefill before its first token is read
+    eng = _engine(model, slots=1, decode_block=k)
+    order = []
+    emit_firsts, dispatch = eng._emit_firsts, eng._dispatch
+    eng._emit_firsts = lambda: (order.append("firsts"), emit_firsts())
+    eng._dispatch = lambda ahead: (order.append("dispatch"), dispatch(ahead))
+    only = eng.submit(PROMPTS[2], max_new_tokens=5)
+    eng.step()
+    assert order[:2] == ["dispatch", "firsts"]
+    _drive(eng)
+    assert len(only.result(0)) == 5
 
 
 # -- (c) a boundary hook sees nothing in flight -------------------------------

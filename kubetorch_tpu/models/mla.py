@@ -22,13 +22,17 @@ What differs from ``models.llama`` / ``models.moe``, each handed to
   group-limited selection is the identity), the weights are the UNBIASED
   scores of the chosen, normalised and scaled by ``routed_scaling_factor``.
   No capacity, so no token is dropped and padding claims nothing
-  (:func:`moe_ffn_dropless`): up to ``DENSE_ROWS_MAX`` rows (a decode step's
-  slots, a short prompt) go through EVERY expert with a gate of zero where
-  it was not chosen, as plain einsums that stream all the banks at the rate
-  of a dense layer's weights; more rows are sorted by expert and each
-  expert multiplies its own run (``jax.lax.ragged_dot``, which XLA:TPU
-  lowers to its grouped-matmul kernel). Shared experts are one dense SwiGLU
-  added beside them.
+  (:func:`moe_ffn_dropless`). One algorithm in two ranges of rows, by the
+  row count alone. Up to ``DENSE_ROWS_MAX`` rows (a decode step's slots, a
+  short prompt) every row goes through every expert that got a token, with
+  a gate of zero where the row did not choose it: on the TPU backend ONE
+  kernel call that walks those experts and streams only their banks
+  (``ops.moe_experts``: about a fifth of a layer's experts get no token at
+  16 rows), elsewhere, and for experts the kernel cannot tile, plain einsums
+  over every bank (also the kernel's reference and its backward pass). More
+  rows are sorted by expert and each expert multiplies its own run
+  (``jax.lax.ragged_dot``, which XLA:TPU lowers to its grouped-matmul
+  kernel). Shared experts are one dense SwiGLU added beside them.
 - **The stack.** ``first_dense_layers`` dense layers, then expert layers:
   two stacked leaves, ``params["dense_layers"]`` and ``params["layers"]``,
   each scanned (:meth:`MlaMoeConfig.layer_stacks`, which ``models.block.
@@ -52,6 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..exceptions import UnsupportedMechanismError
+from ..ops.moe_experts import moe_experts, moe_experts_auto
 from .block import (apply_rope, decoder_block, dense_ffn, layer_stacks,
                     rmsnorm, with_banks)
 from .llama import rope_freqs
@@ -59,14 +64,26 @@ from .quant import wdot
 
 NEG_INF = -1e30
 
-# Rows (tokens of one call) up to which every expert multiplies every row,
-# gate zero where it was not chosen, instead of sorted runs through the
-# grouped matmul. Chosen from the row count alone, on a v5e, one layer's three
-# products alone on the chip, ms a layer at 16 / 256 / 512 / 1,024 rows (the
-# 64 banks stream in 1.35): the einsums 1.49 / 2.01 / 3.61 / 7.35, XLA's
-# grouped matmul over the whole stack 2.70 / 5.53 / 5.84 / 6.39 (PERF.md
-# section 5, PR 33). The cell runs both sides: a decode step's 16 rows and
-# the buckets 256 / 512 against the bucket 1,024.
+# The routed experts are one algorithm in two ranges of rows (the tokens of
+# one call), chosen from the row count alone. The readings are one layer's
+# three products alone on a v5e, ms a layer, where the 64 banks of
+# 2048 x 1408 stream in 1.35 (PERF.md section 5 has the tables).
+#
+# Up to DENSE_ROWS_MAX rows every row goes through every expert that got a
+# token, gate zero where it was not chosen: the grouped kernel
+# (``ops.moe_experts``) where it runs (``moe_experts_auto``: the TPU backend
+# and experts it can tile), else plain einsums over every bank. The kernel
+# streams only the hit banks, 1 - (1 - K/E)^m of them: 55 / 79 / 96 / 99.8%
+# at 8 / 16 / 32 / 64 rows here. Einsums against the kernel (PR 34) at 8 /
+# 16 / 32 / 64 / 128 / 256 / 512 rows: 1.50 / 0.83, 1.51 / 1.20, 1.51 /
+# 1.46, 1.51 / 1.50, 1.79 / 1.51, 2.02 / 1.54, 3.62 / 2.98: the kernel is
+# no slower at any, so no second threshold chooses between them.
+#
+# Beyond, (row, choice) pairs sorted by expert go through XLA's grouped
+# matmul. At 16 / 256 / 512 / 1,024 rows the einsums take 1.49 / 2.01 / 3.61
+# / 7.35 and the grouped matmul over the whole stack 2.70 / 5.53 / 5.84 /
+# 6.39 (PR 33); the kernel was not read at 1,024. The cell runs both ranges:
+# a decode step's 16 rows and the buckets 256 / 512, and the bucket 1,024.
 DENSE_ROWS_MAX = 512
 
 
@@ -331,25 +348,60 @@ def route(cfg: MlaMoeConfig, h: jax.Array, lw: Dict[str, Any]):
     return w * cfg.routed_scaling_factor, idx
 
 
+def _dense_experts(x: jax.Array, gates: jax.Array, whole, layer) -> jax.Array:
+    """Every expert of the layer over every row, plain einsums that stream
+    all the banks: Σ_e gates[m, e] · SwiGLU_e(x[m]). Also what the grouped
+    kernel is held to, and what its backward pass differentiates."""
+    one = {k: lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+           for k, v in whole.items()}
+    act = (jax.nn.silu(jnp.einsum("md,edf->emf", x, one["w_gate"]))
+           * jnp.einsum("md,edf->emf", x, one["w_up"]))
+    ys = jnp.einsum("emf,efd->emd", act, one["w_down"])
+    return jnp.einsum("me,emd->md", gates.astype(x.dtype), ys)
+
+
+@jax.custom_vjp
+def _grouped_experts(x, gates, whole, layer, sizes):
+    """:func:`_dense_experts` over the experts with ``sizes > 0`` alone: one
+    call of the kernel, which streams just their banks from the whole stacks
+    (``ops.moe_experts``). Its gradient is the einsum form's."""
+    return moe_experts(x, gates, whole["w_gate"], whole["w_up"],
+                       whole["w_down"], layer, sizes)
+
+
+def _grouped_fwd(x, gates, whole, layer, sizes):
+    return _grouped_experts(x, gates, whole, layer, sizes), (
+        x, gates, whole, layer)
+
+
+def _grouped_bwd(saved, ct):
+    x, gates, whole, layer = saved
+    _, pull = jax.vjp(lambda *a: _dense_experts(*a, layer), x, gates, whole)
+    return (*pull(ct), None, None)
+
+
+_grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def _routed_experts(cfg: MlaMoeConfig, x: jax.Array, w: jax.Array,
                     idx: jax.Array, sizes: jax.Array, banks) -> jax.Array:
     """Σ_k w[m, k] · SwiGLU_{idx[m, k]}(x[m]) for rows x (M, D); ``idx`` may
     hold ``n_experts`` for a row that routes nowhere (its ``w`` is 0);
     ``sizes`` (E,): the pairs an expert got.
     ``banks``: (the run's whole stacked banks {w_gate, w_up (L, E, D, F),
-    w_down (L, E, F, D)}, this layer's index in it)."""
+    w_down (L, E, F, D)}, this layer's index in it). One algorithm by the
+    row count (and the experts' shape and the backend, for the kernel): see
+    ``DENSE_ROWS_MAX``."""
     E, K = cfg.n_experts, cfg.experts_per_token
     whole, layer = banks
     m, d = x.shape
     if m <= DENSE_ROWS_MAX:
-        one = {k: lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
-               for k, v in whole.items()}
         gates = jnp.einsum("mk,mke->me", w, jax.nn.one_hot(
-            idx, E, dtype=w.dtype)).astype(x.dtype)
-        act = (jax.nn.silu(jnp.einsum("md,edf->emf", x, one["w_gate"]))
-               * jnp.einsum("md,edf->emf", x, one["w_up"]))
-        ys = jnp.einsum("emf,efd->emd", act, one["w_down"])
-        return jnp.einsum("me,emd->md", gates, ys)
+            idx, E, dtype=w.dtype))
+        bank = whole["w_gate"]
+        if moe_experts_auto(d, bank.shape[-1], bank.dtype.itemsize):
+            return _grouped_experts(x, gates, whole, layer, sizes)
+        return _dense_experts(x, gates, whole, layer)
     # sorted (row, choice) pairs, an expert its own run; the other layers'
     # groups of the whole stack are empty
     order = jnp.argsort(idx.reshape(-1), stable=True)

@@ -107,11 +107,18 @@ def test_configuration_carries_the_published_widths(full):
     assert {"serve_tok_s", "ttft_p50_ms", "ttft_p90_ms",
             "step_mfu.serve_mla_moe", "moe_expert_hit_share",
             "moe_load_max_over_mean", "engine_host_ms_per_block",
-            "decode_step_ms"} <= listed
-    # GQA's FLOPs and kernel; and no roofline share without a kernel of the
-    # PR's own to time (the expert and latent paths are XLA's)
+            "decode_step_ms", "moe_experts_roofline"} <= listed
+    # GQA's FLOPs and kernel are the other families'; the expert kernel's
+    # roofline share came with the kernel (ISSUE 34), and the latent
+    # attention, still XLA's, has none
     assert not {"step_mfu.serve", "decode_attn_roofline",
-                "moe_experts_roofline"} & listed
+                "mla_decode_attn_roofline"} & listed
+    roofline = next(m for m in bench["per_layer"]
+                    if m["name"] == "moe_experts_roofline")
+    assert roofline == {
+        "name": "moe_experts_roofline", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels", "moves": "serve_tok_s",
+        "workloads": ["kimivl-chat-closed"]}
 
 
 def test_bytes_and_parameters_are_the_hand_sums(bench_path, full, tiny):
@@ -298,10 +305,42 @@ def test_reader_serve_mfu_mla_moe(bench_path, tiny):
     assert read({**ctx, "trace": {}}) is None
 
 
+def test_reader_moe_experts_roofline(bench_path, tiny):
+    """ISSUE 34's reader: the hit banks' bytes (or the slots' products
+    against them, whichever takes longer at the peaks given) as a rate over
+    the counters' window, over the kernel's self time as a rate over the
+    trace's."""
+    import bench_flops
+    mod = _reader("moe_experts_roofline")
+    kernel = {"moe_experts": {"count": 160.0, "seconds": 0.05}}
+    ctx = {**_ctx(tiny, queries=kernel), "flops": bench_flops}
+    bank = 3 * 64 * 32                       # an expert's three matrices
+    cost = mod.moe_experts_cost(tiny, 110)   # 110 hits in the window
+    assert cost == {"bytes": 110 * bank * 2,
+                    "flops": 110 * 2.0 * bank * tiny["engine"]["slots"]}
+    least = max(cost["bytes"] / 1e8, cost["flops"] / 1e9)
+    assert mod.read(ctx, query="moe_experts") == pytest.approx(
+        100 * (least / 2.0) / (0.05 / 4.0))
+    # at the chip's peaks sixteen rows leave the kernel bound by memory
+    real = bench_flops.peaks("TPU v5 lite")
+    assert bench_flops.roofline_seconds(cost, real)[1] == "memory"
+    # a program without the kernel leaves no event, one without the tally no
+    # counter, an untraced run no trace: nothing to read, and no error
+    idle = {"moe_experts": {"count": 0.0, "seconds": 0.0}}
+    assert mod.read({**ctx, "trace": {**ctx["trace"], "queries": idle}},
+                    query="moe_experts") is None
+    assert mod.read({**ctx, "trace": {**ctx["trace"], "queries": {}}},
+                    query="moe_experts") is None
+    bare = {**ctx["trace"], "c0": {"now": 100.0}, "c1": {"now": 102.0}}
+    assert mod.read({**ctx, "trace": bare}, query="moe_experts") is None
+    assert mod.read({**ctx, "trace": {}}, query="moe_experts") is None
+
+
 def test_metric_files_name_their_readers():
     for name, reader in (("step_mfu.serve_mla_moe", "serve_mfu_mla_moe"),
                          ("moe_expert_hit_share", "moe_tally"),
-                         ("moe_load_max_over_mean", "moe_tally")):
+                         ("moe_load_max_over_mean", "moe_tally"),
+                         ("moe_experts_roofline", "moe_experts_roofline")):
         with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
             spec = json.load(f)
         assert spec["reader"] == reader

@@ -14,10 +14,26 @@ compiles for a chip that is described, not attached (``on-chip-measurement``
   are the two whole grids;
 - both grids are input/output-aliased (the block donates them).
 
+For the latent configuration (``kimi-vl-a3b-l9``), its decode block and its
+prefills of the buckets 256 and 512: the expert layers' scan
+body holds ONE ``kt_moe_experts`` call, whose bank operands are the whole
+stacks, and nothing makes an array the size of an expert, a layer's bank or
+the stack (ISSUE 34). And the census: the dense and Mixtral decode blocks and
+their bucket-256 prefills compile to the multiset of (opcode, result type)
+kept in ``tests/assets/compile_census.json``, which a PR that does not mean
+to touch them leaves as it is; a PR that does makes the file again with
+``JAX_PLATFORMS=cpu python -m tests.test_decode_block_compiles`` from the
+root of the repo.
+
 The topology is described inside a fixture, and skipped from there where it
 cannot be: nothing touches the TPU library while a module is imported.
 """
 
+import collections
+import contextlib
+import hashlib
+import json
+import os
 import re
 
 import pytest
@@ -67,53 +83,104 @@ def grid_traffic(text):
     return bad
 
 
-@pytest.fixture(scope="module")
-def one_chip():
+def described_chip():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def compile_block(one_chip, monkeypatch):
-    """``_decode_block`` compiled for the described chip, as text. The CPU
-    process is steered onto the chip's branch here, not by an option of the
-    program: the kernel path, compiled by Mosaic."""
-    from kubetorch_tpu.ops import decode_attention as kernel_mod
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        return described_chip()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+class _ChipPrograms:
+    """The engine's programs compiled for the described chip, as text, each
+    compiled once a module."""
+
+    def __init__(self, one_chip):
+        self.one_chip = one_chip
+        self.texts = {}
+
+    def shaped(self, tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=self.one_chip), tree)
+
+    def arg(self, shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.one_chip)
+
+    def params(self, cfg, init):
+        return self.shaped(jax.eval_shape(
+            lambda: init(jax.random.PRNGKey(0), cfg)))
+
+    def decode(self, cfg, init):
+        """``_decode_block``, the engine's common decode signature
+        (``aot_cache.warm_engine``; a latent cache's has the routing tally
+        and the live mask beside it)."""
+        from kubetorch_tpu.serve import engine as E
+        if ("decode", cfg) not in self.texts:
+            arg = self.arg
+            cache = self.shaped(jax.eval_shape(
+                lambda: E._cache_ops(cfg).init_grid(cfg, SLOTS, S_MAX)))
+            extra = {}
+            if hasattr(cfg, "routed_tally_shape"):
+                extra = dict(tally=arg(cfg.routed_tally_shape, jnp.int32),
+                             live=arg((SLOTS,), jnp.bool_))
+            self.texts["decode", cfg] = E._decode_block.lower(
+                self.params(cfg, init), cache, arg((SLOTS,), jnp.int32),
+                arg((SLOTS,), jnp.int32), arg((2,), jnp.uint32),
+                arg((SLOTS,), jnp.float32), cfg, n_steps=BLOCK,
+                skeys=arg((SLOTS, 2), jnp.uint32), **extra,
+            ).compile().as_text()
+        return self.texts["decode", cfg]
+
+    def prefill(self, cfg, init, bucket):
+        """``_prefill`` of one prompt padded to ``bucket``."""
+        from kubetorch_tpu.serve import engine as E
+        if ("prefill", cfg, bucket) not in self.texts:
+            arg = self.arg
+            self.texts["prefill", cfg, bucket] = E._prefill.lower(
+                self.params(cfg, init), arg((1, bucket), jnp.int32),
+                arg((), jnp.int32), arg((2,), jnp.uint32),
+                arg((1,), jnp.float32), cfg).compile().as_text()
+        return self.texts["prefill", cfg, bucket]
+
+
+@contextlib.contextmanager
+def steered_onto_the_chip(one_chip):
+    """The CPU process steered onto the chip's branch here, not by an option
+    of the program: the kernel paths, compiled by Mosaic."""
+    from kubetorch_tpu.models import generate, mla
+    from kubetorch_tpu.ops import attention, decode_attention, moe_experts
     from kubetorch_tpu.serve import engine as E
-    monkeypatch.setattr(E, "_decode_kernel_wanted", lambda: True)
-    monkeypatch.setattr(kernel_mod, "interpret_default", lambda: False)
     # an entry compiled for a described chip cannot be read back without one
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-
-    def shaped(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def run(cfg, init):
-        params = shaped(jax.eval_shape(
-            lambda: init(jax.random.PRNGKey(0), cfg)))
-        cache = shaped(jax.eval_shape(
-            lambda: E.init_grid_cache(cfg, SLOTS, S_MAX)))
-        # the engine's common decode signature (aot_cache.warm_engine)
-        return E._decode_block.lower(
-            params, cache, arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.int32),
-            arg((2,), jnp.uint32), arg((SLOTS,), jnp.float32), cfg,
-            n_steps=BLOCK, skeys=arg((SLOTS, 2), jnp.uint32),
-        ).compile().as_text()
-
-    yield run
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(E, "_decode_kernel_wanted", lambda: True)
+        mp.setattr(mla, "moe_experts_auto", moe_experts.moe_experts_supported)
+        mp.setattr(generate, "_FLASH_PREFILL_FLAG", "1")
+        for kernel_mod in (attention, decode_attention, moe_experts):
+            mp.setattr(kernel_mod, "interpret_default", lambda: False)
+        yield _ChipPrograms(one_chip)
     jax.config.update("jax_enable_compilation_cache", cached)
+
+
+@pytest.fixture(scope="module")
+def chip_programs(one_chip):
+    with steered_onto_the_chip(one_chip) as programs:
+        yield programs
+
+
+@pytest.fixture
+def compile_block(chip_programs):
+    return chip_programs.decode
 
 
 def _configs():
@@ -167,39 +234,24 @@ def _aliased_cache_params(text, n):
     return True
 
 
-def test_latent_decode_block_writes_its_rows_in_place(one_chip, compile_block):
+KIMI_LAYERS = 4                                      # 1 dense + 3 expert
+
+
+def _kimi():
+    from kubetorch_tpu.models.mla import MlaMoeConfig, mla_moe_init
+    return MlaMoeConfig(n_layers=KIMI_LAYERS, max_seq_len=S_MAX), mla_moe_init
+
+
+def test_latent_decode_block_writes_its_rows_in_place(compile_block):
     """The third configuration (``kimi-vl-a3b-l9``: latent rows of 576, a
     dense layer before the expert layers, 64 experts of 1,408) at the cell's
-    widths: the latent grid is updated in place and aliased, nothing copies,
-    transposes or scatters a layer of it or the whole of it, and the expert
-    layers are ONE scan body (three products over the banks, not three a
-    layer) that reads a layer's banks where they lie."""
-    from kubetorch_tpu.models.mla import MlaMoeConfig, mla_moe_init
-    from kubetorch_tpu.serve import engine as E
-    layers = 4                                       # 1 dense + 3 expert
-    cfg = MlaMoeConfig(n_layers=layers, max_seq_len=S_MAX)
-
-    def shaped(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = shaped(jax.eval_shape(
-        lambda: mla_moe_init(jax.random.PRNGKey(0), cfg)))
-    cache = shaped(jax.eval_shape(
-        lambda: E._cache_ops(cfg).init_grid(cfg, SLOTS, S_MAX)))
-    text = E._decode_block.lower(
-        params, cache, arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.int32),
-        arg((2,), jnp.uint32), arg((SLOTS,), jnp.float32), cfg,
-        n_steps=BLOCK, skeys=arg((SLOTS, 2), jnp.uint32),
-        tally=arg(cfg.routed_tally_shape, jnp.int32),
-        live=arg((SLOTS,), jnp.bool_)).compile().as_text()
+    widths: the latent grid is updated in place and aliased, and nothing
+    copies, transposes or scatters a layer of it or the whole of it."""
+    cfg, init = _kimi()
+    text = compile_block(cfg, init)
 
     layer_dims = sorted((SLOTS, S_MAX, cfg.latent_dim))
-    grid_dims = sorted((layers, SLOTS, S_MAX, cfg.latent_dim))
+    grid_dims = sorted((KIMI_LAYERS, SLOTS, S_MAX, cfg.latent_dim))
     moving = ("copy", "copy-start", "transpose", "scatter", "gather",
               "concatenate", "pad", "select", "broadcast")
     passing = ("parameter", "get-tuple-element", "bitcast",
@@ -212,15 +264,105 @@ def test_latent_decode_block_writes_its_rows_in_place(one_chip, compile_block):
                if dims == grid_dims and op == "dynamic-update-slice"]
     assert len(updates) >= SLOTS, updates        # a row a slot, in place
     assert _aliased_cache_params(text, 1)
-    # sixteen rows go through every expert as plain products (gate, up and
-    # down over (E, 16, ·)): one scan body, so three of them and not three a
-    # layer; the grouped kernel is a prompt's, and no bank is copied for it
-    products = [n for n, dims, op, _ in _instructions(text)
-                if op == "convolution" and dims in (
-                    sorted((cfg.n_experts, SLOTS, cfg.moe_ffn_dim)),
-                    sorted((cfg.n_experts, SLOTS, cfg.dim)))]
-    assert len(products) == 3, products
+
+
+@pytest.mark.parametrize("program", ["decode_block", "prefill_256",
+                                     "prefill_512"])
+def test_latent_programs_stream_their_banks_through_one_kernel_call(
+        chip_programs, program):
+    """A decode step's sixteen rows and a short prompt's bucket take the
+    grouped kernel (``ops/moe_experts.py``): the expert layers are ONE scan
+    body, so one ``kt_moe_experts`` call and not one a layer, Mosaic's, whose
+    bank operands are the three whole stacks as the program was given them;
+    no einsum over every bank and no grouped matmul is left, and nothing but
+    a parameter passed on has the size of an expert, of a layer's bank or of
+    the stack (PR 33's first version lost 23 ms a step to a
+    ``dynamic-slice_bitcast_fusion`` of a layer's bank)."""
+    cfg, init = _kimi()
+    rows = SLOTS if program == "decode_block" else int(program[-3:])
+    text = (chip_programs.decode(cfg, init) if program == "decode_block"
+            else chip_programs.prefill(cfg, init, rows))
+    by_name = {name: (dims, op) for name, dims, op, _ in _instructions(text)}
+    E_, D, F = cfg.n_experts, cfg.dim, cfg.moe_ffn_dim
+
+    calls = [(name, rest) for name, _, op, rest in _instructions(text)
+             if op == "custom-call" and "kt_moe_experts" in name]
+    assert len(calls) == 1, [c[0] for c in calls]
+    name, rest = calls[0]
+    assert 'custom_call_target="tpu_custom_call"' in rest
+    stack = sorted((cfg.n_moe_layers, E_, D, F))
+    operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+    stacks = [o for o in operands if by_name.get(o, ([], ""))[0] == stack]
+    assert len(stacks) == 3, operands
+    assert all(by_name[o][1] in ("parameter", "get-tuple-element")
+               for o in stacks), [by_name[o] for o in stacks]
+
+    sized = (sorted((D, F)), sorted((E_, D, F)), stack)
+    passing = ("parameter", "get-tuple-element", "bitcast")
+    assert [f"{op} {n} {dims}" for n, dims, op, _ in _instructions(text)
+            if dims in sized and op not in passing] == []
     assert "ragged-dot" not in text
-    bank = sorted((cfg.n_experts, cfg.dim, cfg.moe_ffn_dim))
     assert [n for n, dims, op, _ in _instructions(text)
-            if dims == bank and op in moving] == []
+            if op == "convolution" and dims in (
+                sorted((E_, rows, F)), sorted((E_, rows, D)))] == []
+
+
+# -- the census of the older cells' programs -----------------------------------
+
+CENSUS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "assets", "compile_census.json")
+_ANY = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|[a-z0-9]+\[[^\]]*\])"
+                  r"\S* ([\w\-]+)\(")
+
+
+def census(text) -> dict:
+    """{"opcode result-type": count} over every instruction of a compiled
+    program, fused computations' bodies too; layouts left out, a tuple type
+    as a digest of itself."""
+    out = collections.Counter()
+    for line in text.splitlines():
+        m = _ANY.match(line)
+        if m:
+            kind = re.sub(r"\{[^}]*\}", "", m.group(1))
+            if kind.startswith("("):
+                kind = "tuple:" + hashlib.sha1(kind.encode()).hexdigest()[:12]
+            out[f"{m.group(2)} {kind}"] += 1
+    return dict(sorted(out.items()))
+
+
+def older_cells_programs(programs, only=None) -> dict:
+    """{name: text}: the decode block and the bucket-256 prefill of the dense
+    and the Mixtral configuration at the cells' widths (``only``: one)."""
+    out = {}
+    for model, (cfg, init) in _configs().items():
+        for which, compile_it in (
+                ("decode_block", lambda: programs.decode(cfg, init)),
+                ("prefill_256", lambda: programs.prefill(cfg, init, 256))):
+            if only in (None, f"{model}.{which}"):
+                out[f"{model}.{which}"] = compile_it()
+    return out
+
+
+@pytest.mark.parametrize("program", [
+    "mistral-7b.decode_block", "mistral-7b.prefill_256",
+    "mixtral-8x7b.decode_block", "mixtral-8x7b.prefill_256"])
+def test_the_older_cells_programs_keep_their_census(chip_programs, program):
+    """A change to the latent family's expert layer leaves the two older
+    cells' device programs as they were: the same instructions making the
+    same arrays, as counted at PR 33's commit."""
+    with open(CENSUS_FILE) as f:
+        want = json.load(f)[program]
+    got = census(older_cells_programs(chip_programs, only=program)[program])
+    moved = {k: (want.get(k, 0), got.get(k, 0))
+             for k in set(want) | set(got) if want.get(k, 0) != got.get(k, 0)}
+    assert moved == {}, f"(kept, compiled) counts that differ: {moved}"
+
+
+if __name__ == "__main__":
+    # the census written anew, for a PR that means to change those programs
+    with steered_onto_the_chip(described_chip()) as chip:
+        kept = {name: census(text)
+                for name, text in older_cells_programs(chip).items()}
+    with open(CENSUS_FILE, "w") as out_file:
+        json.dump(kept, out_file, indent=0)
+    print({name: sum(counts.values()) for name, counts in kept.items()})

@@ -34,6 +34,7 @@ from kubetorch_tpu.models.generate import ffn_block
 from kubetorch_tpu.models.llama import rope_freqs
 from kubetorch_tpu.models.mla import (MlaMoeConfig, mla_moe_forward,
                                       mla_moe_init)
+from kubetorch_tpu.ops.moe_experts import moe_experts_supported
 from kubetorch_tpu.serve import GenerationEngine
 
 pytestmark = pytest.mark.level("unit")
@@ -340,20 +341,36 @@ def test_a_scrape_beside_the_loop_never_fetches_the_tally(tiny):
         eng.stop()
 
 
-def test_the_two_formulations_of_the_experts_agree(tiny):
+@pytest.mark.parametrize("widths", ["tiny", "lane-aligned"])
+def test_the_two_formulations_of_the_experts_agree(tiny, widths):
     """Rows past ``mla.DENSE_ROWS_MAX`` are sorted by expert, the rest go
-    through every expert: one layer, the same rows, the same output."""
+    through every expert: one layer, the same rows, the same output. Where
+    the grouped kernel runs (``ops.moe_experts``: the chip's branch, chosen
+    here and interpreted; the tiny model's widths are not lane-aligned) it is
+    the third formulation: the same output again, and it ran."""
     params, cfg = tiny
+    if widths == "lane-aligned":
+        cfg = dataclasses.replace(cfg, dim=128, moe_ffn_dim=128)
+        params = mla_moe_init(jax.random.PRNGKey(0), cfg)
     lw = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
     for rows in (2, 16, 40):
         h = jax.random.normal(jax.random.PRNGKey(rows), (1, rows, cfg.dim))
-        outs = []
-        for n in (0, 20, 512):
+        outs, kernel_rows = [], []
+        # sorted runs; every expert, as einsums; the same through the kernel
+        for dense, on_chip in ((0, False), (20, False), (512, False),
+                               (20, True)):
             with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(mla, "DENSE_ROWS_MAX", n)     # eager: no jit
+                mp.setattr(mla, "DENSE_ROWS_MAX", dense)     # eager: no jit
+                if on_chip:
+                    mp.setattr(mla, "moe_experts_auto", moe_experts_supported)
+                kernel = mla.moe_experts
+                mp.setattr(mla, "moe_experts", lambda x, *a: (
+                    kernel_rows.append(x.shape[0]), kernel(x, *a))[1])
                 outs.append(mla.moe_ffn_dropless(cfg, h, lw)[0])
-        np.testing.assert_allclose(outs[0], outs[1], atol=1e-5)
-        np.testing.assert_allclose(outs[0], outs[2], atol=1e-5)
+        for out in outs[1:]:
+            np.testing.assert_allclose(outs[0], out, atol=1e-5)
+        took_kernel = widths == "lane-aligned" and rows <= 20
+        assert kernel_rows == ([rows] if took_kernel else [])
 
 
 def test_dense_and_mixtral_engines_keep_no_tally():

@@ -27,11 +27,15 @@ over the experts ``e`` with ``sizes[e] > 0`` only, in the house style of
   hit id (``work_list``). Grid step ``i < n_hit`` streams expert ``ids[i]``;
   a later step names the block of the step before it, so Pallas elides its
   DMA, and ``pl.when`` skips its body. No hit at all gives zeros.
-- **A grid step is one expert, whole**: its three matrices (17.3 MB at
-  2048 × 1408 in bfloat16, ≥ 21 us of stream, beside which the ~0.3 us a
-  grid step costs vanishes; 1408 = 11 × 128 has no useful lane-aligned
-  divisor). Double-buffered that is 34.6 MB of VMEM, so the call raises
-  ``vmem_limit_bytes`` (``vmem_bytes``; a v5e has 128 MiB).
+- **A grid step is one expert, whole** where its three matrices fit the
+  VMEM double-buffered (17.3 MB at 2048 × 1408 in bfloat16, ≥ 21 us of
+  stream, beside which the ~0.3 us a grid step costs vanishes; 1408 =
+  11 × 128 has no useful lane-aligned divisor; double-buffered 34.6 MB, so
+  the call raises ``vmem_limit_bytes``: ``vmem_bytes``; a v5e has 128 MiB).
+  A wider expert (6144 × 2048: 75.5 MB) goes ``f_tile`` of its F columns a
+  step, the widest lane-aligned divisor that fits (1,024 there): SwiGLU is
+  a sum over F, so a step adds its columns' part of the down product, and
+  an expert is ``F / f_tile`` steps in a row.
 - Every row goes through every hit expert, its gate (the routing weight,
   zero where the row did not choose the expert) applied to the expert's
   output in float32 before the sum, which is kept in a float32 VMEM scratch
@@ -44,6 +48,7 @@ dtype before the down product.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -69,11 +74,22 @@ def vmem_bytes(d: int, f: int, itemsize: int) -> int:
     return 2 * 3 * d * f * itemsize + VMEM_BESIDE_BANKS
 
 
+def f_tile(d: int, f: int, itemsize: int) -> int:
+    """The F columns of an expert that one grid step streams: all of them
+    where the three matrices double-buffered fit the VMEM, else the widest
+    lane-aligned divisor of ``f`` whose blocks do; 0 where the kernel cannot
+    tile experts of ``d`` × ``f`` at all."""
+    if d % 128 or f % 128:
+        return 0
+    return next((f // n for n in range(1, f // 128 + 1)
+                 if f % (128 * n) == 0
+                 and vmem_bytes(d, f // n, itemsize) <= VMEM_MAX), 0)
+
+
 def moe_experts_supported(d: int, f: int, itemsize: int) -> bool:
     """Whether the kernel tiles on the chip for experts of ``d`` × ``f``:
-    both lane-aligned, and an expert double-buffered fits the VMEM."""
-    return d % 128 == 0 and f % 128 == 0 and \
-        vmem_bytes(d, f, itemsize) <= VMEM_MAX
+    both lane-aligned, and some tile of an expert fits the VMEM."""
+    return f_tile(d, f, itemsize) > 0
 
 
 def moe_experts_auto(d: int, f: int, itemsize: int) -> bool:
@@ -96,30 +112,38 @@ def work_list(sizes: jax.Array):
     return ids, n_hit.reshape(1)
 
 
-def bank_block(i, layer_ref, ids_ref, n_ref):
+def bank_block(i, layer_ref, ids_ref, n_ref, chunks: int = 1,
+               down: bool = False):
     """Index map of the three banks: grid step ``i`` names expert
-    ``ids[i]`` of layer ``layer``, whole."""
-    del n_ref                             # the list's tail repeats its last
-    return layer_ref[0], ids_ref[i], 0, 0
+    ``ids[i]`` of layer ``layer``, whole; or, an expert being ``chunks``
+    steps, its F-tile ``i % chunks`` (on the last axis of gate and up, on
+    the row axis of ``down``). A step past the list names the last step's
+    block again."""
+    if chunks == 1:
+        return layer_ref[0], ids_ref[i], 0, 0     # the tail repeats its last
+    tile = jnp.where(i < n_ref[0] * chunks, i % chunks, chunks - 1)
+    tail = (tile, 0) if down else (0, tile)
+    return layer_ref[0], ids_ref[i // chunks], *tail
 
 
-def _kernel(layer_ref, ids_ref, n_ref, x_ref, g_ref, wg_ref, wu_ref, wd_ref,
-            o_ref, acc_ref):
+def _kernel(chunks, layer_ref, ids_ref, n_ref, x_ref, g_ref, wg_ref, wu_ref,
+            wd_ref, o_ref, acc_ref):
     del layer_ref                         # read by the index maps only
     i = pl.program_id(0)
+    expert = i if chunks == 1 else i // chunks
 
     @pl.when(i == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(i < n_ref[0])
+    @pl.when(i < (n_ref[0] if chunks == 1 else n_ref[0] * chunks))
     def _expert():
         x = x_ref[:]                                            # (M, D)
         gate = jnp.dot(x, wg_ref[0, 0], preferred_element_type=jnp.float32)
         up = jnp.dot(x, wu_ref[0, 0], preferred_element_type=jnp.float32)
         act = (jax.nn.silu(gate) * up).astype(x.dtype)          # (M, F)
         y = jnp.dot(act, wd_ref[0, 0], preferred_element_type=jnp.float32)
-        acc_ref[:] += g_ref[ids_ref[i]] * y                     # (M, 1)·(M, D)
+        acc_ref[:] += g_ref[ids_ref[expert]] * y                # (M, 1)·(M, D)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _write():
@@ -143,26 +167,29 @@ def moe_experts(x: jax.Array, gates: jax.Array, w_gate: jax.Array,
         x = jnp.pad(x, ((0, mp - m), (0, 0)))
         gates = jnp.pad(gates, ((0, mp - m), (0, 0)))
     ids, n_hit = work_list(sizes)
+    ft = f_tile(d, f, w_gate.dtype.itemsize) or f
+    chunks = f // ft
 
     def whole(shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
+    block = partial(bank_block, chunks=chunks)
     out = pl.pallas_call(
-        _kernel,
+        partial(_kernel, chunks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n_experts,),
+            grid=(n_experts * chunks,),
             in_specs=[whole((mp, d)), whole((n_experts, mp, 1)),
-                      pl.BlockSpec((1, 1, d, f), bank_block),
-                      pl.BlockSpec((1, 1, d, f), bank_block),
-                      pl.BlockSpec((1, 1, f, d), bank_block)],
+                      pl.BlockSpec((1, 1, d, ft), block),
+                      pl.BlockSpec((1, 1, d, ft), block),
+                      pl.BlockSpec((1, 1, ft, d), partial(block, down=True))],
             out_specs=whole((mp, d)),
             scratch_shapes=[pltpu.VMEM((mp, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((mp, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=vmem_bytes(d, f, w_gate.dtype.itemsize)),
+            vmem_limit_bytes=vmem_bytes(d, ft, w_gate.dtype.itemsize)),
         interpret=interpret,
         name="kt_moe_experts",
     )(jnp.asarray(layer, jnp.int32).reshape(1), ids, n_hit, x,

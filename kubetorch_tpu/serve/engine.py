@@ -158,14 +158,14 @@ class _CacheOps(NamedTuple):
     init_grid: Callable    # (cfg, slots, max_len) -> grid
     init_rows: Callable    # (cfg, batch, t) -> a prompt's rows, row-major
     rows_mix: Callable     # (cfg, layer_rows, q_pos, freqs_full, **kw)
-    grid_mix: Callable     # (cfg, grid, layer, pos, freqs)
+    grid_mix: Callable     # (cfg, grid, layer, pos, freqs, live)
 
 
 _KV_OPS = _CacheOps(
     GRID_LAYOUT, init_grid_cache, init_cache,
     lambda cfg, rows, q_pos, freqs_full, **kw: qkv_attend(
         cfg, freqs_full[q_pos], cache_attend(cfg, *rows, q_pos, **kw)),
-    lambda cfg, grid, layer, pos, freqs: qkv_attend(
+    lambda cfg, grid, layer, pos, freqs, live: qkv_attend(
         cfg, freqs, grid_attend(cfg, grid, layer, pos)))
 
 
@@ -175,7 +175,7 @@ def _cache_ops(cfg) -> _CacheOps:
     imported here and nowhere earlier)."""
     if getattr(cfg, "cache_kind", "kv") == "latent":
         from . import latent_cache as lc
-        return _CacheOps(lc.GRID_LAYOUT, lc.init_grid, lc.init_rows,
+        return _CacheOps(lc.grid_layout(cfg), lc.init_grid, lc.init_rows,
                          lc.rows_mix, lc.grid_mix)
     return _KV_OPS
 
@@ -345,9 +345,11 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     ``live`` (SLOTS,) int32, given only where the engine keeps a routing
     tally: non-zero for the slots that hold a request (a column of the
     carry patch); the others' tokens claim no expert
-    and count nothing, and the expert layers' tallies come back stacked.
+    and count nothing, and the step's tallies come back stacked a layer, by
+    the names of :func:`_tally_shapes`: the expert layers' routing tally
+    and, where attention selects its rows, the rows scored and selected.
     Always returns the 5-tuple (cache', next_tok, logprobs, counts',
-    routed) — ``counts'`` is None when ``counts`` is, ``routed`` when
+    tallied) — ``counts'`` is None when ``counts`` is, ``tallied`` when
     ``live`` is."""
     s_max = cache[0].shape[3]
     ops = _cache_ops(cfg)
@@ -359,27 +361,37 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     # the grid rides in the CARRY, the layer index beside the weights:
     # scanned as ``xs``/``ys``, XLA builds a second grid every step and
     # slices every layer out of one and writes it back into the other
+    selects = "dsa" in _tally_shapes(cfg)
+
     def body(whole, start, carry, layer):
         h, grid = carry
         lw, l, bank_l = layer
         # per-slot adapters gathered to (B, D, R)/(B, R, O) per target
         # (multi-LoRA serving — see ``GenerationEngine`` docs)
         lora = gather_slot_adapters(bank_l, aidx, lora_scale, banks)
-        h, grid, aux = decoder_block(cfg, h, lw,
-                                     ops.grid_mix(cfg, grid, l, pos, freqs),
-                                     with_banks(ffn, whole, l - start),
-                                     lora=lora)
-        return (h, grid), (None if live is None else aux)
+        h, grid, aux = decoder_block(
+            cfg, h, lw, ops.grid_mix(cfg, grid, l, pos, freqs, live),
+            with_banks(ffn, whole, l - start), lora=lora)
+        # a mix that selects its rows hands its counts out beside the grid
+        grid, seen = grid if selects else (grid, None)
+        return (h, grid), (None if live is None else (aux, seen))
 
     ffn = (partial(ffn_block, cfg) if live is None
            else partial(ffn_block, cfg, token_mask=(live != 0)[:, None]))
-    carry, routed = (x, cache), None
+    carry, routed, seen = (x, cache), None, []
     for stack, whole, start, n in layer_stacks(cfg, params):
         # adapter banks are stacked over one run of layers
-        carry, aux = lax.scan(
+        carry, out = lax.scan(
             partial(body, whole, start), carry,
             (stack, jnp.arange(start, start + n), banks or {}))
-        routed = aux if aux is not None else routed
+        if out is not None:
+            routed = routed if out[0] is None else out[0]   # the one run
+            seen.append(out[1])                             # every run
+    tallied = None
+    if live is not None:
+        tallied = {} if routed is None else {"moe": routed}
+        if selects:
+            tallied["dsa"] = jnp.concatenate(seen, axis=0)
     x, new_cache = carry
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_head_dot(x[:, 0], params, cfg.dtype)
@@ -400,7 +412,45 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
                              lp_logits=raw_logits, keys=step_keys)
     if counts is not None:
         counts = counts.at[jnp.arange(counts.shape[0]), nxt].add(1)
-    return _constrain_cache(new_cache), nxt, lps, counts, routed
+    return _constrain_cache(new_cache), nxt, lps, counts, tallied
+
+
+def _tally_shapes(cfg) -> Dict[str, tuple]:
+    """What a decode step tallies on the device for this config, by name:
+    ``moe``, the expert layers' routing tally (L_moe, 2, E) — the routed
+    (token, choice) pairs of the live slots an expert got, and the steps in
+    which it got any — and ``dsa``, (L, 2): the rows a sparse-attention
+    layer scored and selected for the live slots. A family says which it
+    keeps (``routed_tally_shape`` / ``dsa_tally_shape``)."""
+    shapes = {"moe": getattr(cfg, "routed_tally_shape", None),
+              "dsa": getattr(cfg, "dsa_tally_shape", None)}
+    return {k: v for k, v in shapes.items() if v is not None}
+
+
+# ``dsa`` counts rows, 100,000 a step and layer at 16 slots of 8k context:
+# an int32 would wrap within minutes, so the running sum is kept as two
+# int32 words, [..., 0] counting 2**_DSA_WORD of [..., 1]'s units (a step
+# adds at most SLOTS x S_max to the low word before it carries)
+_DSA_WORD = 24
+
+
+def _init_tally(cfg) -> Optional[Dict[str, Any]]:
+    shapes = _tally_shapes(cfg)
+    if "dsa" in shapes:
+        shapes["dsa"] += (2,)
+    return {k: jnp.zeros(v, jnp.int32) for k, v in shapes.items()} or None
+
+
+def _tally_add(tally, step):
+    """The running tally plus one step's."""
+    out = dict(tally)
+    if "moe" in tally:
+        out["moe"] = tally["moe"] + step["moe"]
+    if "dsa" in tally:
+        low = tally["dsa"][..., 1] + step["dsa"]
+        out["dsa"] = jnp.stack([tally["dsa"][..., 0] + (low >> _DSA_WORD),
+                                low & ((1 << _DSA_WORD) - 1)], axis=-1)
+    return out
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "lora_scale"),
@@ -418,8 +468,9 @@ def _decode_step(params, cache, pos, toks, rng, temps, cfg,
     ``QuantKVCache`` (``kv_quant``) — the pytree structure keys the jit, so
     each engine compiles exactly one of the bodies. Returns
     (cache', next_tok, logprobs), then ``counts'`` and ``tally'`` (the
-    routing tally; ``live`` comes with it) where they were given."""
-    cache, nxt, lps, counts, routed = _decode_step_impl(
+    running tally of :func:`_tally_shapes`; ``live`` comes with it) where
+    they were given."""
+    cache, nxt, lps, counts, tallied = _decode_step_impl(
         params, cache, pos, toks, rng, temps, cfg, top_k=top_k, banks=banks,
         aidx=aidx, lora_scale=lora_scale, top_ps=top_ps, counts=counts,
         fpen=fpen, ppen=ppen, bias=bias, bmask=bmask, skeys=skeys, live=live)
@@ -427,7 +478,7 @@ def _decode_step(params, cache, pos, toks, rng, temps, cfg,
     if counts is not None:
         out += (counts,)
     if tally is not None:
-        out += (tally + routed,)
+        out += (_tally_add(tally, tallied),)
     return out
 
 
@@ -464,23 +515,23 @@ def _decode_block(params, cache, pos, toks, rng, temps, cfg, n_steps: int,
     computed (a request's budget ends at or before that row). Rows past a
     retired frontier are never attended before being rewritten, and the
     next occupant writes every row before it attends it — so the garbage
-    is unobservable. ``tally`` (L_moe, 2, E) int32, where the engine keeps
-    one: the routed (token, choice) pairs of the ``live`` slots an expert
-    got, and the steps in which it got any, accumulated in this scan's carry
-    and read back only when someone reads ``stats()``. Returns
+    is unobservable. ``tally``, where the engine keeps one
+    (:func:`_tally_shapes`): what the ``live`` slots' tokens did, accumulated
+    in this scan's carry and read back only when someone reads ``stats()``.
+    Returns
     (cache', final_pos, final_tok, toks (K, B), logprobs (K, B), counts'),
     and ``tally'`` behind them where one was given."""
 
     def step_fn(carry, k):
         cache, pos, toks, counts, tally = carry
         key = jax.random.fold_in(rng, k)
-        cache, nxt, lps, counts, routed = _decode_step_impl(
+        cache, nxt, lps, counts, tallied = _decode_step_impl(
             params, cache, pos, toks, key, temps, cfg, top_k=top_k,
             banks=banks, aidx=aidx, lora_scale=lora_scale, top_ps=top_ps,
             counts=counts, fpen=fpen, ppen=ppen, bias=bias, bmask=bmask,
             skeys=skeys, live=live)
         if tally is not None:
-            tally = tally + routed
+            tally = _tally_add(tally, tallied)
         return (cache, pos + 1, nxt, counts, tally), (nxt, lps)
 
     (cache, pos, toks, counts, tally), (toks_k, lps_k) = lax.scan(
@@ -868,6 +919,12 @@ class EngineStats:
     # else the last reading
     moe_routed_pairs: Any = None
     moe_expert_hits: Any = None
+    # layers whose attention selects its rows (a sparse-attention indexer;
+    # None elsewhere), (L,) int64 each: the cached rows the live slots'
+    # decode steps scored (every row up to the frontier) and the rows they
+    # then attended to. Accumulated and fetched like the routing tally
+    dsa_rows_scored: Any = None
+    dsa_rows_selected: Any = None
 
 
 class GenerationEngine:
@@ -953,13 +1010,13 @@ class GenerationEngine:
                      aot_cache is not None
                      and "the AOT executable cache (aot_cache)",
                      self._mesh is not None and "a sharded mesh")
-        # routing tally of the expert layers (families that keep one), on
-        # the device; the host's copy is refreshed when stats() is read at a
-        # batch boundary
-        shape = getattr(cfg, "routed_tally_shape", None)
-        self._tally = None if shape is None else jnp.zeros(shape, jnp.int32)
-        self._tally_host = None if shape is None else np.zeros(shape,
-                                                               np.int64)
+        # what the decode steps tally on the device (_tally_shapes: the
+        # expert layers' routing, a sparse attention's rows), for families
+        # that keep any; the host's copy is refreshed when stats() is read
+        # at a batch boundary
+        self._tally = _init_tally(cfg)
+        self._tally_host = self._tally and {
+            k: np.zeros(v.shape, np.int64) for k, v in self._tally.items()}
         if self.quantize_kv:
             # int8 grid (kv_quant): halves the decode HBM stream + cache
             # footprint; prefill/prefix math stays full-precision, rows
@@ -2228,23 +2285,33 @@ class GenerationEngine:
         return {"seconds": self._phases.snapshot(),
                 "blocks": self._phases.blocks}
 
-    def _read_tally(self):
-        """The routing tally as (pairs, hits), each (L_moe, E), or (None,
-        None). The device's is fetched only where nothing is in flight (a
-        batch boundary: ``at_batch_boundary`` runs its hook there, on the
-        stepping thread or inline); any other reader — the metrics scrape —
-        gets the last reading and never waits for a block."""
+    def _read_tally(self) -> Dict[str, Any]:
+        """The device tallies as ``EngineStats`` has them (its fields by
+        name, absent where the family keeps none): the routing tally as
+        pairs and hits, each (L_moe, E); the sparse attention's rows scored
+        and selected, each (L,). The device's are fetched only where nothing
+        is in flight (a batch boundary: ``at_batch_boundary`` runs its hook
+        there, on the stepping thread or inline); any other reader — the
+        metrics scrape — gets the last reading and never waits for a
+        block."""
         if self._tally is None:
-            return None, None
+            return {}
         thread = self._thread
         if not self._inflight and (thread is None or not thread.is_alive()
                                    or threading.current_thread() is thread):
-            self._tally_host = np.asarray(self._tally).astype(np.int64)
-        return self._tally_host[:, 0], self._tally_host[:, 1]
+            self._tally_host = {k: np.asarray(v).astype(np.int64)
+                                for k, v in self._tally.items()}
+        out, host = {}, self._tally_host
+        if "moe" in host:
+            out.update(moe_routed_pairs=host["moe"][:, 0],
+                       moe_expert_hits=host["moe"][:, 1])
+        if "dsa" in host:
+            rows = (host["dsa"][..., 0] << _DSA_WORD) + host["dsa"][..., 1]
+            out.update(dsa_rows_scored=rows[:, 0], dsa_rows_selected=rows[:, 1])
+        return out
 
     def stats(self) -> EngineStats:
         dt = max(time.monotonic() - self._t0, 1e-9)
-        pairs, hits = self._read_tally()
         return EngineStats(
             slots=self.slots,
             active=sum(r is not None for r in self._slot_req),
@@ -2260,8 +2327,7 @@ class GenerationEngine:
             tokens_per_sec=self._tokens / dt,
             ttft_avg=(sum(self._ttfts) / len(self._ttfts)
                       if self._ttfts else 0.0),
-            blocks_run_ahead=self._blocks_ahead,
-            moe_routed_pairs=pairs, moe_expert_hits=hits)
+            blocks_run_ahead=self._blocks_ahead, **self._read_tally())
 
     def __kt_metrics__(self) -> Dict[str, float]:
         """Pod-scrape hook (``serving.process_worker`` — the
@@ -2291,6 +2357,11 @@ class GenerationEngine:
             for layer, row in enumerate(s.moe_routed_pairs):
                 out[f"engine_moe_layer{layer}_load_max_over_mean"] = float(
                     row.max() / max(row.mean(), 1e-9))
+        if s.dsa_rows_scored is not None:
+            out["engine_dsa_rows_scored_total"] = float(
+                s.dsa_rows_scored.sum())
+            out["engine_dsa_rows_selected_total"] = float(
+                s.dsa_rows_selected.sum())
         spec = getattr(self, "spec_stats", None)
         if spec is not None:
             out["engine_spec_rounds"] = float(spec.rounds)

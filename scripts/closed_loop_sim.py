@@ -1,14 +1,17 @@
 """Where a closed-loop cell's time-to-first-token quantiles fall, without a chip.
 
     python scripts/closed_loop_sim.py --seeds 2147499301,2147499304 [--decode-block 8] [--no-admit-late]
+    python scripts/closed_loop_sim.py --seeds 1,2 --mix longdoc-closed --buckets 8192 --prefill-ms 2400 --step-ms 40 --ramp-s 12
 
 A discrete-event walk through ``GenerationEngine._step_once`` (boundary:
 admit, ``_admit_late``, dispatch, first tokens; else run ahead and collect)
-under the benchmark's own ``chat-closed`` deck (``benchmark/bench_traffic``),
-with the device's and the host's times as constants. The constants are
-``kimivl-chat-closed``'s, from the per-request records of four chip runs
-(PERF.md section 6, PR 33's fix round); another cell passes its own step and
-prefill times. It prints, per seed, the requests sent in the window, the
+under one of the benchmark's own closed-loop decks (``--mix``, a file of
+``benchmark/traffic``; ``chat-closed`` unless named), with the device's and
+the host's times as constants. The defaults are ``kimivl-chat-closed``'s, from
+the per-request records of four chip runs (PERF.md section 6, PR 33's fix
+round); another cell passes its mix, its buckets (``--buckets``), its step
+and prefill times (``--step-ms``, ``--prefill-ms``, one a bucket), its slots
+and its ramp on the command line. It prints, per seed, the requests sent in the window, the
 rate, the median and the 90th percentile of the time to first token, and the
 shares of the window's requests under 100 ms, in the first mode (the median
 +- 4 ms), in the bucket-512 mode (+9..+19 ms) and beyond it. A CPU timing is
@@ -48,9 +51,12 @@ def quantile(values, q):
 
 def simulate(seed, *, decode_block=8, slots=16, admit_late=True,
              run_ahead=True, host=1.0, jitter=0.15, path_jitter=0.3,
-             ramp_ms=4000.0, window_ms=50000.0, mix="chat-closed", **over):
+             ramp_ms=4000.0, window_ms=50000.0, mix="chat-closed",
+             buckets=BUCKETS, **over):
     """One run of the cell: ([ttft ms of the requests sent in the window],
-    tokens/s). ``host`` scales every host time (a slower machine)."""
+    tokens/s). ``host`` scales every host time (a slower machine);
+    ``buckets``: the engine's prefill buckets, each with its time in
+    ``prefill``; ``over``: any of ``KIMIVL``'s constants."""
     c = {**KIMIVL, **over}
     rng = random.Random(seed ^ 0x5BD1)
     sizes = bench_traffic._sizes_in_order(bench_traffic.load(mix), seed)
@@ -100,7 +106,7 @@ def simulate(seed, *, decode_block=8, slots=16, admit_late=True,
             arrivals.remove(a)
             r, i = a[2], free.pop(0)
             t += h(c["setup"])
-            took = c["prefill"][next(b for b in BUCKETS if b >= r["p"])]
+            took = c["prefill"][next(b for b in buckets if b >= r["p"])]
             start = max(t, device_free)
             device_free = start + took + c["splice"]
             t += h(c["seat"])
@@ -161,11 +167,30 @@ def main(argv=None) -> int:
     ap.add_argument("--decode-block", type=int, default=8)
     ap.add_argument("--no-admit-late", action="store_true")
     ap.add_argument("--host", type=float, default=1.0)
+    ap.add_argument("--mix", default="chat-closed",
+                    help="a closed-loop mix of benchmark/traffic")
+    ap.add_argument("--buckets", default=",".join(map(str, BUCKETS)))
+    ap.add_argument("--prefill-ms", default=",".join(
+        str(KIMIVL["prefill"][b]) for b in BUCKETS),
+        help="a prefill's device time, one a bucket")
+    ap.add_argument("--step-ms", type=float, default=KIMIVL["step"])
+    ap.add_argument("--slots", type=int, default=16)
+    ap.add_argument("--ramp-s", type=float, default=4.0)
+    ap.add_argument("--window-s", type=float, default=50.0)
     args = ap.parse_args(argv)
+    buckets = tuple(int(b) for b in args.buckets.split(","))
+    prefill = [float(x) for x in args.prefill_ms.split(",")]
+    if len(prefill) != len(buckets):
+        ap.error("--prefill-ms gives one time a bucket of --buckets")
     print("[SIMULATION on the CPU: not a chip result]")
     for seed in (int(s) for s in args.seeds.split(",")):
         tt, rate = simulate(seed, decode_block=args.decode_block,
-                            admit_late=not args.no_admit_late, host=args.host)
+                            admit_late=not args.no_admit_late, host=args.host,
+                            mix=args.mix, buckets=buckets, slots=args.slots,
+                            ramp_ms=1e3 * args.ramp_s,
+                            window_ms=1e3 * args.window_s,
+                            step=args.step_ms,
+                            prefill=dict(zip(buckets, prefill)))
         m = quantile(tt, 0.5)
 
         def share(lo, hi):

@@ -60,3 +60,23 @@ def test_cli_prints_a_labelled_line_a_seed(sim, capsys):
     out = capsys.readouterr().out.splitlines()
     assert out[0].startswith("[SIMULATION on the CPU")
     assert out[1].startswith("seed 7: ") and "ttft p50" in out[1]
+
+
+def test_cli_takes_another_cells_mix_buckets_and_times(sim, capsys):
+    """The long-document mix through one bucket of 8,192: every prompt's
+    prefill is one mode, and the defaults still print ``chat-closed``'s
+    line (the case above)."""
+    assert sim.main(["--seeds", "7", "--mix", "longdoc-closed", "--buckets",
+                     "8192", "--prefill-ms", "900", "--step-ms", "20",
+                     "--ramp-s", "12", "--window-s", "30"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[1].startswith("seed 7: ")
+    p50 = float(out[1].split("ttft p50 ")[1].split()[0])
+    assert p50 > 900                      # no first token before a prefill
+    with pytest.raises(SystemExit):
+        sim.main(["--seeds", "7", "--buckets", "256,512", "--prefill-ms",
+                  "16.6"])
+    tt, _ = sim.simulate(7, mix="longdoc-closed", buckets=(8192,),
+                         prefill={8192: 900.0}, step=20.0, ramp_ms=12000.0,
+                         window_ms=30000.0)
+    assert sim.quantile(tt, 0.5) == pytest.approx(p50, abs=0.01)

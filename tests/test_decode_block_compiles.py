@@ -18,8 +18,9 @@ For the latent configuration (``kimi-vl-a3b-l9``), its decode block and its
 prefills of the buckets 256 and 512: the expert layers' scan
 body holds ONE ``kt_moe_experts`` call, whose bank operands are the whole
 stacks, and nothing makes an array the size of an expert, a layer's bank or
-the stack (ISSUE 34). And the census: the dense and Mixtral decode blocks and
-their bucket-256 prefills compile to the multiset of (opcode, result type)
+the stack (ISSUE 34). And the census: the dense, Mixtral and Kimi-VL shaped
+decode blocks and their bucket-256 (Kimi-VL: and 512) prefills compile to the
+multiset of (opcode, result type)
 kept in ``tests/assets/compile_census.json``, which a PR that does not mean
 to touch them leaves as it is; a PR that does makes the file again with
 ``JAX_PLATFORMS=cpu python -m tests.test_decode_block_compiles`` from the
@@ -119,7 +120,7 @@ class _ChipPrograms:
         return self.shaped(jax.eval_shape(
             lambda: init(jax.random.PRNGKey(0), cfg)))
 
-    def decode(self, cfg, init):
+    def decode(self, cfg, init, s_max=S_MAX):
         """``_decode_block``, the engine's common decode signature
         (``aot_cache.warm_engine``; a latent cache's has the routing tally
         and the live mask beside it)."""
@@ -127,11 +128,12 @@ class _ChipPrograms:
         if ("decode", cfg) not in self.texts:
             arg = self.arg
             cache = self.shaped(jax.eval_shape(
-                lambda: E._cache_ops(cfg).init_grid(cfg, SLOTS, S_MAX)))
+                lambda: E._cache_ops(cfg).init_grid(cfg, SLOTS, s_max)))
             extra = {}
-            if hasattr(cfg, "routed_tally_shape"):
-                extra = dict(tally=arg(cfg.routed_tally_shape, jnp.int32),
-                             live=arg((SLOTS,), jnp.bool_))
+            if E._tally_shapes(cfg):
+                extra = dict(tally=self.shaped(jax.eval_shape(
+                    lambda: E._init_tally(cfg))),
+                    live=arg((SLOTS,), jnp.bool_))
             self.texts["decode", cfg] = E._decode_block.lower(
                 self.params(cfg, init), cache, arg((SLOTS,), jnp.int32),
                 arg((SLOTS,), jnp.int32), arg((2,), jnp.uint32),
@@ -307,6 +309,74 @@ def test_latent_programs_stream_their_banks_through_one_kernel_call(
                 sorted((E_, rows, F)), sorted((E_, rows, D)))] == []
 
 
+GLM_S_MAX, GLM_LAYERS = 9728, 3                      # 1 dense + 2 expert
+
+
+def _glm5():
+    """``glm-5-ep16-l6``'s widths, three layers of its six."""
+    from kubetorch_tpu.models.mla import MlaMoeConfig, mla_moe_init
+    return MlaMoeConfig(
+        vocab_size=19360, dim=6144, n_layers=GLM_LAYERS, n_heads=64,
+        kv_lora_rank=512, q_lora_rank=2048, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, ffn_dim=12288, moe_ffn_dim=2048,
+        n_experts=256, held=(0, 16), experts_per_token=8, n_shared_experts=1,
+        routed_scaling_factor=2.5, max_seq_len=GLM_S_MAX, rope_theta=1e6,
+        index_n_heads=32, index_head_dim=128, index_topk=2048), mla_moe_init
+
+
+def test_sparse_latent_decode_block_gathers_from_the_grid_where_it_lies(
+        chip_programs):
+    """The fourth configuration (``glm-5-ep16-l6``) at the cell's widths,
+    16 slots of 9,728 rows: both cache leaves are updated in place and
+    aliased; the selected rows are gathered from the stacked latent grid
+    itself (no layer of it is sliced or copied on the way); the held experts
+    stream through ONE ``kt_moe_experts`` call, whose F-tile fits the VMEM,
+    with the three whole stacks as operands and no bank-sized array made.
+    What it does NOT hold (PERF.md section 7): XLA keeps the latent grid
+    row-minor between blocks and relays it for the gather once on the way
+    into a block and once on the way out, and it materialises the layer's
+    slice of the index keys that the scoring pass reads."""
+    cfg, init = _glm5()
+    text = chip_programs.decode(cfg, init, GLM_S_MAX)
+    by_name = {name: (dims, op) for name, dims, op, _ in _instructions(text)}
+    rows = sorted((GLM_LAYERS, SLOTS, GLM_S_MAX, cfg.latent_dim))
+    keys = sorted((GLM_LAYERS, SLOTS, GLM_S_MAX, cfg.index_head_dim))
+    for grid in (rows, keys):
+        updates = [n for n, (dims, op) in by_name.items()
+                   if dims == grid and op == "dynamic-update-slice"]
+        assert len(updates) >= SLOTS, (grid, updates)
+    assert _aliased_cache_params(text, 2)
+
+    # the gather: (slots x index_topk, latent_dim) rows out of the grid
+    picked = sorted((SLOTS * cfg.index_topk, cfg.latent_dim))
+    gathers = [(n, rest) for n, dims, op, rest in _instructions(text)
+               if dims == picked and op in ("fusion", "gather")
+               and "kt.dsa.gather" in rest]
+    assert gathers, "no gather of the selected rows"
+    for name, rest in gathers:
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+        assert any(by_name.get(o, ([], ""))[0] == rows for o in operands), (
+            name, operands)
+    a_layer = sorted((SLOTS, GLM_S_MAX, cfg.latent_dim))
+    assert [f"{op} {n}" for n, dims, op, _ in _instructions(text)
+            if dims == a_layer] == []
+
+    # the held experts: one kernel call over the whole stacks, F tiled
+    from kubetorch_tpu.ops.moe_experts import f_tile
+    assert f_tile(cfg.dim, cfg.moe_ffn_dim, 2) == 1024
+    calls = [(name, rest) for name, _, op, rest in _instructions(text)
+             if op == "custom-call" and "kt_moe_experts" in name]
+    assert len(calls) == 1, [c[0] for c in calls]
+    stack = sorted((cfg.n_moe_layers, cfg.n_held, cfg.dim, cfg.moe_ffn_dim))
+    operands = re.findall(r"%([\w.\-]+)", calls[0][1].split(")")[0])
+    assert len([o for o in operands
+                if by_name.get(o, ([], ""))[0] == stack]) == 3, operands
+    sized = (sorted((cfg.n_held, cfg.dim, cfg.moe_ffn_dim)), stack)
+    passing = ("parameter", "get-tuple-element", "bitcast")
+    assert [f"{op} {n} {dims}" for n, dims, op, _ in _instructions(text)
+            if dims in sized and op not in passing] == []
+
+
 # -- the census of the older cells' programs -----------------------------------
 
 CENSUS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -332,24 +402,31 @@ def census(text) -> dict:
 
 def older_cells_programs(programs, only=None) -> dict:
     """{name: text}: the decode block and the bucket-256 prefill of the dense
-    and the Mixtral configuration at the cells' widths (``only``: one)."""
+    and the Mixtral configuration at the cells' widths, and of the Kimi-VL
+    shaped one with its bucket-512 prefill (``only``: one)."""
     out = {}
-    for model, (cfg, init) in _configs().items():
+    for model, (cfg, init) in {**_configs(), "kimi-vl-a3b": _kimi()}.items():
         for which, compile_it in (
                 ("decode_block", lambda: programs.decode(cfg, init)),
-                ("prefill_256", lambda: programs.prefill(cfg, init, 256))):
-            if only in (None, f"{model}.{which}"):
+                ("prefill_256", lambda: programs.prefill(cfg, init, 256)),
+                ("prefill_512", lambda: programs.prefill(cfg, init, 512))):
+            if only in (None, f"{model}.{which}") and (
+                    which != "prefill_512" or model == "kimi-vl-a3b"):
                 out[f"{model}.{which}"] = compile_it()
     return out
 
 
 @pytest.mark.parametrize("program", [
     "mistral-7b.decode_block", "mistral-7b.prefill_256",
-    "mixtral-8x7b.decode_block", "mixtral-8x7b.prefill_256"])
+    "mixtral-8x7b.decode_block", "mixtral-8x7b.prefill_256",
+    "kimi-vl-a3b.decode_block", "kimi-vl-a3b.prefill_256",
+    "kimi-vl-a3b.prefill_512"])
 def test_the_older_cells_programs_keep_their_census(chip_programs, program):
-    """A change to the latent family's expert layer leaves the two older
-    cells' device programs as they were: the same instructions making the
-    same arrays, as counted at PR 33's commit."""
+    """A change to the latent family (a query rank, an indexer, a share of
+    the experts: each a path of its own config) leaves the accepted cells'
+    device programs as they were: the same instructions making the same
+    arrays, as counted at PR 33's commit for the two older cells and at PR
+    34's for the Kimi-VL shaped one."""
     with open(CENSUS_FILE) as f:
         want = json.load(f)[program]
     got = census(older_cells_programs(chip_programs, only=program)[program])

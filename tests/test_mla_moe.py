@@ -144,9 +144,9 @@ def test_absorbed_attention_is_the_expanded_attention(tiny):
     t = 13
     h = jax.random.normal(jax.random.PRNGKey(5), (2, t, cfg.dim), jnp.float32)
     freqs = rope_freqs(cfg, t)
-    expanded, rows = mla.expanded_mix(cfg, freqs)(h, lw, None)
-    q_nope, q_pe, row = mla.mla_project(cfg, h[:, -1:], lw,
-                                        freqs[t - 1][None, None])
+    expanded, (rows,) = mla.expanded_mix(cfg, freqs)(h, lw, None)
+    q_nope, q_pe, row, _ = mla.mla_project(cfg, h[:, -1:], lw,
+                                           freqs[t - 1][None, None])
     np.testing.assert_allclose(row[:, 0], rows[:, -1, 0], atol=1e-5)
     # rows beyond the frontier hold another request's values: masked out
     padded = jnp.concatenate(
@@ -204,7 +204,7 @@ def test_router_bias_changes_the_choice_and_not_the_weights():
 
 
 @pytest.mark.parametrize("field,value", [
-    ("n_group", 8), ("topk_group", 4), ("q_lora_rank", 1536),
+    ("n_group", 8), ("topk_group", 4),
     ("scoring_func", "softmax"), ("topk_method", "greedy")])
 def test_config_refuses_what_it_would_have_to_guess(field, value):
     with pytest.raises(UnsupportedMechanismError) as e:

@@ -157,10 +157,45 @@ def test_an_unhit_experts_bank_is_never_read():
     (128, 256, 4, True),
     (64, 32, 4, False),             # ``MlaMoeConfig.tiny``: not lane-aligned
     (2048, 1400, 2, False),
-    (4096, 14336, 2, False),        # an expert of 352 MB does not fit VMEM
+    (4096, 14336, 2, True),         # an expert of 352 MB: 7 tiles of 2,048
+    (6144, 2048, 2, True),          # GLM-5's, 75.5 MB: 2 tiles of 1,024
+    (131072, 256, 2, False),        # 128 columns of it do not fit the VMEM
 ])
 def test_which_shapes_the_kernel_tiles(d, f, itemsize, ok):
     assert K.moe_experts_supported(d, f, itemsize) is ok
+
+
+def test_an_expert_too_wide_for_the_vmem_goes_a_tile_of_f_at_a_time():
+    assert K.f_tile(2048, 1408, 2) == 1408          # whole, one step
+    assert K.f_tile(6144, 2048, 2) == 1024
+    assert K.f_tile(4096, 14336, 2) == 2048
+    assert K.f_tile(64, 32, 4) == 0
+    # the index maps of an expert in 2 steps: gate and up move along F,
+    # down along its rows; a step past the list repeats the last block
+    layer, ids, n_hit = (np.asarray(a, np.int32) for a in ([1], [2, 5, 5, 5],
+                                                           [2]))
+    up = [tuple(int(v) for v in K.bank_block(i, layer, ids, n_hit, chunks=2))
+          for i in range(8)]
+    down = [tuple(int(v) for v in K.bank_block(i, layer, ids, n_hit, chunks=2,
+                                               down=True)) for i in range(8)]
+    assert up == [(1, 2, 0, 0), (1, 2, 0, 1), (1, 5, 0, 0)] + [(1, 5, 0, 1)] * 5
+    assert down == [(1, 2, 0, 0), (1, 2, 1, 0), (1, 5, 0, 0)] + [
+        (1, 5, 1, 0)] * 5
+
+
+@pytest.mark.parametrize("case", ["all-hit", "some-unhit", "none-hit",
+                                  "rows-past-a-tile"])
+def test_the_tiled_kernel_is_the_einsum_form(case, monkeypatch):
+    """The same cases with an expert in two steps of 128 of its 256
+    columns (the VMEM bound lowered to force it)."""
+    monkeypatch.setattr(K, "f_tile", lambda d, f, itemsize: 128)
+    banks = _banks(jnp.float32)
+    gates, sizes = _routing(CASES[case])
+    x = _rows(len(CASES[case]), jnp.float32)
+    got = K.moe_experts(x, gates, banks["w_gate"], banks["w_up"],
+                        banks["w_down"], 2, sizes)
+    np.testing.assert_allclose(got, mla._dense_experts(x, gates, banks, 2),
+                               atol=1e-5)
 
 
 @pytest.mark.parametrize("rows,d,f,backend,kernel", [

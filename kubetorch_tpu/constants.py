@@ -12,6 +12,12 @@ DEFAULT_SERVER_PORT = 32300
 SESSION_HEADER = "X-KT-Session"
 PRIORITY_HEADER = "X-KT-Priority"
 
+# /ready?wait=<seconds>: the longest a pod holds a deploying client's
+# request open while its answer is "not yet", and so what that client asks
+# for. Well under the 600 s of the controller's proxy route, through which
+# a client without a service_url reaches the pod.
+READY_WAIT_CAP_S = 10.0
+
 
 def server_port(value: "str | int | None" = None) -> int:
     """The ONE tolerant KT_SERVER_PORT parse, shared by the pod server, the

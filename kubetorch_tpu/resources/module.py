@@ -16,11 +16,16 @@ from typing import Any, Dict, Optional
 from .. import telemetry
 from ..client import controller_client
 from ..config import config
+from ..constants import READY_WAIT_CAP_S
 from ..exceptions import ServiceHealthError, ServiceTimeoutError
 from ..serving.http_client import HTTPClient
 from ..utils.naming import service_name_for
 from .compute import Compute
 from .pointers import Pointers, extract_pointers
+
+# a /ready that stayed open this long was held by the pod (or by a pod too
+# busy to answer): an answer at once takes milliseconds
+_HELD_FROM_S = 0.1
 
 
 def extract_call_config(kwargs: Dict[str, Any],
@@ -195,13 +200,24 @@ class Module:
                                        (self.compute.launch_timeout
                                         if self.compute else 900))
         delay = 0.2
-        with telemetry.span("deploy.wait_ready", polls=0,
-                            last_delay_s=0.0) as sp:
-            polls = 0
-            while time.monotonic() < deadline:
+        with telemetry.span("deploy.wait_ready", polls=0, last_delay_s=0.0,
+                            held_polls=0, held_s=0.0) as sp:
+            polls = held_polls = 0
+            held_s = 0.0
+            while (left := deadline - time.monotonic()) > 0:
                 polls += 1
                 sp.set_attr("polls", polls)
-                body = client.ready_body(self.launch_id)
+                # the pod waits, not this loop: it holds the request while
+                # the launch is loading and answers when it is warm
+                wait = min(READY_WAIT_CAP_S, left)
+                asked = time.monotonic()
+                body = client.ready_body(self.launch_id, wait=wait)
+                took = time.monotonic() - asked
+                if took >= _HELD_FROM_S:
+                    held_polls += 1
+                    held_s += took
+                    sp.set_attr("held_polls", held_polls)
+                    sp.set_attr("held_s", round(held_s, 3))
                 if body is not None:
                     return body.get("boot")
                 if self._scaled_to_zero():
@@ -210,6 +226,12 @@ class Module:
                     # window elapsed; the first call cold-starts it through
                     # the controller proxy
                     return None
+                if took >= wait:
+                    # held for all of ``wait``: still loading, ask again
+                    continue
+                # "not yet" sooner than asked for: a pod that does not hold
+                # (an older one, a proxy that drops ``wait``), a launch
+                # that cannot become ready, or nothing listening yet
                 time.sleep(delay)
                 sp.set_attr("last_delay_s", delay)
                 delay = min(delay * 2, 3.0)

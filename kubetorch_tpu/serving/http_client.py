@@ -366,15 +366,21 @@ class HTTPClient:
     # -- health ---------------------------------------------------------------
 
     def ready_body(self, launch_id: Optional[str] = None,
-                   timeout: float = 2.0) -> Optional[Dict[str, Any]]:
+                   timeout: float = 2.0,
+                   wait: float = 0.0) -> Optional[Dict[str, Any]]:
         """The pod's ``/ready`` answer once it is ready, else None. Its
         ``boot`` holds the launch's boot phases in seconds and
         ``ready_for_s``, how long the service had been ready when this
-        poll noticed."""
+        request was answered. ``wait`` lets the pod hold the request that
+        long while its answer is "not yet" (``timeout`` is then the room to
+        connect, and to answer on top of ``wait``); a pod that does not
+        hold answers at once, as without it."""
         try:
             params = {"launch_id": launch_id} if launch_id else {}
+            if wait > 0:
+                params["wait"] = str(wait)
             r = self._session.get(f"{self.base_url}/ready", params=params,
-                                  timeout=timeout)
+                                  timeout=(timeout, wait + timeout))
             if r.status_code != 200:
                 return None
             try:

@@ -36,7 +36,7 @@ import socket
 import sys
 import time
 import uuid
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 from aiohttp import web
 
@@ -56,7 +56,7 @@ from .env_contract import (KT_ALLOWED_SERIALIZATION, KT_CALLABLE_TYPE,
                            KT_SERVICE_NAME, apply_metadata)
 from .supervisor_factory import supervisor_for
 
-from ..constants import server_port
+from ..constants import READY_WAIT_CAP_S, server_port
 request_id_var: contextvars.ContextVar[str] = contextvars.ContextVar(
     "kt_request_id", default="")
 
@@ -65,6 +65,10 @@ BOOT_PHASES = ("pod_boot_s", "pool_spawn_s", "rank_spawn_s", "rank_accel_s",
                "rank_import_s", "rank_init_s", "rank_warmup_s")
 
 RESERVED_ROUTES = {"health", "ready", "metrics", "app", "_kt", "debug"}
+
+# how often a held /ready (``wait=``, at most READY_WAIT_CAP_S) looks
+# again: a launch that turns ready is answered within this
+READY_RECHECK_S = 0.025
 
 # probes and the observability surface itself are never spanned: a 3s
 # scrape cadence would churn the whole trace ring in minutes (they still
@@ -508,44 +512,70 @@ async def health(request: web.Request) -> web.Response:
     return web.json_response(body)
 
 
-async def ready(request: web.Request) -> web.Response:
-    """Reload-completion barrier (reference :1670): ready only when the pod's
-    launch_id matches the client's freshly deployed one AND the rank workers
-    have finished their load+warmup window (``__kt_warmup__`` pays jit
-    compilation before the pod joins the endpoint pool)."""
-    state: ServerState = request.app["state"]
-    want = request.query.get("launch_id")
+def _readiness(state: ServerState,
+               want: Optional[str]) -> Tuple[int, Dict[str, Any], bool]:
+    """``/ready``'s answer right now: status, body, and whether a "not yet"
+    is one that waiting can turn into ready (the launch has not arrived or
+    is still loading) rather than one that will stand (a launch that
+    failed)."""
     if want and want != state.launch_id:
-        return web.json_response(
-            {"ready": False, "launch_id": state.launch_id, "expected": want},
-            status=409)
+        return 409, {"ready": False, "launch_id": state.launch_id,
+                     "expected": want}, True
     # the whole load+warmup window: supervisor being built (prewarm task in
     # flight), rank workers still warming, or a rank that DIED during warmup
     # (a pod that can never serve must not report ready)
     task = state._prewarm_task
     if task is not None and not task.done():
-        return web.json_response(
-            {"ready": False, "launch_id": state.launch_id, "warming": True},
-            status=503)
+        return 503, {"ready": False, "launch_id": state.launch_id,
+                     "warming": True}, True
     if state._prewarm_error is not None and state.supervisor is None:
-        return web.json_response(
-            {"ready": False, "launch_id": state.launch_id,
-             "error": state._prewarm_error}, status=503)
+        return 503, {"ready": False, "launch_id": state.launch_id,
+                     "error": state._prewarm_error}, False
     sup = state.supervisor
-    if sup is not None and (getattr(sup, "warming", False)
-                            or getattr(sup, "recovering", False)
-                            or not getattr(sup, "healthy", True)):
+    warming = bool(getattr(sup, "warming", False))
+    recovering = bool(getattr(sup, "recovering", False))
+    healthy = bool(getattr(sup, "healthy", True))
+    if sup is not None and (warming or recovering or not healthy):
         # recovering: the watchdog is respawning dead ranks — readiness
         # flips down for the recovery window and back up once healed
         # (permanent restart-budget exhaustion keeps healthy False forever,
         # so /ready stays down for good)
-        return web.json_response(
-            {"ready": False, "launch_id": state.launch_id,
-             "warming": bool(getattr(sup, "warming", False)),
-             "recovering": bool(getattr(sup, "recovering", False)),
-             "healthy": bool(getattr(sup, "healthy", True))}, status=503)
-    return web.json_response({"ready": True, "launch_id": state.launch_id,
-                              "boot": state.boot_body()})
+        return 503, {"ready": False, "launch_id": state.launch_id,
+                     "warming": warming, "recovering": recovering,
+                     "healthy": healthy}, warming or recovering
+    return 200, {"ready": True, "launch_id": state.launch_id,
+                 "boot": state.boot_body()}, False
+
+
+def _ready_wait(request: web.Request) -> float:
+    """Seconds the caller lets this pod hold its ``/ready`` (``wait=``), cut
+    to the pod's cap; 0.0 for none, which is every probe's."""
+    try:
+        wait = float(request.query.get("wait", 0))
+    except ValueError:
+        return 0.0
+    return min(wait, READY_WAIT_CAP_S) if wait > 0 else 0.0
+
+
+async def ready(request: web.Request) -> web.Response:
+    """Reload-completion barrier (reference :1670): ready only when the pod's
+    launch_id matches the client's freshly deployed one AND the rank workers
+    have finished their load+warmup window (``__kt_warmup__`` pays jit
+    compilation before the pod joins the endpoint pool).
+
+    With ``wait=<seconds>`` the pod does the deploying client's waiting: a
+    "not yet" that can still become ready is held open and answered when
+    it does, or as it stands once ``wait`` (at most ``READY_WAIT_CAP_S``)
+    has run out. Without it, and for a launch that cannot become ready,
+    the answer is immediate."""
+    state: ServerState = request.app["state"]
+    want = request.query.get("launch_id")
+    status, body, pending = _readiness(state, want)
+    until = time.monotonic() + _ready_wait(request)
+    while pending and (left := until - time.monotonic()) > 0:
+        await asyncio.sleep(min(READY_RECHECK_S, left))
+        status, body, pending = _readiness(state, want)
+    return web.json_response(body, status=status)
 
 
 async def metrics(request: web.Request) -> web.Response:

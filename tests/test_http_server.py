@@ -5,6 +5,7 @@ no cluster)."""
 import asyncio
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -204,6 +205,219 @@ def test_ready_carries_the_boot_timeline():
         await state.reload({}, launch_id="boot-2")
         _, again = await wait_ready(client, "boot-2")
         assert again["boot"]["ready_for_s"] < second["boot"]["ready_for_s"]
+    run_server_test(body)
+
+
+# -- /ready?wait=: the pod holds the request until the launch is warm -------
+
+class _StubPool:
+    """What ``ServerState.boot_body`` reads: the moment the ranks were
+    ready."""
+
+    def __init__(self):
+        self.ready_mono = None
+
+    def boot_record(self):
+        return {} if self.ready_mono is None \
+            else {"ready_mono": self.ready_mono}
+
+
+class _StubSup:
+    """A supervisor as ``/ready`` sees one: three flags and a pool."""
+
+    def __init__(self, **flags):
+        self.warming = self.recovering = False
+        self.healthy = True
+        self.pool = _StubPool()
+        for k, v in flags.items():
+            setattr(self, k, v)
+
+    def turn_ready(self):
+        self.warming = self.recovering = False
+        self.healthy = True
+        self.pool.ready_mono = time.monotonic()
+
+    def cleanup(self):
+        pass
+
+
+def _not_ready(state, why):
+    """Put ``state`` (launch ``launch-1``) into one kind of "not yet";
+    returns the body /ready gives for it, and what ends it."""
+    state.launch_id = "launch-1"
+    if why == "prewarm":
+        gate = asyncio.Event()
+        sup = _StubSup()
+
+        async def build():
+            await gate.wait()
+            state.supervisor = sup
+            sup.turn_ready()
+        state._prewarm_task = asyncio.ensure_future(build())
+        return ({"ready": False, "launch_id": "launch-1", "warming": True},
+                gate.set)
+    if why == "other_launch":
+        state.launch_id = "launch-0"
+        state.supervisor = sup = _StubSup()
+
+        def flip():
+            state.launch_id = "launch-1"
+            sup.turn_ready()
+        return ({"ready": False, "launch_id": "launch-0",
+                 "expected": "launch-1"}, flip)
+    state.supervisor = sup = _StubSup(**{why: True})
+    return ({"ready": False, "launch_id": "launch-1",
+             "warming": why == "warming", "recovering": why == "recovering",
+             "healthy": True}, sup.turn_ready)
+
+
+NOT_YET = {"prewarm": 503, "warming": 503, "recovering": 503,
+           "other_launch": 409}
+
+
+async def _timed_get(client, path, **params):
+    t0 = time.monotonic()
+    r = await client.get(path, params=params)
+    body = await r.json() if path == "/ready" else await r.read()
+    return r.status, body, time.monotonic() - t0
+
+
+@pytest.mark.parametrize("why", [*NOT_YET, "real_rank"])
+def test_ready_with_wait_is_answered_when_the_launch_turns_ready(why):
+    """``/ready?wait=`` sent inside the load+warmup window (or before the
+    pod flipped to the asked launch) stays open, and is answered 200 with
+    the ``boot`` body as soon as the launch is ready: ``ready_for_s`` says
+    within 50 ms, and never before the window closed."""
+    async def body(client, state):
+        if why == "real_rank":
+            set_fn_metadata("Warmable")
+            await state.reload({}, launch_id="launch-1")
+            status, got, took = await _timed_get(
+                client, "/ready", launch_id="launch-1", wait=10)
+            assert state.supervisor is not None \
+                and not state.supervisor.warming
+            # the rank was spawned and imported inside the hold
+            assert took >= got["boot"]["rank_spawn_s"] > 0.0
+        else:
+            _, end = _not_ready(state, why)
+            asyncio.get_running_loop().call_later(0.4, end)
+            status, got, took = await _timed_get(
+                client, "/ready", launch_id="launch-1", wait=5)
+            assert 0.4 <= took < 0.6, took
+        assert status == 200 and got["ready"] is True
+        assert got["launch_id"] == "launch-1"
+        assert 0.0 <= got["boot"]["ready_for_s"] <= 0.05, got["boot"]
+    run_server_test(body)
+
+
+@pytest.mark.parametrize("why,ask,cap", [
+    *[(w, 0.3, None) for w in NOT_YET],
+    ("warming", 60, 0.3),       # a wait above the pod's cap is cut to it
+    ("other_launch", "inf", 0.3),
+])
+def test_ready_wait_runs_out_with_the_answer_it_would_have_had(
+        why, ask, cap, monkeypatch):
+    """A ``wait`` shorter than the window, or the pod's cap where ``wait``
+    is above it, gives the "not yet" status and body, after about that
+    long."""
+    from kubetorch_tpu.serving import http_server
+
+    async def body(client, state):
+        if cap is not None:
+            monkeypatch.setattr(http_server, "READY_WAIT_CAP_S", cap)
+        not_yet, _ = _not_ready(state, why)
+        status, got, took = await _timed_get(
+            client, "/ready", launch_id="launch-1", wait=ask)
+        assert (status, got) == (NOT_YET[why], not_yet)
+        assert 0.3 <= took < 0.5, took
+        if state._prewarm_task is not None:
+            state._prewarm_task.cancel()
+    run_server_test(body)
+
+
+@pytest.mark.parametrize("wait", [None, "0", "-1", "nan", "soon", ""])
+@pytest.mark.parametrize("why", [*NOT_YET, "ready"])
+def test_ready_without_wait_answers_at_once(why, wait):
+    """No ``wait`` (a kubelet probe, ``HTTPClient.is_ready``, every poller
+    there was), or one that is no positive number: today's status and body,
+    at once."""
+    async def body(client, state):
+        if why == "ready":
+            state.launch_id = "launch-1"
+            state.supervisor = sup = _StubSup()
+            sup.turn_ready()
+            want_status = 200
+        else:
+            want_body, _ = _not_ready(state, why)
+            want_status = NOT_YET[why]
+        params = {"launch_id": "launch-1"}
+        if wait is not None:
+            params["wait"] = wait
+        status, got, took = await _timed_get(client, "/ready", **params)
+        assert status == want_status and took < 0.1, (status, took)
+        if why == "ready":
+            boot = got.pop("boot")
+            assert got == {"ready": True, "launch_id": "launch-1"}
+            assert boot["ready_for_s"] >= 0.0
+        else:
+            assert got == want_body
+        if state._prewarm_task is not None:
+            state._prewarm_task.cancel()
+    run_server_test(body)
+
+
+@pytest.mark.parametrize("why", ["prewarm_error", "unhealthy", "dead_rank"])
+def test_ready_never_holds_a_launch_that_cannot_become_ready(why):
+    """A supervisor that could not be built, a pool past its restart
+    budget, a rank that died in its warm-up: a broken launch says so at
+    once, whatever ``wait`` the deploying client sent."""
+    async def body(client, state):
+        state.launch_id = "launch-1"
+        if why == "prewarm_error":
+            state._prewarm_error = "ImportError: no module named model"
+            want = {"ready": False, "launch_id": "launch-1",
+                    "error": "ImportError: no module named model"}
+        elif why == "unhealthy":
+            state.supervisor = _StubSup(healthy=False)
+            want = {"ready": False, "launch_id": "launch-1",
+                    "warming": False, "recovering": False, "healthy": False}
+        else:
+            set_fn_metadata("WarmupCrasher")
+            await state.reload({}, launch_id="launch-1")
+            want = {"ready": False, "launch_id": "launch-1",
+                    "warming": False, "recovering": False, "healthy": False}
+            # held while the rank warms up, let go when it dies
+            status, got, took = await _timed_get(
+                client, "/ready", launch_id="launch-1", wait=10)
+            assert (status, got) == (503, want) and took < 9.0, took
+        status, got, took = await _timed_get(
+            client, "/ready", launch_id="launch-1", wait=5)
+        assert (status, got) == (503, want)
+        assert took < 0.1, took
+    run_server_test(body)
+
+
+@pytest.mark.parametrize("path", ["/health", "/metrics", "/ready"])
+def test_a_held_ready_keeps_nothing_else_waiting(path):
+    """The hold is a coroutine asleep: while several ``/ready`` are held,
+    ``/health``, ``/metrics`` and a probe's own ``/ready`` are served in
+    their usual time."""
+    async def body(client, state):
+        _, alone, _ = await _timed_get(client, path)
+        _, end = _not_ready(state, "warming")
+        held = [asyncio.ensure_future(_timed_get(
+            client, "/ready", launch_id="launch-1", wait=5))
+            for _ in range(3)]
+        await asyncio.sleep(0.1)
+        assert not any(h.done() for h in held)
+        status, _, took = await _timed_get(client, path)
+        assert status == (503 if path == "/ready" else 200)
+        assert took < 0.1, took
+        end()
+        for h in held:
+            status, got, took = await h
+            assert status == 200 and 0.1 <= took < 0.5
+            assert got["boot"]["ready_for_s"] <= 0.05
     run_server_test(body)
 
 

@@ -209,6 +209,7 @@ class TestMetricStreamE2E:
             assert {"deploy.launch", "deploy.check_service_ready",
                     "deploy.wait_ready"} <= set(kids)
             assert kids["deploy.wait_ready"]["attrs"]["polls"] >= 1
+            assert kids["deploy.wait_ready"]["attrs"]["held_polls"] >= 1
             assert kids["deploy.check_service_ready"]["attrs"]["polls"] >= 1
             attrs = dep["attrs"]
             for phase in BOOT_PHASES:
@@ -218,7 +219,7 @@ class TestMetricStreamE2E:
             for phase in ("pod_boot_s", "pool_spawn_s", "rank_spawn_s",
                           "rank_import_s"):
                 assert attrs["boot." + phase] > 0.0, phase
-            assert 0.0 <= attrs["poll_slack_s"] <= 3.5   # the back-off's cap
+            assert 0.0 <= attrs["poll_slack_s"] <= 0.5   # held, not polled
             took = dep["end_mono"] - dep["start_mono"]
             assert attrs["boot.rank_spawn_s"] + attrs["poll_slack_s"] < took
             text = tel.format_waterfall(tel.RING.find(dep["trace_id"]))
@@ -835,14 +836,18 @@ class TestTimelineBackToTheCaller:
         assert kids["deploy.check_service_ready"]["attrs"]["polls"] == 2
         assert kids["deploy.check_service_ready"]["attrs"][
             "last_delay_s"] == 0.25
-        assert kids["deploy.wait_ready"]["attrs"]["polls"] >= 1
+        waited = kids["deploy.wait_ready"]["attrs"]
+        # the pod held the request through the rank's boot (ISSUE 37): the
+        # client never slept beside it
+        assert waited["polls"] >= waited["held_polls"] >= 1
+        assert waited["held_s"] > 0.0 and waited["last_delay_s"] == 0.0
         attrs = dep["attrs"]
         assert {"boot." + p for p in BOOT_PHASES} | {"poll_slack_s"} \
             <= set(attrs)
         assert all(attrs["boot." + p] >= 0.0 for p in BOOT_PHASES)
         for phase in ("pool_spawn_s", "rank_spawn_s", "rank_import_s"):
             assert attrs["boot." + phase] > 0.0, phase
-        assert 0.0 <= attrs["poll_slack_s"] <= 3.5      # the back-off's cap
+        assert 0.0 <= attrs["poll_slack_s"] <= 0.5      # held, not polled
         took = dep["end_mono"] - dep["start_mono"]
         assert attrs["boot.rank_spawn_s"] + attrs["poll_slack_s"] < took
         # the pod is gone; the caller's ring alone renders the deploy

@@ -19,8 +19,6 @@ these seams:
   ``kt_stage_seconds`` histograms across replicas (counter-reset aware),
   computes multi-window SLO burn rates, and emits typed
   :class:`~kubetorch_tpu.exceptions.SloBurnAlert` records.
-- :mod:`trace_record` — the policy-lab recording seam (ROADMAP item 4):
-  op-indexed, seeded-replay-friendly trace files a simulator can replay.
 """
 
 from .blackbox import (format_blackbox, metric_diff, read_spool,  # noqa: F401
@@ -31,5 +29,3 @@ from .fleet import (CounterEpochs, FleetAggregator,  # noqa: F401
 from .recorder import (FlightRecorder, apply_delta, chain_hash,  # noqa: F401
                        maybe_start_recorder, note_death, recorder,
                        snapshot_delta)
-from .trace_record import (TRACE_SCHEMA, TraceReader,  # noqa: F401
-                           TraceRecorder)

@@ -54,6 +54,7 @@ from .env_contract import (KT_ALLOWED_SERIALIZATION, KT_CALLABLE_TYPE,
                            KT_FILE_PATH, KT_INIT_ARGS, KT_LAUNCH_ID,
                            KT_MODULE_NAME, KT_NAMESPACE, KT_PROJECT_ROOT,
                            KT_SERVICE_NAME, apply_metadata)
+from .process_pool import RANK_BOOT_DETAIL, RANK_BOOT_PHASES
 from .supervisor_factory import supervisor_for
 
 from ..constants import READY_WAIT_CAP_S, server_port
@@ -61,8 +62,7 @@ request_id_var: contextvars.ContextVar[str] = contextvars.ContextVar(
     "kt_request_id", default="")
 
 # phases of a launch's boot, as /ready reports them (ServerState.boot_body)
-BOOT_PHASES = ("pod_boot_s", "pool_spawn_s", "rank_spawn_s", "rank_accel_s",
-               "rank_import_s", "rank_init_s", "rank_warmup_s")
+BOOT_PHASES = ("pod_boot_s", "pool_spawn_s") + RANK_BOOT_PHASES
 
 RESERVED_ROUTES = {"health", "ready", "metrics", "app", "_kt", "debug"}
 
@@ -101,6 +101,9 @@ class ServerState:
         # client.
         self.boot: Dict[str, float] = {}
         self._launch_mono = time.monotonic()
+        # the launch whose boot kt_cold_start_* last observed (none yet:
+        # a pod may run with no launch id at all)
+        self._cold_start_noted: Any = object()
         self.request_count = 0
         self.inflight = 0          # concurrency signal for the autoscaler
         self.last_activity = time.time()
@@ -196,19 +199,45 @@ class ServerState:
     def boot_body(self) -> Dict[str, float]:
         """``boot`` of a ready ``/ready``: every phase of the launch in
         seconds (0.0 where it did not happen in this launch), the slowest
-        rank's ``rank_*_s``, and ``ready_for_s``: how long the service has
-        been ready, so the polling client can tell how late it noticed."""
+        rank's ``rank_*_s`` and what they hold (``RANK_BOOT_DETAIL``), and
+        ``ready_for_s``: how long the service has been ready, so the
+        polling client can tell how late it noticed. The first ready body
+        of a launch also records it as a cold start (:meth:`_note_cold_start`)."""
         rec = {}
         pool = getattr(self.supervisor, "pool", None)
         if pool is not None and hasattr(pool, "boot_record"):
             rec = pool.boot_record()
         ready_mono = rec.pop("ready_mono", None)
-        out = {k: 0.0 for k in BOOT_PHASES}
+        out = {k: 0.0 for k in BOOT_PHASES + RANK_BOOT_DETAIL}
         out.update(self.boot)
         out.update(rec)
+        if ready_mono is not None:
+            self._note_cold_start(out, ready_mono)
         out["ready_for_s"] = (0.0 if ready_mono is None
                               else max(0.0, time.monotonic() - ready_mono))
         return {k: round(v, 6) for k, v in out.items()}
+
+    def _note_cold_start(self, boot: Dict[str, float],
+                         ready_mono: float) -> None:
+        """The launch's boot, once, into the replica cold-start family
+        (``kt_cold_start_*``, ISSUE 16), which the controller's fast-scale
+        gate reads: a plain deploy is a measured cold start too. Phases:
+        ``import`` (jax and the target's module), ``compile_or_cache``
+        (``rank_jit_s``), ``engine_init`` (constructor and warm-up); the
+        total runs from this process's start, or the launch if it came to
+        a pod that was up, to the last rank's readiness."""
+        if self._cold_start_noted == self.launch_id:
+            return
+        self._cold_start_noted = self.launch_id
+        fam = telemetry.cold_start_metrics()
+        for phase, seconds in (
+                ("import", boot["rank_jax_import_s"] + boot["rank_import_s"]),
+                ("compile_or_cache", boot["rank_jit_s"]),
+                ("engine_init", boot["rank_init_s"] + boot["rank_warmup_s"])):
+            fam["phase_seconds"].observe(seconds, phase=phase)
+        fam["total"].set(boot["pod_boot_s"]
+                         + max(0.0, ready_mono - self._launch_mono))
+        fam["boot_ts"].set(time.time() - (time.monotonic() - ready_mono))
 
     async def reload(self, metadata: Dict[str, Any], launch_id: str) -> None:
         """Hot reload (reference _handle_reload :352): metadata → code sync →

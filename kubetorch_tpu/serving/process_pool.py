@@ -27,6 +27,16 @@ from . import shm_ring
 from .env_contract import RankInfo
 from .watchdog import Watchdog
 
+# a rank's boot as it reports it (``process_worker.boot_record``), with the
+# pool's ``rank_spawn_s``: consecutive phases, which sum to the rank's boot
+RANK_BOOT_PHASES = ("rank_spawn_s", "rank_accel_s", "rank_import_s",
+                    "rank_init_s", "rank_warmup_s")
+# and what lies inside them: ``rank_accel_s`` is the jax import and the
+# TPU's open; ``rank_jit_s`` (every trace, lowering and compile or fetch)
+# and the programs jax asked for and compiled fall in the later phases
+RANK_BOOT_DETAIL = ("rank_jax_import_s", "rank_tpu_open_s", "rank_jit_s",
+                    "rank_programs", "rank_programs_compiled")
+
 
 class ProcessPool:
     def __init__(self, num_procs: int, framework_name: str,
@@ -545,12 +555,14 @@ class ProcessPool:
         return any(w.alive and w.in_warmup for w in self.workers)
 
     def boot_record(self) -> Dict[str, float]:
-        """The slowest rank's boot phases (``rank_*_s``, durations) and
-        ``ready_mono``: the monotonic time the last rank became ready
-        (absent while any is still warming)."""
+        """The slowest rank's boot record (its phases :data:`RANK_BOOT_PHASES`
+        and what they hold, :data:`RANK_BOOT_DETAIL`) and ``ready_mono``: the
+        monotonic time the last rank became ready (absent while any is
+        still warming). The slowest is the one whose phases sum highest:
+        the details overlap them and weigh nothing."""
         boots = [getattr(w, "boot", {}) for w in self.workers]
         out = dict(max(boots, key=lambda b: sum(
-            v for k, v in b.items() if k.endswith("_s")), default={}))
+            b.get(k, 0.0) for k in RANK_BOOT_PHASES), default={}))
         out.pop("ready_mono", None)
         if boots and all("ready_mono" in b for b in boots):
             out["ready_mono"] = max(b["ready_mono"] for b in boots)

@@ -26,6 +26,7 @@ import contextvars
 import multiprocessing as mp
 import os
 import queue as queue_mod
+import sys
 import threading
 import time
 import traceback
@@ -92,11 +93,9 @@ def _worker_main(request_q: mp.Queue, response_q: mp.Queue,
                  init_args: Optional[Dict], framework_name: str,
                  identity_env: Optional[Dict[str, str]] = None,
                  shm_spec: Optional[Dict[str, str]] = None) -> None:
-    import sys as _sys
-
     os.environ.update(env)
-    _sys.stdout = _QueueTee(_sys.stdout, response_q, "stdout")
-    _sys.stderr = _QueueTee(_sys.stderr, response_q, "stderr")
+    sys.stdout = _QueueTee(sys.stdout, response_q, "stdout")
+    sys.stderr = _QueueTee(sys.stderr, response_q, "stderr")
     # Cooperative preemption (ISSUE 6): SIGTERM no longer kills the rank
     # mid-step — it flips the process-local drain flag, the in-flight user
     # step observes it via elastic.drain_requested() and flushes a committed
@@ -158,22 +157,29 @@ async def _worker_loop(request_q, response_q, pointers_dict, init_args,
     # (b) shutdown withholds its SIGKILL escalation — a jit compile in
     # flight must never be force-killed (it can wedge the TPU runtime).
     # The boot's phases ride the same state ops (ISSUE 26): the pool learns
-    # how long this rank took to open the chip, import the target, build it
-    # and warm it up, and /ready hands that to the deploying client.
+    # how long this rank took to import jax, open the chip, import the
+    # target, build it and warm it up, and /ready hands that to the
+    # deploying client. The phases are one span tree (ISSUE 38), shipped to
+    # the pod's ring like a call's, with every program jax compiled inside.
     from .. import telemetry
     response_q.put({"op": "state", "warmup": "started"})
-    laps = telemetry.Laps()
-    if pointers_dict:
-        try:
-            require_accelerator()
-            laps.lap("rank_accel_s")
-            target = _load_target(pointers_dict, init_args, laps)
-        except BaseException as e:  # noqa: BLE001 — must report, not die
-            load_error = e
-        else:
-            await _run_warmup(target)
-            laps.lap("rank_warmup_s")
-    response_q.put({"op": "state", "warmup": "done", "boot": laps.seconds})
+    boot = telemetry.BootTree("rank.boot", boundary=_count_jit,
+                              rank=os.environ.get("RANK", "0"))
+    with boot:
+        if pointers_dict:
+            try:
+                require_accelerator(boot)
+                target = _load_target(pointers_dict, init_args, boot)
+            except BaseException as e:  # noqa: BLE001 — must report, not die
+                load_error = e
+            else:
+                with boot.phase("warmup"):
+                    await _run_warmup(target)
+        record = boot_record(boot)
+        boot.root.attrs.update(record)
+    for span_dict in boot.spans():
+        response_q.put({"op": "span", "span": span_dict})
+    response_q.put({"op": "state", "warmup": "done", "boot": record})
 
     pending = set()
 
@@ -295,18 +301,50 @@ async def _run_warmup(target: Any) -> None:
         print(f"[kt] __kt_warmup__ failed:\n{traceback.format_exc()}")
 
 
-def require_accelerator() -> None:
+def _count_jit() -> None:
+    """From the first phase boundary with jax in this process on, count
+    what it compiles (``telemetry.JIT``); never import jax to count."""
+    from .. import telemetry
+    if "jax" in sys.modules and not telemetry.JIT.installed:
+        import jax
+        telemetry.JIT.install(jax.monitoring, jax.profiler.TraceAnnotation)
+
+
+def boot_record(boot) -> Dict[str, float]:
+    """What the rank reports of its boot, read off the boot's spans:
+    ``rank_<phase>_s`` for each phase that ran; ``rank_accel_s``, the jax
+    import and the TPU's open together; and, once jax is counted,
+    ``rank_jit_s`` (the union of every trace, lowering and backend compile
+    or fetch), ``rank_programs`` and ``rank_programs_compiled`` (of those,
+    not fetched from the persistent cache)."""
+    from .. import telemetry
+    seconds = boot.seconds()
+    out = {f"rank_{name}_s": s for name, s in seconds.items()}
+    out["rank_accel_s"] = (seconds.get("jax_import", 0.0)
+                           + seconds.get("tpu_open", 0.0))
+    if telemetry.JIT.installed:
+        jit = telemetry.JIT.totals()
+        out.update(rank_jit_s=jit["jit_s"], rank_programs=jit["programs"],
+                   rank_programs_compiled=jit["programs_compiled"])
+    return out
+
+
+def require_accelerator(boot) -> None:
     """One rank process per chip, and a rank that was given the chip holds
     it. ``JAX_PLATFORMS`` naming ``tpu`` first is the statement that this
     process owns the accelerator (the local backend sets it for pods whose
     ``Compute`` asks for a TPU, and ``cpu`` for every other pod): initialize
-    jax now and refuse to load on any other backend."""
+    jax now and refuse to load on any other backend. jax's import and the
+    TPU client's open are the ``boot`` tree's phases ``jax_import`` and
+    ``tpu_open``."""
     if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() != "tpu":
         return
     from ..exceptions import AcceleratorUnavailableError
     try:
-        import jax
-        backend = jax.default_backend()
+        with boot.phase("jax_import"):
+            import jax
+        with boot.phase("tpu_open"):
+            backend = jax.default_backend()
     except RuntimeError as e:    # jax: "Unable to initialize backend 'tpu'"
         raise AcceleratorUnavailableError(
             f"this rank was given the TPU but jax could not open it: {e}"
@@ -318,16 +356,14 @@ def require_accelerator() -> None:
 
 
 def _load_target(pointers_dict: Dict, init_args: Optional[Dict],
-                 laps=None) -> Any:
-    obj = import_callable(Pointers.from_dict(pointers_dict))
-    if laps is not None:
-        laps.lap("rank_import_s")
+                 boot) -> Any:
+    with boot.phase("import"):
+        obj = import_callable(Pointers.from_dict(pointers_dict))
     if isinstance(obj, type):
         args = (init_args or {}).get("args", [])
         kwargs = (init_args or {}).get("kwargs", {})
-        obj = obj(*args, **kwargs)
-        if laps is not None:
-            laps.lap("rank_init_s")
+        with boot.phase("init"):
+            obj = obj(*args, **kwargs)
     return obj
 
 

@@ -43,6 +43,7 @@ without cycles.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import os
 import threading
@@ -979,7 +980,7 @@ def apply_timing(sp, header_value: Optional[str]) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Phase clocks: a hot loop's anatomy, and a one-shot sequence's laps
+# Phase clocks: a hot loop's anatomy, and a one-shot sequence's span tree
 # ---------------------------------------------------------------------------
 
 
@@ -1071,20 +1072,236 @@ class PhaseClock:
         return out
 
 
-class Laps:
-    """Consecutive phases of a one-shot sequence (a boot), each lap from
-    the end of the last: ``laps.lap("rank_import_s")`` after the import."""
+class BootTree:
+    """A one-shot sequence (a rank's boot) as one span with a child span
+    per phase: ``with tree:`` opens the root, ``with tree.phase("init"):``
+    a ``boot.init`` child. The spans are made whether or not tracing is on,
+    because what the sequence reports (:meth:`seconds`) is read off their
+    stamps; with tracing off :meth:`spans` has nothing to ship. Inside a
+    phase, programs jax compiles on a thread with no span of its own are
+    parented onto that phase (:data:`JIT`). ``boundary`` runs after each
+    phase."""
 
-    __slots__ = ("seconds", "_t")
+    def __init__(self, name: str, boundary=None, **attrs: Any):
+        self.root = Span(name, _new_id(8), _new_id(4), None, attrs)
+        self.boundary = boundary
+        self.done: Dict[str, Span] = {}
+
+    def __enter__(self) -> "BootTree":
+        self.root.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.root.__exit__(exc_type, exc, tb)
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        sp = Span("boot." + name, self.root.trace_id, _new_id(4),
+                  self.root.span_id, {})
+        JIT.parent = sp
+        try:
+            with sp:
+                yield sp
+        finally:
+            JIT.parent = None
+            self.done[name] = sp
+            if self.boundary is not None:
+                self.boundary()
+
+    def seconds(self) -> Dict[str, float]:
+        """Seconds of each phase that ran, by name."""
+        return {name: sp.seconds() for name, sp in self.done.items()}
+
+    def spans(self) -> List[Dict]:
+        """The finished tree, with whatever opened under it (``jit``
+        spans among them), oldest first; nothing with tracing off."""
+        return RING.find(self.root.trace_id) if enabled() else []
+
+
+def finished_span(name: str, start: float, end: float,
+                  parent: Optional[Span] = None, **attrs: Any
+                  ) -> Optional[Dict]:
+    """Record a span that has already happened, from its wall stamps
+    (``time.time()``), into the ring; its monotonic stamps are the wall's
+    at the offset between the two clocks now. Returns its dict, or None
+    with tracing off."""
+    if not enabled():
+        return None
+    if parent is not None:
+        trace_id, parent_id = parent.trace_id, parent.span_id
+    else:
+        trace_id, parent_id = _new_id(8), None
+    sp = Span(name, trace_id, _new_id(4), parent_id, attrs)
+    offset = sp.start_mono - sp.start
+    sp.start, sp.end = start, end
+    sp.start_mono, sp.end_mono = start + offset, end + offset
+    d = sp.to_dict()
+    RING.add(d)
+    return d
+
+
+def _union_seconds(intervals: Iterable[Tuple[float, float]]) -> float:
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+# jax's own compile events (``jax.monitoring``, jax 0.9): each is a time
+# span with ``fun_name``, and each also announces its start as a scalar
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# what the persistent cache said, fired on the compiling thread while its
+# backend span is still open: asked (and, unless a hit follows, missed)
+JIT_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+
+
+class _JitThread:
+    """One thread's program in the making: its open compile events, the
+    trace and lowering intervals since its last backend compile, and what
+    the persistent cache said."""
+
+    __slots__ = ("depth", "note", "pending", "cache")
 
     def __init__(self):
-        self.seconds: Dict[str, float] = {}
-        self._t = time.monotonic()
+        self.depth = 0
+        self.note = None
+        self.pending: Dict[str, List[Tuple[float, float]]] = {
+            "trace": [], "lower": []}
+        self.cache: Optional[str] = None
 
-    def lap(self, name: str) -> None:
-        now = time.monotonic()
-        self.seconds[name] = self.seconds.get(name, 0.0) + now - self._t
-        self._t = now
+
+class JitTally:
+    """Every program jax traced, lowered and compiled or fetched in this
+    process, from jax's own ``jax.monitoring`` events (:meth:`install`
+    registers the listeners; this module never imports jax).
+
+    Process-wide and thread-safe. :meth:`totals`: ``jit_s``, the union of
+    all the intervals (a jit traced inside another's trace fires nested
+    events, counted once); ``programs``, the programs jax asked the backend
+    for (eager ops' small ones included); ``programs_compiled``, those of
+    them not fetched from the persistent cache. With tracing on, each program is
+    also a finished ``jit`` span (``program``, ``cache`` ∈ hit / miss /
+    uncached, ``trace_s``, ``lower_s``, ``backend_s``) under the span
+    current on the compiling thread, else under :attr:`parent`, and its
+    outermost events are ``annotate("kt.jit:<name>")`` on a profiler's host
+    timeline while they run."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._intervals: List[Tuple[float, float]] = []
+        self.programs = 0
+        self.compiled = 0
+        self.parent: Optional[Span] = None
+        self._monitoring = None
+        self._annotate = None
+
+    @property
+    def installed(self) -> bool:
+        return self._monitoring is not None
+
+    def install(self, monitoring, annotate=None) -> None:
+        """Listen to ``monitoring`` (the ``jax.monitoring`` module); once."""
+        if self._monitoring is not None:
+            return
+        self._monitoring, self._annotate = monitoring, annotate
+        monitoring.register_scalar_listener(self._on_start)
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_listener(self._on_event)
+
+    def uninstall(self) -> None:
+        monitoring, self._monitoring = self._monitoring, None
+        if monitoring is not None:
+            monitoring.unregister_scalar_listener(self._on_start)
+            monitoring.unregister_event_time_span_listener(self._on_span)
+            monitoring.unregister_event_listener(self._on_event)
+
+    def totals(self) -> Dict[str, float]:
+        with self._lock:
+            return {"jit_s": _union_seconds(self._intervals),
+                    "programs": self.programs,
+                    "programs_compiled": self.compiled}
+
+    def _thread(self) -> _JitThread:
+        st = getattr(self._local, "st", None)
+        if st is None:
+            st = self._local.st = _JitThread()
+        return st
+
+    def _on_start(self, event: str, value, **kw) -> None:
+        kind = JIT_EVENTS.get(event)
+        if kind is None:
+            return
+        st = self._thread()
+        st.depth += 1
+        if st.depth > 1:
+            return
+        if kind == "trace" and st.pending["lower"]:
+            # the last program was lowered and never compiled (``.lower()``
+            # alone): its intervals count, its span would be this one's
+            st.pending = {"trace": [], "lower": []}
+        if self._annotate is not None and enabled():
+            st.note = self._annotate(
+                "kt.jit:" + _program_name(kw.get("fun_name")))
+            st.note.__enter__()
+
+    def _on_span(self, event: str, start: float, end: float, **kw) -> None:
+        kind = JIT_EVENTS.get(event)
+        if kind is None:
+            return
+        st = self._thread()
+        st.depth = max(st.depth - 1, 0)
+        if st.depth == 0 and st.note is not None:
+            note, st.note = st.note, None
+            note.__exit__(None, None, None)
+        self._add(start, end, kind == "backend", st.cache)
+        if kind != "backend":
+            st.pending[kind].append((start, end))
+            return
+        cache, st.cache = st.cache or "uncached", None
+        pending, st.pending = st.pending, {"trace": [], "lower": []}
+        first = min([start] + [a for ivs in pending.values()
+                               for a, _ in ivs])
+        finished_span("jit", first, end, current_span() or self.parent,
+                      program=_program_name(kw.get("fun_name")),
+                      cache=cache,
+                      trace_s=_union_seconds(pending["trace"]),
+                      lower_s=_union_seconds(pending["lower"]),
+                      backend_s=end - start)
+
+    def _on_event(self, event: str, **kw) -> None:
+        cache = JIT_CACHE_EVENTS.get(event)
+        if cache is not None:
+            self._thread().cache = cache
+
+    def _add(self, a: float, b: float, program: bool,
+             cache: Optional[str]) -> None:
+        with self._lock:
+            if program:
+                self.programs += 1
+                self.compiled += cache != "hit"
+            self._intervals.append((a, b))
+
+
+def _program_name(fun_name: Any) -> str:
+    """``jit(f)`` → ``f``: jax names the lowered program after the
+    function it traced."""
+    name = str(fun_name or "?")
+    return name[4:-1] if name.startswith("jit(") and name.endswith(")") \
+        else name
+
+
+JIT = JitTally()
 
 
 # ---------------------------------------------------------------------------

@@ -126,12 +126,14 @@ def test_configuration_carries_the_published_widths(full):
         "admit_wait_ms", "admit_queue_ms", "admit_prefill_ms",
         "prefill_share", "deploy_pod_boot_s", "deploy_rank_boot_s",
         "deploy_poll_slack_s", "dsa_selected_share",
-        "step_mfu.serve_dsa_moe"}
+        "step_mfu.serve_dsa_moe", "deploy_jax_import_s", "deploy_tpu_open_s",
+        "deploy_jit_s", "deploy_programs_compiled"}
     assert {moved[name] for name in listed - {"ttft_p50_ms"}} == {
         "ttft_p50_ms", "setup_s"}
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        "dsa_selected_share", "step_mfu.serve_dsa_moe"]
-    assert bench["per_layer"][-2] == {
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("dsa_selected_share")
+    assert names[at + 1] == "step_mfu.serve_dsa_moe"
+    assert bench["per_layer"][at] == {
         "name": "dsa_selected_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "attention: selection",
         "moves": "ttft_p50_ms", "workloads": [CELL]}

@@ -236,14 +236,19 @@ def test_rank_given_the_tpu_refuses_the_cpu(monkeypatch):
 def test_require_accelerator_unit(monkeypatch):
     from kubetorch_tpu.exceptions import AcceleratorUnavailableError
     from kubetorch_tpu.serving.process_worker import require_accelerator
+    from kubetorch_tpu.telemetry import BootTree
     for held_to_cpu in ("cpu", "", "cpu,tpu"):
         monkeypatch.setenv("JAX_PLATFORMS", held_to_cpu)
-        require_accelerator()                  # not given the chip: no check
+        boot = BootTree("rank.boot")
+        require_accelerator(boot)              # not given the chip: no check
+        assert boot.seconds() == {}
     # given the chip, but this process's jax is on the CPU
     monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    boot = BootTree("rank.boot")
     with pytest.raises(AcceleratorUnavailableError) as e:
-        require_accelerator()
+        require_accelerator(boot)
     assert e.value.backend == "cpu"
+    assert set(boot.seconds()) == {"jax_import", "tpu_open"}
 
 
 def test_jax_env_names_libtpu_hosts_only_for_many(monkeypatch):

@@ -178,6 +178,7 @@ def test_ready_carries_the_boot_timeline():
     phase in seconds, and ``ready_for_s``, which grows from poll to poll —
     how long the service had been ready when the client noticed."""
     from kubetorch_tpu.serving.http_server import BOOT_PHASES
+    from kubetorch_tpu.serving.process_pool import RANK_BOOT_DETAIL
 
     async def body(client, state):
         set_fn_metadata("Warmable")
@@ -187,7 +188,8 @@ def test_ready_carries_the_boot_timeline():
             assert "boot" not in await r.json()     # not before it is ready
         _, first = await wait_ready(client, "boot-1")
         boot = first["boot"]
-        assert set(boot) == set(BOOT_PHASES) | {"ready_for_s"}
+        assert set(boot) == set(BOOT_PHASES) | set(RANK_BOOT_DETAIL) \
+            | {"ready_for_s"}
         assert all(v >= 0.0 for v in boot.values()), boot
         # this launch came to a pod that was up (no pod_boot of its own);
         # its pool was spawned, and the rank took time to start and import
@@ -205,6 +207,37 @@ def test_ready_carries_the_boot_timeline():
         await state.reload({}, launch_id="boot-2")
         _, again = await wait_ready(client, "boot-2")
         assert again["boot"]["ready_for_s"] < second["boot"]["ready_for_s"]
+    run_server_test(body)
+
+
+def test_a_plain_launch_turning_ready_is_a_measured_cold_start():
+    """A launch deployed the normal way sets the replica cold-start family
+    (ISSUE 38) the first time it is seen ready, which the controller's
+    fast-scale gate reads: the total from the launch to the last rank's
+    readiness, its timestamp, and one observation a phase; a second ready
+    answer of the same launch observes nothing more."""
+    from kubetorch_tpu import telemetry
+
+    fam = telemetry.cold_start_metrics()
+    fam["total"].set(0.0)
+    fam["boot_ts"].set(0.0)
+    before = {p: fam["phase_seconds"].count(phase=p)
+              for p in ("import", "compile_or_cache", "engine_init")}
+
+    async def body(client, state):
+        set_fn_metadata("Warmable")
+        t0 = time.time()
+        await state.reload({}, launch_id="cold-1")
+        _, first = await wait_ready(client, "cold-1")
+        boot = first["boot"]
+        total = fam["total"].value()
+        assert total > 0.0
+        assert total >= boot["rank_import_s"] + boot["rank_warmup_s"]
+        assert t0 <= fam["boot_ts"].value() <= time.time()
+        await wait_ready(client, "cold-1")
+        for phase, n in before.items():
+            assert fam["phase_seconds"].count(phase=phase) == n + 1, phase
+        assert fam["total"].value() == total
     run_server_test(body)
 
 

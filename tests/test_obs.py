@@ -22,8 +22,8 @@ from kubetorch_tpu import telemetry
 from kubetorch_tpu.exceptions import (SloBurnAlert, package_exception,
                                       rehydrate_exception)
 from kubetorch_tpu.obs import (CounterEpochs, FleetAggregator, FlightRecorder,
-                               TraceReader, TraceRecorder, format_blackbox,
-                               merge_histograms, read_spool, reconstruct)
+                               format_blackbox, merge_histograms, read_spool,
+                               reconstruct)
 from kubetorch_tpu.soak.history import check_blackbox
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -333,56 +333,6 @@ def test_histogram_blind_above_slo_reads_all_good():
                now=5.0)
     assert agg.tick(now=5.0) == []
     assert agg.status()["stages"]["serve"]["burn"]["fast"] == 0.0
-
-
-# ---------------------------------------------------------------------------
-# trace recording for the policy lab
-# ---------------------------------------------------------------------------
-
-def _span(trace, span, name, start, dur):
-    return {"trace_id": trace, "span_id": span, "name": name,
-            "start": start, "end": start + dur, "status": "ok",
-            "attrs": {"k": "v"}}
-
-
-def test_trace_roundtrip_replay_order_and_dedup(tmp_path):
-    path = tmp_path / "run.trace"
-    with TraceRecorder(path, seed=7, t0=100.0,
-                       meta={"profile": "store"}) as rec:
-        rec.record_span(_span("t1", "s2", "stage.execute", 103.0, 0.02))
-        rec.record_span(_span("t1", "s1", "stage.queue_wait", 101.0, 0.5))
-        assert rec.record_span(
-            _span("t1", "s2", "stage.execute", 103.0, 0.02)) is None
-
-    reader = TraceReader(path)
-    assert reader.seed == 7
-    assert reader.t0 == 100.0
-    assert len(reader) == 2
-    # recorded order is op order; replay re-sorts by relative time
-    assert [op["name"] for op in reader.ops] == ["stage.execute",
-                                                 "stage.queue_wait"]
-    replay = reader.replay()
-    assert [op["name"] for op in replay] == ["stage.queue_wait",
-                                             "stage.execute"]
-    assert replay[0]["t"] == pytest.approx(1.0)
-    assert replay[0]["dur_s"] == pytest.approx(0.5)
-
-
-def test_trace_reader_rejects_schema_and_op_gaps(tmp_path):
-    bad_schema = tmp_path / "bad.trace"
-    bad_schema.write_text(json.dumps({"schema": "kt-trace-v0"}) + "\n")
-    with pytest.raises(ValueError, match="schema"):
-        TraceReader(bad_schema)
-
-    gapped = tmp_path / "gap.trace"
-    with TraceRecorder(gapped, seed=1, t0=0.0) as rec:
-        for i in range(3):
-            rec.record_span(_span("t", f"s{i}", "op", float(i), 0.1))
-    lines = gapped.read_text("utf-8").splitlines()
-    del lines[2]  # drop op 1: indices now 0, 2
-    gapped.write_text("\n".join(lines) + "\n")
-    with pytest.raises(ValueError, match="op index"):
-        TraceReader(gapped)
 
 
 # ---------------------------------------------------------------------------

@@ -357,6 +357,24 @@ class SmokeEngine:
                          qd, grid(kq), grid(ksc), grid(vq), grid(vsc), pos)
             record(f"decode_attention_quant_hd{hd}", err(got, want), 0.08, m)
 
+        # the latent-attention prefill at GLM-5's widths (q/k 192 + 64, the
+        # rope key shared, v 256), a quarter of each query's keys selected
+        from kubetorch_tpu.models.mla import (MlaMoeConfig,
+                                              expanded_attention, select_mask)
+        from kubetorch_tpu.ops.mla_prefill_attention import mla_flash_prefill
+        t, nh = (256, 2) if small else (2048, 8)
+        ks = jax.random.split(jax.random.PRNGKey(7), 6)
+        lat = [jax.random.normal(k, shape, jnp.bfloat16) for k, shape in zip(
+            ks, ((1, t, nh, 192), (1, t, nh, 64), (1, t, nh, 192), (1, t, 64),
+                 (1, t, nh, 256)))]
+        causal = jnp.tril(jnp.ones((t, t), bool))[None]
+        sel = select_mask(MlaMoeConfig.tiny(index_topk=t // 4),
+                          jax.random.normal(ks[5], (1, t, t)), causal)
+        got, m = run(lambda *a: mla_flash_prefill(*a, scale=256 ** -0.5),
+                     *lat, sel.astype(jnp.int8))
+        want = expanded_attention(*lat, sel, 256 ** -0.5).reshape(1, t, -1)
+        record("mla_flash_prefill_hd256", err(got, want), 0.05, m)
+
         din, dout = (256, 512) if small else (2048, 8192)
         w = jax.random.normal(jax.random.PRNGKey(3), (din, dout), f32) \
             / din ** 0.5

@@ -50,8 +50,10 @@ What differs from ``models.llama`` / ``models.moe``, each handed to
   ``w = h·W_Iw / sqrt(heads · width)``; the score of a (query, key) pair is
   ``Σ_j w_j · relu(q_I_j · k_I)`` in float32, and a query attends to the
   ``index_topk`` causal keys of largest score (all of them while there are
-  no more). A prompt applies the selection as a mask, its queries tiled so
-  that a score tile stays bounded (:func:`expanded_mix`); decode scores every
+  no more). A prompt applies the selection as a mask, made a tile of queries
+  at a time so that a score tile stays bounded, and on the TPU attends
+  through ONE flash kernel call that takes the mask as an operand
+  (:func:`expanded_mix`, ``ops.mla_prefill_attention``); decode scores every
   cached key, picks, gathers the chosen latent rows and attends over those
   alone (:func:`select_rows`, ``serve.latent_cache.grid_mix``). A **share of
   the experts** (``held``: first and count): the router still scores all
@@ -174,7 +176,9 @@ class MlaMoeConfig:
     # what the serving engine keys its cache on (``serve.engine._cache_ops``)
     cache_kind = "latent"
     # what ``generate._flash_prefill_wanted`` reads: the expanded heads (q/k
-    # of 192, v of 128) attend through XLA einsums, not the flash kernel
+    # and v of their own widths) never take ``ops.attention``'s kernel; a
+    # prompt too long for one score array takes ``ops.mla_prefill_attention``
+    # (``expanded_mix``)
     attn_impl = "xla"
 
     def __post_init__(self):
@@ -500,15 +504,102 @@ def _query_tile(cfg: MlaMoeConfig, t: int) -> int:
     return tile
 
 
+def _tiles(x: jax.Array, tile: int) -> jax.Array:
+    """(B, T, ...) → (T/tile, B, tile, ...)"""
+    return jnp.moveaxis(x.reshape(x.shape[0], -1, tile, *x.shape[2:]), 1, 0)
+
+
+def flash_prefill_supported(cfg: MlaMoeConfig, t: int) -> bool:
+    """Whether a prompt of t rows takes the flash kernel
+    (``ops.mla_prefill_attention``) where the kernel runs: where the float32
+    scores of all its queries would not be one array (:func:`_query_tile`
+    tiles them), in the kernel's 128-row blocks, a head's values a
+    lane-aligned slice of the output row."""
+    return (_query_tile(cfg, t) < t and t % 128 == 0
+            and cfg.v_head_dim % 128 == 0)
+
+
+def flash_prefill_auto(cfg: MlaMoeConfig, t: int) -> bool:
+    """The choice :func:`expanded_mix` makes (as ``ops.attention.
+    flash_auto``): the kernel on the TPU backend for a prompt it takes, the
+    XLA einsums elsewhere. Decided from the backend and the score array's
+    size alone; nothing is imported here, so a rank whose prompts fit one
+    score array never loads the kernel."""
+    return (jax.default_backend() == "tpu"
+            and flash_prefill_supported(cfg, t))
+
+
+def expanded_attention(q_nope, q_pe, k_nope, k_pe, v, allowed, scale):
+    """Attention over expanded heads as XLA einsums: queries q_nope
+    (B, Tq, N, Dn), q_pe (B, Tq, N, Dr) over keys k_nope (B, S, N, Dn), the
+    rope key every head shares k_pe (B, S, Dr), and values v (B, S, N, Dv);
+    ``allowed`` (B or 1, Tq, S) bool → (B, Tq, N, Dv). Scores and softmax in
+    float32, the probabilities in v's dtype. Also what the flash kernel is
+    held to and what its backward pass differentiates."""
+    with jax.named_scope("kt.attention"):
+        logits = (jnp.einsum("btnh,bsnh->bnts", q_nope, k_nope)
+                  + jnp.einsum("btnh,bsh->bnts", q_pe, k_pe)
+                  ).astype(jnp.float32) * scale
+        logits = jnp.where(allowed[:, None], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+        return jnp.einsum("bnts,bsnh->btnh", probs, v)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _flash_attention(q_nope, q_pe, k_nope, k_pe, v, allowed, scale, tile):
+    """:func:`expanded_attention` over a whole prompt (T queries over their
+    own T keys), ``allowed`` (B, T, T) int8 or None for causal: one call of
+    the flash kernel, imported here. Its gradient is the XLA form's, ``tile``
+    queries at a time."""
+    from ..ops.mla_prefill_attention import mla_flash_prefill
+    return mla_flash_prefill(q_nope, q_pe, k_nope, k_pe, v, allowed,
+                             scale=scale)
+
+
+def _flash_attention_fwd(q_nope, q_pe, k_nope, k_pe, v, allowed, scale,
+                         tile):
+    return (_flash_attention(q_nope, q_pe, k_nope, k_pe, v, allowed, scale,
+                             tile), (q_nope, q_pe, k_nope, k_pe, v, allowed))
+
+
+def _flash_attention_bwd(scale, tile, saved, ct):
+    *xs, allowed = saved
+    b, t = xs[0].shape[:2]
+
+    def tiled(q_nope, q_pe, k_nope, k_pe, v):
+        def one(a):
+            qn, qp, q_at, sel = a
+            mask = ((jnp.arange(t)[None, :] <= q_at[:, None])[None]
+                    if sel is None else sel != 0)
+            return expanded_attention(qn, qp, k_nope, k_pe, v, mask, scale)
+        out = lax.map(jax.checkpoint(one), (
+            _tiles(q_nope, tile), _tiles(q_pe, tile),
+            jnp.arange(t).reshape(-1, tile),
+            None if allowed is None else _tiles(allowed, tile)))
+        return jnp.moveaxis(out, 0, 1).reshape(b, t, -1)
+
+    _, pull = jax.vjp(tiled, *xs)
+    return (*pull(ct), None)
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
 def expanded_mix(cfg: MlaMoeConfig, freqs: jax.Array) -> Callable:
     """The block's mixing operation over T tokens that attend only to
     themselves, causally (training, the plain forward, a from-zero prefill):
     keys and values expanded a head, softmax in float32; with an indexer and
     more keys than ``index_topk``, each query over its selected keys only
-    (the selection as a mask). Plain XLA einsums: the flash kernel wants one
-    width for q, k and v, and here they differ. Queries go a tile at a time
-    where the scores of all of them would be too large (:func:`_query_tile`),
-    in ``KEY_EXTENT_RUNS`` runs of tiles that each stop at their own last key.
+    (the selection as a mask). Where the scores of all queries fit one array
+    (every configuration without an indexer at its buckets), plain XLA
+    einsums over it (:func:`expanded_attention`). Beyond (:func:`_query_tile`)
+    the selection is made a tile of queries at a time, in
+    ``KEY_EXTENT_RUNS`` runs of tiles that each stop at their own last key,
+    and attention is, on the TPU backend, ONE call of the flash kernel
+    (``ops.mla_prefill_attention``: q/k and v of their own widths, the rope
+    key shared, the selection an int8 (B, T, T) operand; no score tile
+    leaves the VMEM) and, elsewhere, the same einsums a tile at a time
+    (:func:`flash_prefill_auto`).
     Returns ``mix(h, lw, lora) -> (attn (B, T, N·Hv), leaves)``, the leaves
     as a row-major prompt cache with one KV head holds them: the rows
     (B, T, 1, R + Hr) and, with an indexer, its keys (B, T, 1, Di)."""
@@ -530,45 +621,55 @@ def expanded_mix(cfg: MlaMoeConfig, freqs: jax.Array) -> Callable:
             if t > cfg.index_topk:        # else every causal key is selected
                 index = (q_idx, w_idx)
 
-        def attend(q_nope, q_pe, q_at, index, upto):
-            """Queries at positions ``q_at`` (Tq,) over the keys 0 ..
-            ``upto`` - 1 (static; none of the queries sees a later one)."""
+        def allowed(q_at, index, upto):
+            """Which of the keys 0 .. ``upto`` - 1 (static; none of the
+            queries sees a later one) queries at positions ``q_at`` (Tq,)
+            attend to: (1 or B, Tq, upto) bool."""
             mask = (jnp.arange(upto)[None, :] <= q_at[:, None])[None]
             if index is not None:
                 mask = select_mask(
                     cfg, index_scores(*index, k_idx[:, :upto]), mask)
-            with jax.named_scope("kt.attention"):
-                logits = (jnp.einsum("btnh,bsnh->bnts", q_nope,
-                                     k_nope[:, :upto])
-                          + jnp.einsum("btnh,bsh->bnts", q_pe,
-                                       row[:, :upto, R:])
-                          ).astype(jnp.float32) * scale
-                logits = jnp.where(mask[:, None], logits, NEG_INF)
-                probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-                return jnp.einsum("bnts,bsnh->btnh", probs, v[:, :upto])
+            return mask
+
+        def attend(q_nope, q_pe, q_at, index, upto):
+            return expanded_attention(
+                q_nope, q_pe, k_nope[:, :upto], row[:, :upto, R:],
+                v[:, :upto], allowed(q_at, index, upto), scale)
 
         tile = _query_tile(cfg, t)
         if tile == t:
-            attn = attend(q_nope, q_pe, jnp.arange(t), index, t)
-        else:
-            def tiles(x):           # (B, Tg, ...) → (Tg/tile, B, tile, ...)
-                return jnp.moveaxis(
-                    x.reshape(b, -1, tile, *x.shape[2:]), 1, 0)
+            return attend(q_nope, q_pe, jnp.arange(t), index, t).reshape(
+                b, t, -1), leaves
 
-            # runs of tiles, each run over the keys up to its own last
-            # query: the causal triangle in KEY_EXTENT_RUNS steps
+        def runs(fn, *rows):
+            """``fn(*row_tiles, q_at, own_index, upto)`` a tile of queries at
+            a time, in runs of tiles each over the keys up to its own last
+            query (the causal triangle in KEY_EXTENT_RUNS steps) → stacked
+            (B, T/tile, tile, ...)."""
             n, done = t // tile, []
             per = -(-n // KEY_EXTENT_RUNS)          # tiles a run
             for first in range(0, n, per):
                 lo, hi = first * tile, min(n, first + per) * tile
                 own = None if index is None or hi <= cfg.index_topk else \
-                    tuple(tiles(x[:, lo:hi]) for x in index)
+                    tuple(_tiles(x[:, lo:hi], tile) for x in index)
                 done.append(lax.map(
-                    lambda a, hi=hi: attend(*a, hi),
-                    (tiles(q_nope[:, lo:hi]), tiles(q_pe[:, lo:hi]),
+                    lambda a, hi=hi: fn(*a, hi),
+                    (*(_tiles(x[:, lo:hi], tile) for x in rows),
                      jnp.arange(lo, hi).reshape(-1, tile), own)))
-            attn = jnp.moveaxis(jnp.concatenate(done, axis=0), 0, 1)
-        return attn.reshape(b, t, -1), leaves
+            return jnp.moveaxis(jnp.concatenate(done, axis=0), 0, 1)
+
+        if not flash_prefill_auto(cfg, t):
+            return runs(attend, q_nope, q_pe).reshape(b, t, -1), leaves
+        sel = None
+        if index is not None:
+            sel = runs(lambda q_at, own, upto: jnp.pad(
+                jnp.broadcast_to(allowed(q_at, own, upto), (b, tile, upto)),
+                ((0, 0), (0, 0), (0, t - upto))).astype(jnp.int8))
+            sel = sel.reshape(b, t, t)
+        with jax.named_scope("kt.attention"):
+            attn = _flash_attention(q_nope, q_pe, k_nope, row[..., R:], v,
+                                    sel, scale, tile)
+        return attn, leaves
 
     return mix
 

@@ -127,9 +127,15 @@ def test_configuration_carries_the_published_widths(full):
         "prefill_share", "deploy_pod_boot_s", "deploy_rank_boot_s",
         "deploy_poll_slack_s", "dsa_selected_share",
         "step_mfu.serve_dsa_moe", "deploy_jax_import_s", "deploy_tpu_open_s",
-        "deploy_jit_s", "deploy_programs_compiled"}
+        "deploy_jit_s", "deploy_programs_compiled",
+        "mla_prefill_attn_roofline"}
     assert {moved[name] for name in listed - {"ttft_p50_ms"}} == {
         "ttft_p50_ms", "setup_s"}
+    # PR 39's kernel share: this cell alone, the last of the list
+    assert bench["per_layer"][-1] == {
+        "name": "mla_prefill_attn_roofline", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels", "moves": "ttft_p50_ms",
+        "workloads": [CELL]}
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("dsa_selected_share")
     assert names[at + 1] == "step_mfu.serve_dsa_moe"
@@ -441,7 +447,9 @@ def test_the_accepted_expert_readers_read_the_held_experts(bench_path, tiny):
 
 def test_metric_files_name_their_readers():
     for name, reader in (("dsa_selected_share", "dsa_selected_share"),
-                         ("step_mfu.serve_dsa_moe", "serve_mfu_dsa_moe")):
+                         ("step_mfu.serve_dsa_moe", "serve_mfu_dsa_moe"),
+                         ("mla_prefill_attn_roofline",
+                          "mla_prefill_attn_roofline")):
         with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
             spec = json.load(f)
         assert spec["reader"] == reader

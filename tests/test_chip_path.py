@@ -426,7 +426,7 @@ def test_chip_smoke_rehearsal_runs_the_whole_control_flow():
         REPO, "chiprun_out", "chip_smoke", "report.json")))
     assert report["requests"]["n"] >= 8
     assert report["reference"]["equals_generate"] is True
-    assert len(report["kernels"]) == 9
+    assert len(report["kernels"]) == 10
     cold, warm = report["serving_cold"], report["serving_reloaded"]
     assert warm["pid"] != cold["pid"]
     assert warm["cache_entries_after_warmup"] == warm["cache_entries_at_start"]

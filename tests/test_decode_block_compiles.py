@@ -26,6 +26,10 @@ to touch them leaves as it is; a PR that does makes the file again with
 ``JAX_PLATFORMS=cpu python -m tests.test_decode_block_compiles`` from the
 root of the repo.
 
+And the latent-attention prefill kernel (ISSUE 39): Kimi-VL's bucket-1,024
+prefill holds no call of it (its score array is one array), and the kernel
+at GLM-5's widths compiles.
+
 The topology is described inside a fixture, and skipped from there where it
 cannot be: nothing touches the TPU library while a module is imported.
 """
@@ -159,7 +163,8 @@ def steered_onto_the_chip(one_chip):
     """The CPU process steered onto the chip's branch here, not by an option
     of the program: the kernel paths, compiled by Mosaic."""
     from kubetorch_tpu.models import generate, mla
-    from kubetorch_tpu.ops import attention, decode_attention, moe_experts
+    from kubetorch_tpu.ops import (attention, decode_attention,
+                                   mla_prefill_attention, moe_experts)
     from kubetorch_tpu.serve import engine as E
     # an entry compiled for a described chip cannot be read back without one
     cached = jax.config.jax_enable_compilation_cache
@@ -167,8 +172,10 @@ def steered_onto_the_chip(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(E, "_decode_kernel_wanted", lambda: True)
         mp.setattr(mla, "moe_experts_auto", moe_experts.moe_experts_supported)
+        mp.setattr(mla, "flash_prefill_auto", mla.flash_prefill_supported)
         mp.setattr(generate, "_FLASH_PREFILL_FLAG", "1")
-        for kernel_mod in (attention, decode_attention, moe_experts):
+        for kernel_mod in (attention, decode_attention, mla_prefill_attention,
+                           moe_experts):
             mp.setattr(kernel_mod, "interpret_default", lambda: False)
         yield _ChipPrograms(one_chip)
     jax.config.update("jax_enable_compilation_cache", cached)
@@ -375,6 +382,35 @@ def test_sparse_latent_decode_block_gathers_from_the_grid_where_it_lies(
     passing = ("parameter", "get-tuple-element", "bitcast")
     assert [f"{op} {n} {dims}" for n, dims, op, _ in _instructions(text)
             if dims in sized and op not in passing] == []
+
+
+def test_only_a_prompt_too_long_for_one_score_array_takes_the_flash_kernel(
+        chip_programs):
+    """Kimi-VL's largest bucket, 1,024 rows of 16 heads, is one score array
+    (64 MB): its prefill, compiled for the chip with every kernel's choice
+    steered to the chip's, holds no call of ``kt_mla_flash_prefill``, so the
+    cell's programs (and its set-up) are what they were (ISSUE 39)."""
+    cfg, init = _kimi()
+    text = chip_programs.prefill(cfg, init, 1024)
+    assert "kt_mla_flash_prefill" not in text
+
+
+def test_the_prefill_kernel_compiles_at_glm5s_widths(chip_programs):
+    """The kernel alone, as GLM-5's bucket of 8,192 calls it (64 heads, q/k
+    of 192 + 64, v of 256, the selection an int8 operand), compiled by
+    Mosaic for the described chip: what interpret mode cannot refuse (block
+    shapes, the VMEM it asks for)."""
+    from kubetorch_tpu.ops.mla_prefill_attention import mla_flash_prefill
+    arg = chip_programs.arg
+    b, t, n, bf = 1, 8192, 64, jnp.bfloat16
+    text = jax.jit(lambda *a: mla_flash_prefill(*a, scale=256 ** -0.5)).lower(
+        arg((b, t, n, 192), bf), arg((b, t, n, 64), bf),
+        arg((b, t, n, 192), bf), arg((b, t, 64), bf), arg((b, t, n, 256), bf),
+        arg((b, t, t), jnp.int8)).compile().as_text()
+    calls = [rest for name, _, op, rest in _instructions(text)
+             if op == "custom-call" and "kt_mla_flash_prefill" in name]
+    assert len(calls) == 1
+    assert 'custom_call_target="tpu_custom_call"' in calls[0]
 
 
 # -- the census of the older cells' programs -----------------------------------

@@ -1,0 +1,16 @@
+"""step_mfu.serve_hybrid: ``serve_mfu``'s reading — model FLOPs of every
+token prefilled or decoded between the two counter readings of the traced
+window, over that time times chips times the bfloat16 peak — with the FLOPs
+of a hybrid Mamba-2 / attention decoder (``bench_flops_hybrid``)."""
+
+import os
+
+import bench_flops_hybrid
+import run
+
+_serve_mfu = run.load_file(os.path.join(os.path.dirname(
+    os.path.abspath(__file__)), "serve_mfu.py"))
+
+
+def read(ctx):
+    return _serve_mfu.read({**ctx, "flops": bench_flops_hybrid})

@@ -1,5 +1,6 @@
 """Model families: Llama (flagship), Mixtral-style MoE, a latent-attention
-(MLA) decoder with fine-grained experts, ViT, ResNet, MLP.
+(MLA) decoder with fine-grained experts, a hybrid of Mamba-2 and attention
+layers, ViT, ResNet, MLP.
 
 The reference ships no models (it is a dispatch fabric; models live in user
 code). This framework makes the headline workloads (BASELINE.md configs 1-5)
@@ -18,6 +19,17 @@ int8 rows, chunked prefill, the prefix store, LoRA on the projections, an
 AOT cache, a sharded mesh, speculative decoding, the scanned ``generate`` —
 raises ``exceptions.UnsupportedMechanismError`` naming the mechanism, as do
 ``n_group``/``topk_group`` other than 1 and a low-rank query in the config.
+
+``models.hybrid`` (imported where it is used, likewise) is Granite-4.0-H-Micro
+(https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json):
+``HybridConfig``, ``hybrid_init``, ``hybrid_forward``; Mamba-2 layers beside
+attention layers with no position embedding, served by
+``serve.GenerationEngine`` over a cache of kind ``"hybrid"``
+(``serve.state_cache``: K/V rows for the attention layers, a slot's
+convolution window and float32 state for the Mamba-2 layers). It refuses, by
+the same error, int8 K/V, chunked prefill, the prefix store, session
+snapshots (``serve.EngineSessionBinder``), LoRA, an AOT cache, a mesh,
+speculative decoding and the scanned ``generate``.
 """
 
 from .llama import LlamaConfig, llama_init, llama_forward, llama_loss

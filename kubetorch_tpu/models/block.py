@@ -89,6 +89,29 @@ def layer_stacks(cfg, params: Dict[str, Any]):
     return [(stack, None, 0, jax.tree_util.tree_leaves(stack)[0].shape[0])]
 
 
+def embed(params: Dict[str, Any], tokens: jax.Array, cfg) -> jax.Array:
+    """The tokens' embeddings in the model dtype, times the family's
+    ``embedding_multiplier`` where it states one other than 1 (Granite);
+    elsewhere nothing more is emitted."""
+    x = params["embed"][tokens].astype(cfg.dtype)
+    m = getattr(cfg, "embedding_multiplier", 1)
+    return x if m == 1 else x * jnp.asarray(m, x.dtype)
+
+
+def scale_logits(logits: jax.Array, cfg) -> jax.Array:
+    """Logits divided by the family's ``logits_scaling`` where it states one
+    other than 1 (Granite); elsewhere as they are."""
+    s = getattr(cfg, "logits_scaling", 1)
+    return logits if s == 1 else logits / s
+
+
+def attention_scale(cfg) -> float:
+    """The softmax scale of a config's attention: ``attention_multiplier``
+    where the family states one (Granite: 1/64 over heads of 64), else
+    ``head_dim ** -0.5``."""
+    return getattr(cfg, "attention_multiplier", None) or cfg.head_dim ** -0.5
+
+
 def with_banks(ffn: Callable, banks, index) -> Callable:
     """``ffn`` with a run's whole ``banks`` and the layer's index in the run
     bound to it as ``banks=(banks, index)``; ``ffn`` itself where the run
@@ -102,7 +125,8 @@ def qkv_attend(cfg, freqs: jax.Array, attend: Callable) -> Callable:
     caller's ``attend(q, k, v) -> (attn, cache)``. Head counts come from the
     projections' own widths: inside ``shard_map`` the leaves are the LOCAL
     column shards (this device's ``n_heads/tp`` query and ``n_kv_heads/tp``
-    kv heads; GQA grouping survives as long as tp | n_kv_heads)."""
+    kv heads; GQA grouping survives as long as tp | n_kv_heads). ``freqs``
+    None: a family with no position embedding (``models.hybrid``)."""
     hd = cfg.head_dim
 
     def mix(h, lw, lora):
@@ -111,7 +135,8 @@ def qkv_attend(cfg, freqs: jax.Array, attend: Callable) -> Callable:
             q = lora_proj(h, lw["wq"], lora, "wq").reshape(b, t, -1, hd)
             k = lora_proj(h, lw["wk"], lora, "wk").reshape(b, t, -1, hd)
             v = lora_proj(h, lw["wv"], lora, "wv").reshape(b, t, -1, hd)
-            q, k = apply_rope(q, freqs), apply_rope(k, freqs)
+            if freqs is not None:
+                q, k = apply_rope(q, freqs), apply_rope(k, freqs)
         attn, cache = attend(q, k, v)
         return attn.reshape(b, t, -1), cache
 
@@ -132,16 +157,24 @@ def decoder_block(cfg, x: jax.Array, lw: Dict[str, Any], mix: Callable,
     (adapters_by_target, scale) with this layer's factors
     (``models.lora.lora_proj``), applied to wq/wk/wv/wo.
 
+    Both residual adds scale the branch by ``residual_multiplier`` where the
+    family states one other than 1 (Granite); elsewhere nothing is emitted.
+
     The named scopes are metadata on the ops, for a device trace to group
     time by; ``kt.cache_update`` and ``kt.attention`` sit inside ``attend``.
     """
     lw = dequant_layer(lw, cfg.dtype)
+    rm = getattr(cfg, "residual_multiplier", 1)
+
+    def residual(x, out):
+        return x + (out if rm == 1 else out * jnp.asarray(rm, out.dtype))
+
     with jax.named_scope("kt.qkv_rope"):
         h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
     attn, cache = mix(h, lw, lora)
     with jax.named_scope("kt.out_proj"):
         out = lora_proj(attn, lw["wo"], lora, "wo")
-        x = x + (reduce(out) if reduce else out)
+        x = residual(x, reduce(out) if reduce else out)
     with jax.named_scope("kt.ffn"):
         out, aux = ffn(rmsnorm(x, lw["ffn_norm"], cfg.norm_eps), lw)
-        return x + out, cache, aux
+        return residual(x, out), cache, aux

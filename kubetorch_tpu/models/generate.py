@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .block import decoder_block, dense_ffn, qkv_attend, rmsnorm
+from .block import (attention_scale, decoder_block, dense_ffn, qkv_attend,
+                    rmsnorm)
 from .llama import LlamaConfig, rope_freqs
 from .moe import MoeConfig, moe_ffn, moe_ffn_decode
 
@@ -143,7 +144,7 @@ def cache_attend(cfg, layer_cache_k, layer_cache_v, q_pos,
     :func:`_sp_prefill_impl` / the caller's ``flash_prefill`` say so;
     everything else attends the cache under the absolute-position mask.
     Returns ``attend(q, k, v) -> (attn, (cache_k, cache_v))``."""
-    scale = cfg.head_dim ** -0.5
+    scale = attention_scale(cfg)
 
     def attend(q, k, v):
         b, t = q.shape[:2]

@@ -73,7 +73,11 @@ def head_weight(params: Dict[str, Any], dtype=jnp.bfloat16):
     """The lm_head in compute dtype, whether stored quantized or not — the
     ONE definition of head handling shared by the scanned generate path,
     the engine's decode/prefill jits, and speculative decoding (a change
-    here cannot silently break their bit-identical contract)."""
+    here cannot silently break their bit-identical contract). A tree with
+    no ``lm_head`` ties the head to its embedding (``models.hybrid``): the
+    embedding, transposed."""
+    if "lm_head" not in params:
+        return params["embed"].astype(dtype).T
     leaf = params["lm_head"]
     if is_quantized(leaf):
         return dequant(leaf, dtype)
@@ -320,7 +324,7 @@ def lm_head_dot(x: jax.Array, params: Dict[str, Any], dtype) -> jax.Array:
     the scanned generate path, the engine's decode/prefill jits, and
     speculative decoding (an int4 head streams packed through the kernel
     instead of materializing ~2 GB of fp rows per step on a 13B)."""
-    leaf = params["lm_head"]
+    leaf = params.get("lm_head")
     if isinstance(leaf, dict) and Q4KEY in leaf:
         return wdot(x, leaf, dtype=jnp.float32)
     return (x @ head_weight(params, dtype)).astype(jnp.float32)

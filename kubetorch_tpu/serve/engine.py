@@ -57,14 +57,18 @@ import numpy as np
 from jax import lax
 
 from .. import telemetry
-from ..models.block import (decoder_block, layer_stacks, qkv_attend,
-                            rmsnorm, with_banks)
+from ..models.block import (attention_scale, decoder_block, embed,
+                            layer_stacks, qkv_attend, rmsnorm, scale_logits,
+                            with_banks)
 from ..models.generate import (KVCache, _layer_step, cache_attend, ffn_block,
                                init_cache, rope_freqs)
 from ..models.moe import moe_prefill_keep_capacity as _moe_keep_capacity
 from ..models.quant import lm_head_dot
 
 NEG_INF = -1e30
+# the TPU's vector lanes: the minor width a grid must have for the decode
+# kernel to read it where it lies (``grid_attend``)
+_LANES = 128
 
 # Decode-attention dispatch, frozen at import like generate's flash flag
 # (the gate runs at trace time inside jits whose cache key never sees env):
@@ -147,36 +151,62 @@ def init_grid_cache(cfg, slots: int, max_len: int) -> KVCache:
                    v=jnp.zeros(shape, cfg.dtype))
 
 
+def _run_rows(rows, stack, start: int, n: int) -> tuple:
+    """A prompt's rows that one run of layers scans over: every leaf's
+    layers ``start .. start + n``."""
+    del stack
+    return tuple(r[start:start + n] for r in rows)
+
+
+def _join_rows(cfg, done: list) -> tuple:
+    """The runs' rows, in order, as ``(k_new, v_new)`` for
+    ``_splice_slot`` (a one-leaf kind's second is None)."""
+    del cfg
+    rows = (done[0] if len(done) == 1
+            else tuple(jnp.concatenate(r, axis=0) for r in zip(*done)))
+    return (*rows, None)[:2]
+
+
 class _CacheOps(NamedTuple):
     """What a cache kind supplies to the engine's one prefill, one decode
     step and one cache manager: the grid, a prompt's row-major rows, and the
     block's mixing operation over each (``models.block.decoder_block``).
     Everything else — the in-place row writes, the splice, the sharding
     pin, the donated carry, the host loop — is the engine's, for every
-    kind."""
+    kind. A kind whose leaves are not all stacked over every layer says how
+    a run of layers takes its part of a prompt's rows and how the runs'
+    parts join (``serve.state_cache``)."""
     layout: str            # part of ``aot_cache.AOTKey``
     init_grid: Callable    # (cfg, slots, max_len) -> grid
     init_rows: Callable    # (cfg, batch, t) -> a prompt's rows, row-major
     rows_mix: Callable     # (cfg, layer_rows, q_pos, freqs_full, **kw)
     grid_mix: Callable     # (cfg, grid, layer, pos, freqs, live)
+    run_rows: Callable = _run_rows     # (rows, stack, start, n) -> a run's
+    join_rows: Callable = _join_rows   # (cfg, [run's rows]) -> (k, v)
 
 
 _KV_OPS = _CacheOps(
     GRID_LAYOUT, init_grid_cache, init_cache,
-    lambda cfg, rows, q_pos, freqs_full, **kw: qkv_attend(
+    lambda cfg, rows, q_pos, freqs_full, true_len=None, **kw: qkv_attend(
         cfg, freqs_full[q_pos], cache_attend(cfg, *rows, q_pos, **kw)),
     lambda cfg, grid, layer, pos, freqs, live: qkv_attend(
         cfg, freqs, grid_attend(cfg, grid, layer, pos)))
 
 
 def _cache_ops(cfg) -> _CacheOps:
-    """Per-head K/V rows unless the config names another cache kind
-    (``models.mla.MlaMoeConfig.cache_kind``: latent rows, whose module is
-    imported here and nowhere earlier)."""
-    if getattr(cfg, "cache_kind", "kv") == "latent":
+    """Per-head K/V rows unless the config names another cache kind, whose
+    module is imported here and nowhere earlier: latent rows
+    (``models.mla.MlaMoeConfig.cache_kind``) or a hybrid's recurrent state
+    beside K/V rows (``models.hybrid.HybridConfig``)."""
+    kind = getattr(cfg, "cache_kind", "kv")
+    if kind == "latent":
         from . import latent_cache as lc
         return _CacheOps(lc.grid_layout(cfg), lc.init_grid, lc.init_rows,
                          lc.rows_mix, lc.grid_mix)
+    if kind == "hybrid":
+        from . import state_cache as sc
+        return _CacheOps(sc.GRID_LAYOUT, sc.init_grid, sc.init_rows,
+                         sc.rows_mix, sc.grid_mix, sc.run_rows, sc.join_rows)
     return _KV_OPS
 
 
@@ -238,10 +268,10 @@ def grid_attend(cfg, cache, layer, pos):
     k, v (B, 1, NKV, Hd)."""
     from .kv_quant import QuantKVCache, grid_rows
     quant = isinstance(cache, QuantKVCache)
-    scale = cfg.head_dim ** -0.5
+    scale = attention_scale(cfg)
 
     def attend(q, k, v):
-        b, nh, nkv = q.shape[0], q.shape[2], k.shape[2]
+        b, nh, nkv, hd = q.shape[0], q.shape[2], k.shape[2], q.shape[3]
         q1 = q[:, 0]
         with jax.named_scope("kt.cache_update"):
             grid = type(cache)(*(
@@ -274,11 +304,15 @@ def grid_attend(cfg, cache, layer, pos):
                 sp = (ring.sp_decode_attention_quant_sharded if quant
                       else ring.sp_decode_attention_sharded)
                 attn = sp(q1, *layer_leaves(), pos, mesh, scale=scale)
-            elif _decode_kernel_wanted():
+            elif _decode_kernel_wanted() and hd % _LANES == 0:
                 # fused flash-decode over the stacked grid itself: streams
                 # K/V tiles, skips tiles past each slot's frontier entirely
                 # (ops/decode_attention.py); under a mesh each device runs
-                # it over its own slots and heads
+                # it over its own slots and heads. A head narrower than the
+                # lanes (Granite's 64) takes the einsums below: XLA:TPU
+                # lays such a grid out with its rows minor, and the kernel
+                # wants its heads minor, so every block would relay the
+                # whole grid in and out
                 kernel = (kernel_shard.decode_attention_quant_sharded if quant
                           else kernel_shard.decode_attention_sharded)
                 attn = kernel(q1, *grid, pos, layer, mesh, scale=scale)
@@ -353,7 +387,7 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
     ``live`` is."""
     s_max = cache[0].shape[3]
     ops = _cache_ops(cfg)
-    x = params["embed"][toks[:, None]].astype(cfg.dtype)   # (B, 1, D)
+    x = embed(params, toks[:, None], cfg)                   # (B, 1, D)
     freqs = rope_freqs(cfg, s_max)[pos][:, None]            # (B, 1, Hd/2)
 
     from ..models.lora import gather_slot_adapters
@@ -392,9 +426,12 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
         tallied = {} if routed is None else {"moe": routed}
         if selects:
             tallied["dsa"] = jnp.concatenate(seen, axis=0)
+        if "ssm_state_slot_steps" in _tally_shapes(cfg):
+            tallied["ssm_state_slot_steps"] = jnp.sum(live != 0,
+                                                      dtype=jnp.int32)
     x, new_cache = carry
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_head_dot(x[:, 0], params, cfg.dtype)
+    logits = scale_logits(lm_head_dot(x[:, 0], params, cfg.dtype), cfg)
     raw_logits = logits
     if counts is not None:
         # OpenAI-style repetition control: subtract per-token penalties
@@ -419,25 +456,31 @@ def _tally_shapes(cfg) -> Dict[str, tuple]:
     """What a decode step tallies on the device for this config, by name:
     ``moe``, the expert layers' routing tally (L_moe, 2, E) — the routed
     (token, choice) pairs of the live slots an expert got, and the steps in
-    which it got any — and ``dsa``, (L, 2): the rows a sparse-attention
-    layer scored and selected for the live slots. A family says which it
-    keeps (``routed_tally_shape`` / ``dsa_tally_shape``)."""
+    which it got any —, ``dsa``, (L, 2): the rows a sparse-attention
+    layer scored and selected for the live slots, and
+    ``ssm_state_slot_steps``, (): the live slots whose recurrent state a
+    step advanced, summed over steps. A family says which it keeps
+    (``routed_tally_shape`` / ``dsa_tally_shape`` / ``ssm_tally_shape``)."""
     shapes = {"moe": getattr(cfg, "routed_tally_shape", None),
-              "dsa": getattr(cfg, "dsa_tally_shape", None)}
+              "dsa": getattr(cfg, "dsa_tally_shape", None),
+              "ssm_state_slot_steps": getattr(cfg, "ssm_tally_shape", None)}
     return {k: v for k, v in shapes.items() if v is not None}
 
 
 # ``dsa`` counts rows, 100,000 a step and layer at 16 slots of 8k context:
-# an int32 would wrap within minutes, so the running sum is kept as two
-# int32 words, [..., 0] counting 2**_DSA_WORD of [..., 1]'s units (a step
-# adds at most SLOTS x S_max to the low word before it carries)
+# an int32 would wrap within minutes, and ``ssm_state_slot_steps`` within
+# days, so their running sums are kept as two int32 words, [..., 0]
+# counting 2**_DSA_WORD of [..., 1]'s units (a step adds at most
+# SLOTS x S_max to the low word before it carries)
 _DSA_WORD = 24
+_TWO_WORDS = ("dsa", "ssm_state_slot_steps")
 
 
 def _init_tally(cfg) -> Optional[Dict[str, Any]]:
     shapes = _tally_shapes(cfg)
-    if "dsa" in shapes:
-        shapes["dsa"] += (2,)
+    for k in _TWO_WORDS:
+        if k in shapes:
+            shapes[k] += (2,)
     return {k: jnp.zeros(v, jnp.int32) for k, v in shapes.items()} or None
 
 
@@ -446,11 +489,17 @@ def _tally_add(tally, step):
     out = dict(tally)
     if "moe" in tally:
         out["moe"] = tally["moe"] + step["moe"]
-    if "dsa" in tally:
-        low = tally["dsa"][..., 1] + step["dsa"]
-        out["dsa"] = jnp.stack([tally["dsa"][..., 0] + (low >> _DSA_WORD),
+    for k in _TWO_WORDS:
+        if k in tally:
+            low = tally[k][..., 1] + step[k]
+            out[k] = jnp.stack([tally[k][..., 0] + (low >> _DSA_WORD),
                                 low & ((1 << _DSA_WORD) - 1)], axis=-1)
     return out
+
+
+def _from_words(words):
+    """A two-word running sum (``_TWO_WORDS``), fetched as int64."""
+    return (words[..., 0] << _DSA_WORD) + words[..., 1]
 
 
 @partial(jax.jit, static_argnames=("cfg", "top_k", "lora_scale"),
@@ -547,18 +596,20 @@ def _prefill(params, tokens, true_len, rng, temps, cfg,
     """Prompt pass at one bucket length. tokens (1, T_bucket) right-padded;
     logits are taken at the REAL last position ``true_len - 1`` (padding
     rows only pollute their own cache rows, which decode overwrites before
-    ever attending to them). Returns (first_token (1,), k, v, logprobs)
-    with k/v the prompt's rows, row-major (L, 1, T_bucket, NKV, Hd); a
-    latent cache's rows come as ``k`` (L, 1, T_bucket, 1, C) and ``v`` is
-    None."""
+    ever attending to them; a recurrence would carry them on, so its scan
+    stops at ``true_len``: ``serve.state_cache``). Returns (first_token
+    (1,), k, v, logprobs) with k/v the prompt's rows, row-major
+    (L, 1, T_bucket, NKV, Hd); a latent cache's rows come as ``k``
+    (L, 1, T_bucket, 1, C) and ``v`` is None, a hybrid's rows and state as
+    one ``HybridCache`` in ``k``."""
     b, t = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = embed(params, tokens, cfg)
     freqs_full = rope_freqs(cfg, t)
     q_pos = jnp.arange(t)
     from ..models.generate import _flash_prefill_wanted
     flash = _flash_prefill_wanted(cfg, t)
     ops = _cache_ops(cfg)
-    rows = tuple(ops.init_rows(cfg, b, t))
+    rows = ops.init_rows(cfg, b, t)
     # Padding must not perturb MoE routing: masked tokens never claim a
     # capacity slot, and the overflow-drop threshold is the REAL length's
     # capacity (the static buffer stays bucket-sized) — so a bucketed
@@ -575,7 +626,7 @@ def _prefill(params, tokens, true_len, rng, temps, cfg,
         h, rows_l, _ = decoder_block(
             cfg, carry, lw,
             ops.rows_mix(cfg, rows_l, q_pos, freqs_full, flash_prefill=flash,
-                         causal_prefill=True),
+                         causal_prefill=True, true_len=true_len),
             with_banks(ffn, whole, l), lora=lora)
         return h, tuple(rows_l)
 
@@ -583,15 +634,13 @@ def _prefill(params, tokens, true_len, rng, temps, cfg,
     done = []
     for stack, whole, start, n in layer_stacks(cfg, params):
         x, out = lax.scan(partial(body, whole), x, (
-            stack, jnp.arange(n), tuple(r[start:start + n] for r in rows),
+            stack, jnp.arange(n), ops.run_rows(rows, stack, start, n),
             adapter or {}))
         done.append(out)
-    rows = (done[0] if len(done) == 1
-            else tuple(jnp.concatenate(r, axis=0) for r in zip(*done)))
-    nk, nv = (*rows, None)[:2]
+    nk, nv = ops.join_rows(cfg, done)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     h_last = x[jnp.arange(b), true_len - 1]                 # (1, D)
-    logits = lm_head_dot(h_last, params, cfg.dtype)
+    logits = scale_logits(lm_head_dot(h_last, params, cfg.dtype), cfg)
     raw_logits = logits
     if pen_row is not None:
         logits = logits - pen_row[None, :]
@@ -725,13 +774,17 @@ def _splice_slot(cache, slot, k_new, v_new):
     to the grid's head-major (L, 1, NKV, T_b, Hd) as they are written (the
     ≤ bucket-width new rows, not the grid). For an int8 ``QuantKVCache``
     grid the rows quantize HERE — prefill itself always runs full-precision
-    math."""
+    math. A cache kind with leaves that have no rows (its type's
+    ``state_leaves``: ``serve.state_cache.HybridCache``) comes whole in
+    ``k_new``; each such leaf replaces the slot's as it is."""
     from .kv_quant import grid_rows
+    state = getattr(cache, "state_leaves", ())
+    new = (k_new if state else grid_rows(cache, k_new, v_new))
     return _constrain_cache(type(cache)(*(
         lax.dynamic_update_slice(
-            g, jnp.swapaxes(r, 2, 3).astype(g.dtype),
+            g, (r if name in state else jnp.swapaxes(r, 2, 3)).astype(g.dtype),
             (0, slot) + (0,) * (g.ndim - 2))
-        for g, r in zip(cache, grid_rows(cache, k_new, v_new)))))
+        for name, g, r in zip(cache._fields, cache, new))))
 
 
 # ---------------------------------------------------------------------------
@@ -925,6 +978,10 @@ class EngineStats:
     # then attended to. Accumulated and fetched like the routing tally
     dsa_rows_scored: Any = None
     dsa_rows_selected: Any = None
+    # layers with recurrent state (None elsewhere): the live slots whose
+    # state the decode steps advanced, summed over steps. Accumulated and
+    # fetched like the routing tally
+    ssm_state_slot_steps: Any = None
 
 
 class GenerationEngine:
@@ -2306,8 +2363,11 @@ class GenerationEngine:
             out.update(moe_routed_pairs=host["moe"][:, 0],
                        moe_expert_hits=host["moe"][:, 1])
         if "dsa" in host:
-            rows = (host["dsa"][..., 0] << _DSA_WORD) + host["dsa"][..., 1]
+            rows = _from_words(host["dsa"])
             out.update(dsa_rows_scored=rows[:, 0], dsa_rows_selected=rows[:, 1])
+        if "ssm_state_slot_steps" in host:
+            out["ssm_state_slot_steps"] = int(
+                _from_words(host["ssm_state_slot_steps"]))
         return out
 
     def stats(self) -> EngineStats:
@@ -2362,6 +2422,9 @@ class GenerationEngine:
                 s.dsa_rows_scored.sum())
             out["engine_dsa_rows_selected_total"] = float(
                 s.dsa_rows_selected.sum())
+        if s.ssm_state_slot_steps is not None:
+            out["engine_ssm_state_slot_steps_total"] = float(
+                s.ssm_state_slot_steps)
         spec = getattr(self, "spec_stats", None)
         if spec is not None:
             out["engine_spec_rounds"] = float(spec.rounds)

@@ -92,6 +92,11 @@ class EngineSessionBinder:
                  advance: bool = False, min_prefix_tokens: int = 8):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
+        # a session's snapshot is a registered prefix: a cache kind without
+        # the prefix store refuses it here, not on the first turn
+        refuse = getattr(engine, "_refuse", None)
+        if refuse is not None:
+            refuse("session snapshots (EngineSessionBinder)")
         self.engine = engine
         self.capacity = int(capacity)
         self.advance = bool(advance)

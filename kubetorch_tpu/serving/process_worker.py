@@ -37,7 +37,10 @@ from ..exceptions import detect_hbm_oom, package_exception
 from ..resources.pointers import Pointers, import_callable
 from .env_contract import RankInfo, framework_for
 
-_SYNC_EXECUTOR_THREADS = 40  # matches the server's sync-callable concurrency
+# sync calls of the target in flight at once: a served engine is called by
+# as many callers as it has slots (64 for a hybrid model's state grid), each
+# blocking a thread until its reply. Threads start only as calls arrive.
+_SYNC_EXECUTOR_THREADS = 128
 
 
 # The HTTP X-Request-ID travels server → worker in the request item and is

@@ -106,12 +106,12 @@ def test_configuration_carries_the_published_widths(full):
                               "prefill_buckets": [8192], "decode_block": 8}
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["configs"][-1]
+    entry = next(c for c in bench["configs"] if c["name"] == full["name"])
     assert entry["name"] == full["name"] == "glm-5-ep16-l6"
     assert set(entry["reduced"]) == set(REDUCED)
     assert entry["file"] == "benchmark/configs/glm-5-ep16-l6.json"
     assert entry["source"] == full["source"]
-    cell = bench["workloads"][-1]
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, full["name"], "longdoc-closed", 1)
     assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
@@ -131,8 +131,9 @@ def test_configuration_carries_the_published_widths(full):
         "mla_prefill_attn_roofline"}
     assert {moved[name] for name in listed - {"ttft_p50_ms"}} == {
         "ttft_p50_ms", "setup_s"}
-    # PR 39's kernel share: this cell alone, the last of the list
-    assert bench["per_layer"][-1] == {
+    # the latent-attention prefill kernel's share: this cell alone
+    assert next(m for m in bench["per_layer"]
+                if m["name"] == "mla_prefill_attn_roofline") == {
         "name": "mla_prefill_attn_roofline", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels", "moves": "ttft_p50_ms",
         "workloads": [CELL]}

@@ -413,6 +413,43 @@ def test_the_prefill_kernel_compiles_at_glm5s_widths(chip_programs):
     assert 'custom_call_target="tpu_custom_call"' in calls[0]
 
 
+
+def test_hybrid_decode_block_updates_its_state_in_place(chip_programs):
+    """The hybrid configuration (``granite-4.0-h-micro``: 36 Mamba-2 layers,
+    4 attention layers of heads of 64) at the cell's widths and this file's
+    16 slots: the only instructions that make an array the size of the state
+    grid are in-place updates of it (``dynamic-update-slice`` or a fusion
+    rooted in one), nothing copies or transposes the state grid or a layer
+    of it, all four cache leaves are donated, and the attention layers
+    decode through the einsums (no ``kt_decode_attention``: heads narrower
+    than the lanes, ``engine.grid_attend``)."""
+    from kubetorch_tpu.models.hybrid import HybridConfig, hybrid_init
+    cfg = HybridConfig()
+    text = chip_programs.decode(cfg, hybrid_init)
+    state = (cfg.n_mamba, SLOTS, cfg.mamba_n_heads, cfg.mamba_d_head,
+             cfg.mamba_d_state)
+    grid, layer = sorted(state), sorted(state[1:])
+    passing = ("parameter", "get-tuple-element", "bitcast",
+               "dynamic-update-slice")
+    made = [f"{op} {n}" for n, dims, op, _ in _instructions(text)
+            if dims == grid and op not in passing
+            and not (op == "fusion" and "dynamic-update-slice" in n)]
+    assert made == []
+    assert [f"{op} {n}" for n, dims, op, _ in _instructions(text)
+            if dims in (grid, layer)
+            and op in ("copy", "copy-start", "transpose")] == []
+    assert any(dims == grid and ("dynamic-update-slice" in n
+                                 or op == "dynamic-update-slice")
+               for n, dims, op, _ in _instructions(text))
+    assert _aliased_cache_params(text, 4)
+    assert "kt_decode_attention" not in text
+    # the state as the configuration states it: float32 in the grid and in
+    # every update of it
+    kinds = {m.group(2) for m in map(_INSTR.match, text.splitlines())
+             if m and sorted(int(d) for d in m.group(3).split(",")
+                             if d and d != "1") in (grid, layer)}
+    assert kinds == {"f32"}
+
 # -- the census of the older cells' programs -----------------------------------
 
 CENSUS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),

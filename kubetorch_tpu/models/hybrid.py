@@ -41,7 +41,8 @@ whole, never sliced out of a longer stack.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from functools import partial
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -348,12 +349,29 @@ def mamba_rows_mix(cfg: HybridConfig, true_len=None):
     return mix
 
 
+def ssm_update(ssm: jax.Array, decay: jax.Array, dtx: jax.Array,
+               b: jax.Array, c: jax.Array):
+    """The recurrence's step on the slots' states ssm (B, H, P, N): decay
+    (B, H) = exp(dt·A), dtx (B, H, P) = dt·x, b and c (B, N). Returns (y
+    (B, H, P) float32 without the skip term, the new state in ssm's
+    dtype)."""
+    new = (decay[:, :, None, None] * ssm.astype(jnp.float32)
+           + dtx[..., None] * b[:, None, None, :])
+    return jnp.sum(new * c[:, None, None, :], axis=-1), new.astype(ssm.dtype)
+
+
 def mamba_step(cfg: HybridConfig, h: jax.Array, lw: Dict[str, Any],
-               conv: jax.Array, ssm: jax.Array):
+               conv: jax.Array, ssm: Optional[jax.Array] = None, *,
+               state_update: Optional[Callable] = None):
     """One token a slot through a Mamba-2 layer. h (B, D); conv
     (B, d_conv - 1, conv_dim) the slot's last inputs of the convolution; ssm
-    (B, H, P, N) its state. Returns y (B, d_inner) in the model dtype, the
-    window shifted by the new input, and the new state."""
+    (B, H, P, N) its state, advanced by :func:`ssm_update`; or, for a state
+    held elsewhere (the engine's grid, in place), ``state_update(decay, dtx,
+    b, c) -> (y, state)`` with :func:`ssm_update`'s operands and y. Returns
+    y (B, d_inner) in the model dtype, the window shifted by the new input,
+    and the new state (what ``state_update`` returned for it)."""
+    if state_update is None:
+        state_update = partial(ssm_update, ssm)
     with jax.named_scope("kt.ssm.in_proj"):
         z, xbc, dt_raw = _split_in_proj(cfg, h @ lw["in_proj"])
     with jax.named_scope("kt.ssm.conv"):
@@ -366,11 +384,9 @@ def mamba_step(cfg: HybridConfig, h: jax.Array, lw: Dict[str, Any],
     dt = _dt(dt_raw, lw)                                    # (B, H)
     with jax.named_scope("kt.ssm.state_update"):
         decay = jnp.exp(dt * -jnp.exp(lw["A_log"]))
-        new = (decay[:, :, None, None] * ssm.astype(jnp.float32)
-               + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
-        y = jnp.sum(new * c[:, None, None, :], axis=-1) \
-            + lw["D"][:, None] * x
-    return _gate_norm(cfg, y, z, lw), window[:, 1:], new.astype(ssm.dtype)
+        y, state = state_update(decay, dt[:, :, None] * x, b, c)
+        y = y + lw["D"][:, None] * x
+    return _gate_norm(cfg, y, z, lw), window[:, 1:], state
 
 
 # ---------------------------------------------------------------------------
@@ -415,5 +431,5 @@ def hybrid_forward(params: Dict[str, Any], tokens: jax.Array,
 
 
 __all__ = ["HybridConfig", "hybrid_init", "hybrid_forward", "ssd_scan",
-           "mamba_rows_mix", "mamba_step", "attention_rows_mix", "is_mamba",
-           "GRANITE_4_H_MICRO_LAYERS"]
+           "mamba_rows_mix", "mamba_step", "ssm_update", "attention_rows_mix",
+           "is_mamba", "GRANITE_4_H_MICRO_LAYERS"]

@@ -31,7 +31,8 @@ from jax import lax
 from ..models.block import qkv_attend
 from ..models.generate import KVCache
 from ..models.hybrid import (SSM_DTYPE, attention_rows_mix, is_mamba,
-                             mamba_rows_mix, mamba_step)
+                             mamba_rows_mix, mamba_step, ssm_update)
+from ..ops.ssm_state import ssm_state_step, ssm_state_step_auto
 
 # part of ``aot_cache.AOTKey``, as ``engine.GRID_LAYOUT`` is for K/V grids
 GRID_LAYOUT = ("hybrid:kv:attention_layer,slot,kv_head,row,head_dim;"
@@ -116,11 +117,21 @@ def rows_mix(cfg, layer_rows, q_pos, freqs_full, true_len=None,
 def grid_mix(cfg, cache: HybridCache, layer, pos, freqs=None, live=None):
     """The block's mixing operation over the slot grid, one token a slot:
     a Mamba-2 layer shifts each slot's window and advances its state at
-    ``[layer]`` of the two state leaves, in place; an attention layer
-    attends over layer ``layer`` of the K/V grid (``engine.grid_attend``).
-    ``freqs`` and ``live``: nothing here reads them."""
+    ``[layer]`` of the two state leaves, in place (the state through
+    ``kt_ssm_state_step`` on the TPU backend, which reads it once for the
+    update and ``y``: ``ops.ssm_state``); an attention layer attends over
+    layer ``layer`` of the K/V grid (``engine.grid_attend``). ``freqs`` and
+    ``live``: nothing here reads them."""
     from .engine import grid_attend
     del freqs, live
+
+    def state_update(decay, dtx, b, c):
+        """(y, the whole ssm leaf with layer ``layer`` advanced)."""
+        if ssm_state_step_auto(*cache.ssm.shape[2:]):
+            return ssm_state_step(cache.ssm, layer, decay, dtx, b, c)
+        ssm = lax.dynamic_index_in_dim(cache.ssm, layer, 0, keepdims=False)
+        y, ssm = ssm_update(ssm, decay, dtx, b, c)
+        return y, lax.dynamic_update_index_in_dim(cache.ssm, ssm, layer, 0)
 
     def mix(h, lw, lora):
         if not is_mamba(lw):
@@ -128,13 +139,13 @@ def grid_mix(cfg, cache: HybridCache, layer, pos, freqs=None, live=None):
                 cfg, KVCache(cache.k, cache.v), layer, pos))(h, lw, lora)
             return attn, cache._replace(k=kv.k, v=kv.v)
         conv = lax.dynamic_index_in_dim(cache.conv, layer, 0, keepdims=False)
-        ssm = lax.dynamic_index_in_dim(cache.ssm, layer, 0, keepdims=False)
-        y, conv, ssm = mamba_step(cfg, h[:, 0], lw, conv, ssm)
+        y, conv, ssm = mamba_step(cfg, h[:, 0], lw, conv,
+                                  state_update=state_update)
         with jax.named_scope("kt.cache_update"):
             cache_out = cache._replace(
                 conv=lax.dynamic_update_index_in_dim(cache.conv, conv, layer,
                                                      0),
-                ssm=lax.dynamic_update_index_in_dim(cache.ssm, ssm, layer, 0))
+                ssm=ssm)
         return y[:, None], cache_out
 
     return mix

@@ -30,6 +30,12 @@ And the latent-attention prefill kernel (ISSUE 39): Kimi-VL's bucket-1,024
 prefill holds no call of it (its score array is one array), and the kernel
 at GLM-5's widths compiles.
 
+And the hybrid configuration (``granite-4.0-h-micro``): its decode block
+advances the Mamba-2 state only through ``kt_ssm_state_step`` calls that
+alias the whole state grid to their output, and nothing else reads, copies
+or remakes the grid or a layer of it; the kernel alone compiles at the
+cell's 64 slots.
+
 The topology is described inside a fixture, and skipped from there where it
 cannot be: nothing touches the TPU library while a module is imported.
 """
@@ -164,8 +170,9 @@ def steered_onto_the_chip(one_chip):
     of the program: the kernel paths, compiled by Mosaic."""
     from kubetorch_tpu.models import generate, mla
     from kubetorch_tpu.ops import (attention, decode_attention,
-                                   mla_prefill_attention, moe_experts)
-    from kubetorch_tpu.serve import engine as E
+                                   mla_prefill_attention, moe_experts,
+                                   ssm_state)
+    from kubetorch_tpu.serve import engine as E, state_cache
     # an entry compiled for a described chip cannot be read back without one
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -173,9 +180,11 @@ def steered_onto_the_chip(one_chip):
         mp.setattr(E, "_decode_kernel_wanted", lambda: True)
         mp.setattr(mla, "moe_experts_auto", moe_experts.moe_experts_supported)
         mp.setattr(mla, "flash_prefill_auto", mla.flash_prefill_supported)
+        mp.setattr(state_cache, "ssm_state_step_auto",
+                   ssm_state.ssm_state_supported)
         mp.setattr(generate, "_FLASH_PREFILL_FLAG", "1")
         for kernel_mod in (attention, decode_attention, mla_prefill_attention,
-                           moe_experts):
+                           moe_experts, ssm_state):
             mp.setattr(kernel_mod, "interpret_default", lambda: False)
         yield _ChipPrograms(one_chip)
     jax.config.update("jax_enable_compilation_cache", cached)
@@ -415,40 +424,94 @@ def test_the_prefill_kernel_compiles_at_glm5s_widths(chip_programs):
 
 
 def test_hybrid_decode_block_updates_its_state_in_place(chip_programs):
-    """The hybrid configuration (``granite-4.0-h-micro``: 36 Mamba-2 layers,
-    4 attention layers of heads of 64) at the cell's widths and this file's
-    16 slots: the only instructions that make an array the size of the state
-    grid are in-place updates of it (``dynamic-update-slice`` or a fusion
-    rooted in one), nothing copies or transposes the state grid or a layer
-    of it, all four cache leaves are donated, and the attention layers
-    decode through the einsums (no ``kt_decode_attention``: heads narrower
-    than the lanes, ``engine.grid_attend``)."""
+    """The hybrid configuration (``granite-4.0-h-micro``: 36 Mamba-2 layers
+    in five runs, 4 attention layers of heads of 64) at the cell's widths
+    and this file's 16 slots: the state grid is advanced only by
+    ``kt_ssm_state_step`` (``ops/ssm_state.py``), Mosaic's, one call for
+    each scan of Mamba-2 layers, each taking the whole grid and aliasing it
+    to its output; nothing but those calls reads the state grid or a layer
+    of it (XLA's form read the new state a second time for ``y = S·C``, a
+    ``f32[SLOTS,64,64]`` fusion over the updated grid), nothing but a
+    parameter or a passed-on tuple element has its shape or a layer's (no
+    slice, copy, transpose or second buffer), all four cache leaves are
+    donated, and the attention layers decode through the einsums (no
+    ``kt_decode_attention``: heads narrower than the lanes,
+    ``engine.grid_attend``)."""
     from kubetorch_tpu.models.hybrid import HybridConfig, hybrid_init
     cfg = HybridConfig()
     text = chip_programs.decode(cfg, hybrid_init)
     state = (cfg.n_mamba, SLOTS, cfg.mamba_n_heads, cfg.mamba_d_head,
              cfg.mamba_d_state)
     grid, layer = sorted(state), sorted(state[1:])
-    passing = ("parameter", "get-tuple-element", "bitcast",
-               "dynamic-update-slice")
+    by_name = {name: (dims, op) for name, dims, op, _ in _instructions(text)}
+
+    calls = [(name, rest) for name, op, rest in _any_instructions(text)
+             if op == "custom-call" and name.startswith("kt_ssm_state_step")]
+    runs = sum(kind == "mamba" for kind, _, _ in cfg.runs)
+    assert runs == 5 and len(calls) == runs, [c[0] for c in calls]
+    for name, rest in calls:
+        assert 'custom_call_target="tpu_custom_call"' in rest
+        assert "output_to_operand_aliasing={{1}: (1, {})}" in rest, name
+        operand = _operands(rest)[1]
+        assert by_name[operand] == (grid, "get-tuple-element"), (name,
+                                                                  operand)
+
+    readers = [f"{op} {n}" for n, op, rest in _any_instructions(text)
+               if any(by_name.get(o, ([], ""))[0] in (grid, layer)
+                      for o in _operands(rest))
+               and op != "tuple" and not n.startswith("kt_ssm_state_step")]
+    assert readers == []
     made = [f"{op} {n}" for n, dims, op, _ in _instructions(text)
-            if dims == grid and op not in passing
-            and not (op == "fusion" and "dynamic-update-slice" in n)]
-    assert made == []
-    assert [f"{op} {n}" for n, dims, op, _ in _instructions(text)
             if dims in (grid, layer)
-            and op in ("copy", "copy-start", "transpose")] == []
-    assert any(dims == grid and ("dynamic-update-slice" in n
-                                 or op == "dynamic-update-slice")
-               for n, dims, op, _ in _instructions(text))
+            and op not in ("parameter", "get-tuple-element")]
+    assert made == []
     assert _aliased_cache_params(text, 4)
     assert "kt_decode_attention" not in text
     # the state as the configuration states it: float32 in the grid and in
-    # every update of it
+    # every instruction of a grid's or a layer's shape
     kinds = {m.group(2) for m in map(_INSTR.match, text.splitlines())
              if m and sorted(int(d) for d in m.group(3).split(",")
                              if d and d != "1") in (grid, layer)}
     assert kinds == {"f32"}
+
+
+_ANY_NAMED = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                        r"(?:\(.*?\)|\w+\[[\d,]*\])\S* ([\w\-]+)\((.*)")
+
+
+def _any_instructions(text):
+    """(name, opcode, rest of line) of every instruction, a tuple result's
+    too (a kernel with two outputs, a ``while``)."""
+    for line in text.splitlines():
+        m = _ANY_NAMED.match(line)
+        if m:
+            yield m.groups()
+
+
+def _operands(rest):
+    """The operand names of an instruction, from the rest of its line."""
+    return re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+
+
+def test_the_state_kernel_compiles_at_granites_widths(chip_programs):
+    """The kernel alone over the cell's whole state leaf, 36 layers × 64
+    slots of 64 heads × 64 × 128 in float32 (4.8 GB), a traced layer,
+    compiled by Mosaic for the described chip: what interpret mode cannot
+    refuse (block shapes, the VMEM it asks for), the grid aliased to the
+    output, and no temporary the size of a slot's layer."""
+    from kubetorch_tpu.ops.ssm_state import ssm_state_step
+    arg, f32 = chip_programs.arg, jnp.float32
+    slots, h, p, n = 64, 64, 64, 128
+    compiled = jax.jit(ssm_state_step, donate_argnums=0).lower(
+        arg((36, slots, h, p, n), f32), arg((), jnp.int32),
+        arg((slots, h), f32), arg((slots, h, p), f32), arg((slots, n), f32),
+        arg((slots, n), f32)).compile()
+    calls = [rest for name, op, rest in _any_instructions(compiled.as_text())
+             if op == "custom-call" and name.startswith("kt_ssm_state_step")]
+    assert len(calls) == 1
+    assert 'custom_call_target="tpu_custom_call"' in calls[0]
+    assert "output_to_operand_aliasing={{1}: (1, {})}" in calls[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < h * p * n * 4
 
 # -- the census of the older cells' programs -----------------------------------
 

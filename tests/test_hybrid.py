@@ -301,7 +301,8 @@ def test_config_refuses_what_it_would_have_to_guess(field, value):
 
 def test_a_dense_engine_imports_nothing_of_the_family():
     """A Mistral-shaped rank imports the serving package and builds its
-    engine; neither the family's model nor its cache module is loaded."""
+    engine; neither the family's model, nor its cache module, nor its state
+    kernel is loaded."""
     code = (
         "import sys, jax\n"
         "from kubetorch_tpu.serve import GenerationEngine\n"
@@ -310,7 +311,7 @@ def test_a_dense_engine_imports_nothing_of_the_family():
         "GenerationEngine(llama_init(jax.random.PRNGKey(0), cfg), cfg,\n"
         "                 slots=2, max_len=32, prefill_buckets=(8,))\n"
         "print(sorted(m for m in sys.modules if m.endswith(\n"
-        "    ('models.hybrid', 'serve.state_cache'))))\n")
+        "    ('models.hybrid', 'serve.state_cache', 'ops.ssm_state'))))\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True,
                          capture_output=True, timeout=300,
                          env={**os.environ, "JAX_PLATFORMS": "cpu"})

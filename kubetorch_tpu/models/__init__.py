@@ -1,6 +1,7 @@
 """Model families: Llama (flagship), Mixtral-style MoE, a latent-attention
 (MLA) decoder with fine-grained experts, a hybrid of Mamba-2 and attention
-layers, ViT, ResNet, MLP.
+layers, a decoder of window and full attention layers with fine-grained
+experts, ViT, ResNet, MLP.
 
 The reference ships no models (it is a dispatch fabric; models live in user
 code). This framework makes the headline workloads (BASELINE.md configs 1-5)
@@ -30,6 +31,19 @@ convolution window and float32 state for the Mamba-2 layers). It refuses, by
 the same error, int8 K/V, chunked prefill, the prefix store, session
 snapshots (``serve.EngineSessionBinder``), LoRA, an AOT cache, a mesh,
 speculative decoding and the scanned ``generate``.
+
+``models.afmoe`` (imported where it is used, likewise) is Trinity-Mini
+(https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json):
+``AfmoeConfig``, ``afmoe_init``, ``afmoe_forward``; grouped-query layers
+that attend a window of ``sliding_window`` keys (RoPE) beside layers that
+attend every earlier key (no position embedding), per-head q/k norms, a
+sigmoid gate on the attention's output, norms after both branches, and the
+expert layer it shares with ``models.mla`` (``models.experts``). Served by
+``serve.GenerationEngine`` over a cache of kind ``"window"``
+(``serve.window_cache``: a full layer's K/V rows for every position, a window
+layer's as a ring of its last W). It refuses, by the same error, int8 K/V,
+chunked prefill, the prefix store, session snapshots, LoRA, an AOT cache, a
+mesh, speculative decoding and the scanned ``generate``.
 """
 
 from .llama import LlamaConfig, llama_init, llama_forward, llama_loss

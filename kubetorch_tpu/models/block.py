@@ -159,6 +159,9 @@ def decoder_block(cfg, x: jax.Array, lw: Dict[str, Any], mix: Callable,
 
     Both residual adds scale the branch by ``residual_multiplier`` where the
     family states one other than 1 (Granite); elsewhere nothing is emitted.
+    A layer with ``post_attn_norm`` / ``post_ffn_norm`` weights RMS-norms
+    that branch's output before its add (``models.afmoe``); a layer
+    without them emits nothing more.
 
     The named scopes are metadata on the ops, for a device trace to group
     time by; ``kt.cache_update`` and ``kt.attention`` sit inside ``attend``.
@@ -169,12 +172,17 @@ def decoder_block(cfg, x: jax.Array, lw: Dict[str, Any], mix: Callable,
     def residual(x, out):
         return x + (out if rm == 1 else out * jnp.asarray(rm, out.dtype))
 
+    def post_norm(out, name):
+        return (rmsnorm(out, lw[name], cfg.norm_eps) if name in lw
+                else out)
+
     with jax.named_scope("kt.qkv_rope"):
         h = rmsnorm(x, lw["attn_norm"], cfg.norm_eps)
     attn, cache = mix(h, lw, lora)
     with jax.named_scope("kt.out_proj"):
         out = lora_proj(attn, lw["wo"], lora, "wo")
-        x = residual(x, reduce(out) if reduce else out)
+        out = post_norm(reduce(out) if reduce else out, "post_attn_norm")
+        x = residual(x, out)
     with jax.named_scope("kt.ffn"):
         out, aux = ffn(rmsnorm(x, lw["ffn_norm"], cfg.norm_eps), lw)
-        return residual(x, out), cache, aux
+        return residual(x, post_norm(out, "post_ffn_norm")), cache, aux

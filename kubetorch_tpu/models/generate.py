@@ -236,7 +236,7 @@ def ffn_block(cfg, h: jax.Array, lw: Dict[str, jax.Array],
     b, t = h.shape[0], h.shape[1]
     if "router_bias" in lw:
         # sigmoid bias-corrected routing, shared experts, no capacity
-        from .mla import moe_ffn_dropless
+        from .experts import moe_ffn_dropless
         return moe_ffn_dropless(cfg, h, lw, token_mask=token_mask,
                                 banks=banks)
     if "router" in lw:

@@ -13,7 +13,11 @@ broadcast in HBM.
 
 Causality is enforced at two levels: whole (q-block, k-block) tiles above the
 diagonal are skipped via ``pl.when`` (half the FLOPs), and the diagonal tile
-is masked elementwise.
+is masked elementwise. A ``window`` W (a sliding-window layer: query i sees
+keys i − W < j ≤ i) adds the band's lower edge at both levels: key blocks
+wholly below the band are skipped, and the forward's index maps hold such a
+step on a block it fetches anyway, so no DMA moves it. Without a window the
+kernels are what they were.
 """
 
 from __future__ import annotations
@@ -38,6 +42,34 @@ NEG_INF = -1e30
 LANES = 128
 
 
+def _block_live(qb, kj, block_q, block_k, causal, window):
+    """Does the (query block ``qb``, key block ``kj``) tile hold any pair the
+    masks leave? True where no mask says otherwise: above the diagonal
+    (``causal``) or wholly below the band (``window``) it does not."""
+    live = True
+    if causal:
+        # block above the diagonal ⇒ fully masked ⇒ skip
+        live = qb * block_q + block_q - 1 >= kj * block_k
+    if window is not None:
+        # every key of the block is at least ``window`` behind every query
+        below = kj * block_k + block_k - 1 > qb * block_q - window
+        live = below if live is True else jnp.logical_and(live, below)
+    return live
+
+
+def _mask(s, qb, kj, block_q, block_k, causal, window):
+    """The score tile with the pairs the masks drop at NEG_INF."""
+    if not causal and window is None:
+        return s
+    rows = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    if causal:
+        s = jnp.where(rows >= cols, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(cols > rows - window, s, NEG_INF)
+    return s
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
@@ -45,7 +77,7 @@ LANES = 128
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                 scale: float, causal: bool, block_q: int, block_k: int,
-                need_lse: bool):
+                need_lse: bool, window: Optional[int] = None):
     lse_ref, acc_ref, m_ref, l_ref = rest if need_lse else (None, *rest)
     qi = pl.program_id(2)   # q-block index
     kj = pl.program_id(3)   # k-block index (innermost, sequential)
@@ -57,22 +89,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    should_compute = True
-    if causal:
-        # block above the diagonal ⇒ fully masked ⇒ skip
-        should_compute = qi * block_q + block_q - 1 >= kj * block_k
-
-    @pl.when(should_compute)
+    @pl.when(_block_live(qi, kj, block_q, block_k, causal, window))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (BQ, Hd)
         k = k_ref[0, 0].astype(jnp.float32)          # (BK, Hd)
         v = v_ref[0, 0].astype(jnp.float32)          # (BK, Hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        s = _mask(s, qi, kj, block_q, block_k, causal, window)
 
         m_prev = m_ref[:]                             # (BQ, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -95,7 +119,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
             lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True):
+def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True,
+         window=None):
     b, n, s, hd = q.shape
     nkv = k.shape[1]
     block_q = min(block_q, s)
@@ -105,7 +130,19 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True):
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               need_lse=need_lse)
+                               need_lse=need_lse, window=window)
+
+    def kv_block(i, j):
+        """The key block a grid step fetches: its own, or, under a window,
+        the nearest one the query block computes (a repeated index is no
+        DMA), so a step the band skips moves nothing."""
+        if window is None:
+            return j
+        lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
+        hi = ((i * block_q + block_q - 1) // block_k if causal
+              else s // block_k - 1)
+        return jnp.minimum(jnp.maximum(j, lo), hi)
+
     out_specs = [pl.BlockSpec((1, 1, block_q, hd), lambda b_, h, i, j: (b_, h, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, n, s, hd), q.dtype)]
     if need_lse:
@@ -119,8 +156,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h, i, j: (b_, h * nkv // n, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h, i, j: (b_, h * nkv // n, j, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h, i, j: (b_, h * nkv // n, kv_block(i, j), 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b_, h, i, j: (b_, h * nkv // n, kv_block(i, j), 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -141,7 +178,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, need_lse=True):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc_ref, *, scale, causal, block_q, block_k):
+                   dq_acc_ref, *, scale, causal, block_q, block_k,
+                   window=None):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -150,11 +188,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    should = True
-    if causal:
-        should = qi * block_q + block_q - 1 >= kj * block_k
-
-    @pl.when(should)
+    @pl.when(_block_live(qi, kj, block_q, block_k, causal, window))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -164,10 +198,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         delta = delta_ref[0, 0][:, :1]                # (BQ, 1), lane 0
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        s = _mask(s, qi, kj, block_q, block_k, causal, window)
         p = jnp.exp(s - lse)                           # (BQ, BK)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -182,7 +213,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                    *, scale, causal, block_q, block_k, nq_blocks):
+                    *, scale, causal, block_q, block_k, nq_blocks,
+                    window=None):
     kj = pl.program_id(2)
     qi = pl.program_id(3)   # innermost: folded (group-member × q-block) index
     nq = pl.num_programs(3)
@@ -196,11 +228,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    should = True
-    if causal:
-        should = qb * block_q + block_q - 1 >= kj * block_k
-
-    @pl.when(should)
+    @pl.when(_block_live(qb, kj, block_q, block_k, causal, window))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -210,10 +238,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+        s = _mask(s, qb, kj, block_q, block_k, causal, window)
         p = jnp.exp(s - lse)                           # (BQ, BK)
         dv_acc_ref[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                              preferred_element_type=jnp.float32)
@@ -229,7 +254,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, block_q, block_k, interpret, res, dout):
+def _bwd(scale, causal, block_q, block_k, interpret, res, dout, window=None):
     q, k, v, out, lse = res
     b, n, s, hd = q.shape
     nkv = k.shape[1]
@@ -245,7 +270,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, dout):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         grid=(b, n, s // block_q, s // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b_, h, i, j: (b_, h, i, 0)),
@@ -277,7 +302,8 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, dout):
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq_blocks=nq_blocks),
+                          block_q=block_q, block_k=block_k, nq_blocks=nq_blocks,
+                          window=window),
         grid=(b, nkv, s // block_k, group * nq_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b_, h, j, i: (b_, qhead(h, i), qblock(i), 0)),
@@ -309,22 +335,24 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, dout):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, window):
     out, _ = _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                  need_lse=False)
+                  need_lse=False, window=window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window=window)
     # keep only lane 0 as the residual — holding the full 128-lane stat from
     # forward to backward would be a 128x HBM blow-up per un-remat'd layer
     return out, (q, k, v, out, lse[..., 0])
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, dout):
-    return _bwd(scale, causal, block_q, block_k, interpret, res, dout)
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, dout):
+    return _bwd(scale, causal, block_q, block_k, interpret, res, dout,
+                window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -350,8 +378,11 @@ def flash_auto(seq_len: int, n_heads: int, n_kv_heads: int) -> bool:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 1024,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Blockwise causal attention. q: (B, S, N, Hd); k, v: (B, S, NKV, Hd).
+    ``window`` W: each query i sees only the keys i − W < j ≤ i (a
+    sliding-window layer, W keys with itself); None: no lower bound.
 
     Returns (B, S, N, Hd). NKV must divide N (GQA). S must be divisible by
     the (clamped) block sizes; callers choose between this kernel and the
@@ -381,5 +412,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = _flash(qt, kt, vt, scale, causal, bq, bk, interpret)
+    out = _flash(qt, kt, vt, scale, causal, bq, bk, interpret, window)
     return out.transpose(0, 2, 1, 3)

@@ -196,8 +196,9 @@ _KV_OPS = _CacheOps(
 def _cache_ops(cfg) -> _CacheOps:
     """Per-head K/V rows unless the config names another cache kind, whose
     module is imported here and nowhere earlier: latent rows
-    (``models.mla.MlaMoeConfig.cache_kind``) or a hybrid's recurrent state
-    beside K/V rows (``models.hybrid.HybridConfig``)."""
+    (``models.mla.MlaMoeConfig.cache_kind``), a hybrid's recurrent state
+    beside K/V rows (``models.hybrid.HybridConfig``), or window layers'
+    rings beside full layers' rows (``models.afmoe.AfmoeConfig``)."""
     kind = getattr(cfg, "cache_kind", "kv")
     if kind == "latent":
         from . import latent_cache as lc
@@ -207,6 +208,10 @@ def _cache_ops(cfg) -> _CacheOps:
         from . import state_cache as sc
         return _CacheOps(sc.GRID_LAYOUT, sc.init_grid, sc.init_rows,
                          sc.rows_mix, sc.grid_mix, sc.run_rows, sc.join_rows)
+    if kind == "window":
+        from . import window_cache as wc
+        return _CacheOps(wc.GRID_LAYOUT, wc.init_grid, wc.init_rows,
+                         wc.rows_mix, wc.grid_mix, wc.run_rows, wc.join_rows)
     return _KV_OPS
 
 
@@ -254,7 +259,7 @@ def _einsum_attention(q, leaves, pos, scale):
     return jnp.einsum("bkgws,bksh->bwkgh", probs, cv).reshape(b, w, nh, hd)
 
 
-def grid_attend(cfg, cache, layer, pos):
+def grid_attend(cfg, cache, layer, pos, frontier=None):
     """The block's attention operation over the slot grid: one new token a
     slot, against layer ``layer`` of the stacked grid, which is written and
     read where it lies.
@@ -263,12 +268,15 @@ def grid_attend(cfg, cache, layer, pos):
     ``QuantKVCache`` (``kv_quant``: the new row is QUANTIZED before it is
     written and attention folds the row scales in instead of materializing
     fp rows); layer: traced int32; pos: (B,) absolute position of each
-    slot's new token (also its cache row). Returns
-    ``attend(q, k, v) -> (attn, cache')`` over q (B, 1, NH, Hd) and
-    k, v (B, 1, NKV, Hd)."""
+    slot's new token (also its cache row); ``frontier`` (B,): the last row
+    each slot attends, where it is not ``pos`` (a window layer's ring,
+    ``serve.window_cache``, writes row ``pos mod R`` and reads rows up to
+    ``min(pos, R - 1)``). Returns ``attend(q, k, v) -> (attn, cache')``
+    over q (B, 1, NH, Hd) and k, v (B, 1, NKV, Hd)."""
     from .kv_quant import QuantKVCache, grid_rows
     quant = isinstance(cache, QuantKVCache)
     scale = attention_scale(cfg)
+    read = pos if frontier is None else frontier
 
     def attend(q, k, v):
         b, nh, nkv, hd = q.shape[0], q.shape[2], k.shape[2], q.shape[3]
@@ -303,7 +311,7 @@ def grid_attend(cfg, cache, layer, pos):
                 # chip.
                 sp = (ring.sp_decode_attention_quant_sharded if quant
                       else ring.sp_decode_attention_sharded)
-                attn = sp(q1, *layer_leaves(), pos, mesh, scale=scale)
+                attn = sp(q1, *layer_leaves(), read, mesh, scale=scale)
             elif _decode_kernel_wanted() and hd % _LANES == 0:
                 # fused flash-decode over the stacked grid itself: streams
                 # K/V tiles, skips tiles past each slot's frontier entirely
@@ -315,9 +323,9 @@ def grid_attend(cfg, cache, layer, pos):
                 # whole grid in and out
                 kernel = (kernel_shard.decode_attention_quant_sharded if quant
                           else kernel_shard.decode_attention_sharded)
-                attn = kernel(q1, *grid, pos, layer, mesh, scale=scale)
+                attn = kernel(q1, *grid, read, layer, mesh, scale=scale)
             else:
-                attn = _einsum_attention(q, layer_leaves(), pos[:, None],
+                attn = _einsum_attention(q, layer_leaves(), read[:, None],
                                          scale)
             return attn.astype(q.dtype), grid
 
@@ -412,23 +420,27 @@ def _decode_step_impl(params, cache, pos, toks, rng, temps, cfg,
 
     ffn = (partial(ffn_block, cfg) if live is None
            else partial(ffn_block, cfg, token_mask=(live != 0)[:, None]))
-    carry, routed, seen = (x, cache), None, []
+    carry, routed, seen = (x, cache), [], []
     for stack, whole, start, n in layer_stacks(cfg, params):
         # adapter banks are stacked over one run of layers
         carry, out = lax.scan(
             partial(body, whole, start), carry,
             (stack, jnp.arange(start, start + n), banks or {}))
         if out is not None:
-            routed = routed if out[0] is None else out[0]   # the one run
+            if out[0] is not None:
+                routed.append(out[0])                       # expert runs
             seen.append(out[1])                             # every run
     tallied = None
     if live is not None:
-        tallied = {} if routed is None else {"moe": routed}
+        tallied = {} if not routed else {"moe": routed[0] if len(routed) == 1
+                                         else jnp.concatenate(routed, 0)}
         if selects:
             tallied["dsa"] = jnp.concatenate(seen, axis=0)
         if "ssm_state_slot_steps" in _tally_shapes(cfg):
             tallied["ssm_state_slot_steps"] = jnp.sum(live != 0,
                                                       dtype=jnp.int32)
+        if "attn_rows" in _tally_shapes(cfg):
+            tallied["attn_rows"] = cfg.attn_rows_read(pos, live != 0)
     x, new_cache = carry
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = scale_logits(lm_head_dot(x[:, 0], params, cfg.dtype), cfg)
@@ -457,23 +469,26 @@ def _tally_shapes(cfg) -> Dict[str, tuple]:
     ``moe``, the expert layers' routing tally (L_moe, 2, E) — the routed
     (token, choice) pairs of the live slots an expert got, and the steps in
     which it got any —, ``dsa``, (L, 2): the rows a sparse-attention
-    layer scored and selected for the live slots, and
+    layer scored and selected for the live slots,
     ``ssm_state_slot_steps``, (): the live slots whose recurrent state a
-    step advanced, summed over steps. A family says which it keeps
-    (``routed_tally_shape`` / ``dsa_tally_shape`` / ``ssm_tally_shape``)."""
+    step advanced, summed over steps, and ``attn_rows``, (2,): the K/V rows
+    the live slots' window layers and full layers read. A family says which
+    it keeps (``routed_tally_shape`` / ``dsa_tally_shape`` /
+    ``ssm_tally_shape`` / ``attn_rows_tally_shape``)."""
     shapes = {"moe": getattr(cfg, "routed_tally_shape", None),
               "dsa": getattr(cfg, "dsa_tally_shape", None),
-              "ssm_state_slot_steps": getattr(cfg, "ssm_tally_shape", None)}
+              "ssm_state_slot_steps": getattr(cfg, "ssm_tally_shape", None),
+              "attn_rows": getattr(cfg, "attn_rows_tally_shape", None)}
     return {k: v for k, v in shapes.items() if v is not None}
 
 
 # ``dsa`` counts rows, 100,000 a step and layer at 16 slots of 8k context:
-# an int32 would wrap within minutes, and ``ssm_state_slot_steps`` within
-# days, so their running sums are kept as two int32 words, [..., 0]
-# counting 2**_DSA_WORD of [..., 1]'s units (a step adds at most
-# SLOTS x S_max to the low word before it carries)
+# an int32 would wrap within minutes, ``attn_rows`` likewise, and
+# ``ssm_state_slot_steps`` within days, so their running sums are kept as
+# two int32 words, [..., 0] counting 2**_DSA_WORD of [..., 1]'s units (a
+# step adds at most SLOTS x S_max x layers to the low word before it carries)
 _DSA_WORD = 24
-_TWO_WORDS = ("dsa", "ssm_state_slot_steps")
+_TWO_WORDS = ("dsa", "ssm_state_slot_steps", "attn_rows")
 
 
 def _init_tally(cfg) -> Optional[Dict[str, Any]]:
@@ -776,10 +791,13 @@ def _splice_slot(cache, slot, k_new, v_new):
     grid the rows quantize HERE — prefill itself always runs full-precision
     math. A cache kind with leaves that have no rows (its type's
     ``state_leaves``: ``serve.state_cache.HybridCache``) comes whole in
-    ``k_new``; each such leaf replaces the slot's as it is."""
+    ``k_new``; each such leaf replaces the slot's as it is. So does a kind
+    whose leaves hold different numbers of rows (``serve.window_cache``: a
+    ring's first rows are written as a full grid's are)."""
     from .kv_quant import grid_rows
     state = getattr(cache, "state_leaves", ())
-    new = (k_new if state else grid_rows(cache, k_new, v_new))
+    new = (k_new if isinstance(k_new, type(cache))
+           else grid_rows(cache, k_new, v_new))
     return _constrain_cache(type(cache)(*(
         lax.dynamic_update_slice(
             g, (r if name in state else jnp.swapaxes(r, 2, 3)).astype(g.dtype),
@@ -982,6 +1000,15 @@ class EngineStats:
     # state the decode steps advanced, summed over steps. Accumulated and
     # fetched like the routing tally
     ssm_state_slot_steps: Any = None
+    # window and full attention layers (None elsewhere), each summed over
+    # their layers: the K/V rows the live slots' decode steps read
+    # (accumulated and fetched like the routing tally), and the query-key
+    # pairs the admitted prompts attended (counted at admission, from each
+    # prompt's length: ``AfmoeConfig.prompt_pairs``)
+    window_rows_read: Any = None
+    full_rows_read: Any = None
+    window_pairs_prefilled: Any = None
+    full_pairs_prefilled: Any = None
 
 
 class GenerationEngine:
@@ -1074,6 +1101,10 @@ class GenerationEngine:
         self._tally = _init_tally(cfg)
         self._tally_host = self._tally and {
             k: np.zeros(v.shape, np.int64) for k, v in self._tally.items()}
+        # the query-key pairs the admitted prompts attended, where the family
+        # counts them (window and full layers)
+        self._prompt_pairs = (np.zeros(2, np.int64)
+                              if hasattr(cfg, "prompt_pairs") else None)
         if self.quantize_kv:
             # int8 grid (kv_quant): halves the decode HBM stream + cache
             # footprint; prefill/prefix math stays full-precision, rows
@@ -2016,6 +2047,8 @@ class GenerationEngine:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :t] = req.prompt
             tokens, true_len = jnp.asarray(padded), jnp.int32(t)
+            if self._prompt_pairs is not None:
+                self._prompt_pairs += self.cfg.prompt_pairs(t)
             key, skey = self._request_keys(req, start)
         # the dispatch only: _finish_admission queues the splice behind it and
         # _emit_firsts waits for the first token, under the same phase, once
@@ -2368,6 +2401,12 @@ class GenerationEngine:
         if "ssm_state_slot_steps" in host:
             out["ssm_state_slot_steps"] = int(
                 _from_words(host["ssm_state_slot_steps"]))
+        if "attn_rows" in host:
+            window, full = (int(r) for r in _from_words(host["attn_rows"]))
+            out.update(window_rows_read=window, full_rows_read=full)
+        if self._prompt_pairs is not None:
+            out.update(window_pairs_prefilled=int(self._prompt_pairs[0]),
+                       full_pairs_prefilled=int(self._prompt_pairs[1]))
         return out
 
     def stats(self) -> EngineStats:
@@ -2425,6 +2464,10 @@ class GenerationEngine:
         if s.ssm_state_slot_steps is not None:
             out["engine_ssm_state_slot_steps_total"] = float(
                 s.ssm_state_slot_steps)
+        for name in ("window_rows_read", "full_rows_read",
+                     "window_pairs_prefilled", "full_pairs_prefilled"):
+            if getattr(s, name) is not None:
+                out[f"engine_{name}_total"] = float(getattr(s, name))
         spec = getattr(self, "spec_stats", None)
         if spec is not None:
             out["engine_spec_rounds"] = float(spec.rounds)

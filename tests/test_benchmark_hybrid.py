@@ -110,8 +110,10 @@ def test_configuration_is_the_catalog_row_key_for_key(full):
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         full["name"], "chat-closed-64", 1)
     assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
-    # appended, and nothing before them moved
-    assert bench["configs"][-1] is entry and bench["workloads"][-1] is cell
+    # appended behind the four older configurations and cells, where they
+    # stay when later ones are appended behind them
+    assert bench["configs"].index(entry) == 4
+    assert bench["workloads"].index(cell) == 4
     listed = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
               if CELL in m.get("workloads", ())}
     assert {"serve_tok_s", "step_mfu.serve_hybrid",

@@ -30,6 +30,10 @@ And the latent-attention prefill kernel (ISSUE 39): Kimi-VL's bucket-1,024
 prefill holds no call of it (its score array is one array), and the kernel
 at GLM-5's widths compiles.
 
+And the window configuration (``trinity-mini-l5``): its decode block
+reads each ring and the full grid where they lie through the decode kernel,
+one call a run, and its bucket-8,192 prefill is one flash call a run.
+
 And the hybrid configuration (``granite-4.0-h-micro``): its decode block
 advances the Mamba-2 state only through ``kt_ssm_state_step`` calls that
 alias the whole state grid to their output, and nothing else reads, copies
@@ -168,7 +172,7 @@ class _ChipPrograms:
 def steered_onto_the_chip(one_chip):
     """The CPU process steered onto the chip's branch here, not by an option
     of the program: the kernel paths, compiled by Mosaic."""
-    from kubetorch_tpu.models import generate, mla
+    from kubetorch_tpu.models import experts, generate, mla
     from kubetorch_tpu.ops import (attention, decode_attention,
                                    mla_prefill_attention, moe_experts,
                                    ssm_state)
@@ -178,7 +182,7 @@ def steered_onto_the_chip(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(E, "_decode_kernel_wanted", lambda: True)
-        mp.setattr(mla, "moe_experts_auto", moe_experts.moe_experts_supported)
+        mp.setattr(experts, "moe_experts_auto", moe_experts.moe_experts_supported)
         mp.setattr(mla, "flash_prefill_auto", mla.flash_prefill_supported)
         mp.setattr(state_cache, "ssm_state_step_auto",
                    ssm_state.ssm_state_supported)
@@ -512,6 +516,66 @@ def test_the_state_kernel_compiles_at_granites_widths(chip_programs):
     assert 'custom_call_target="tpu_custom_call"' in calls[0]
     assert "output_to_operand_aliasing={{1}: (1, {})}" in calls[0]
     assert compiled.memory_analysis().temp_size_in_bytes < h * p * n * 4
+
+def _trinity():
+    from kubetorch_tpu.models.afmoe import (FULL, WINDOW, AfmoeConfig,
+                                            afmoe_init)
+    return (AfmoeConfig(layer_types=(WINDOW,) * 4 + (FULL,),
+                        num_dense_layers=1), afmoe_init)
+
+
+def test_window_decode_block_reads_the_rings_and_the_grid_where_they_lie(
+        chip_programs):
+    """The window configuration (``trinity-mini-l5``: a dense window layer,
+    three window expert layers, a full one) at the cell's 16 slots x 9,728
+    rows: one ``kt_decode_attention`` call a run of layers, Mosaic's, whose
+    K and V operands are the whole rings (window runs) or the whole full
+    grid (the full run), each the in-place update that wrote the step's
+    row; nothing copies, slices or remakes a ring, the grid or a layer of
+    either; all four cache leaves are donated."""
+    cfg, init = _trinity()
+    text = chip_programs.decode(cfg, init, s_max=9728)
+    ring = (cfg.n_window, SLOTS, cfg.n_kv_heads, cfg.sliding_window, HD)
+    full = (cfg.n_full, SLOTS, cfg.n_kv_heads, 9728, HD)
+    grids = [sorted(d for d in g if d != 1) for g in (ring, full)]
+    layers = [sorted(d for d in g[1:] if d != 1) for g in (ring, full)]
+    by_name = {name: (dims, op) for name, dims, op, _ in _instructions(text)}
+    calls = [(name, rest) for name, op, rest in _any_instructions(text)
+             if op == "custom-call" and name.startswith("kt_decode_attention")]
+    assert len(calls) == len(cfg.runs) == 3, [c[0] for c in calls]
+    read = []
+    for name, rest in calls:
+        assert 'custom_call_target="tpu_custom_call"' in rest
+        kv = [o for o in _operands(rest) if by_name.get(o, ([], ""))[0]
+              in grids]
+        assert len(kv) == 2, (name, _operands(rest))
+        assert all(by_name[o][1] == "dynamic-update-slice" for o in kv)
+        read.append(by_name[kv[0]][0])
+    assert sorted(map(tuple, read)) == sorted(
+        [tuple(grids[0])] * 2 + [tuple(grids[1])])
+    moving = ("copy", "copy-start", "transpose", "dynamic-slice", "scatter",
+              "gather", "slice", "concatenate", "pad", "select", "broadcast")
+    bad = [f"{op} {n}" for n, dims, op, _ in _instructions(text)
+           if (dims in layers and op in moving) or (
+               dims in grids and op not in (
+                   "parameter", "get-tuple-element", "bitcast",
+                   "dynamic-update-slice"))]
+    assert bad == []
+    assert _aliased_cache_params(text, 4)
+
+
+def test_window_prefill_takes_the_flash_kernel_a_run(chip_programs):
+    """The bucket of 8,192 at the cell's widths: a prompt's attention is one
+    ``kt_flash_attention_fwd`` call a run of layers, the window runs' with
+    the window as its operand, compiled by Mosaic for the described chip."""
+    cfg, init = _trinity()
+    text = chip_programs.prefill(cfg, init, 8192)
+    calls = [rest for name, op, rest in _any_instructions(text)
+             if op == "custom-call"
+             and name.startswith("kt_flash_attention_fwd")]
+    assert len(calls) == len(cfg.runs) == 3
+    assert all('custom_call_target="tpu_custom_call"' in c for c in calls)
+
 
 # -- the census of the older cells' programs -----------------------------------
 

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from tests import glm5_reference as R
 from tests.test_mla_moe import _drive, dense_rows_max
 from kubetorch_tpu.exceptions import UnsupportedMechanismError
-from kubetorch_tpu.models import mla
+from kubetorch_tpu.models import experts, mla
 from kubetorch_tpu.models.llama import rope_freqs
 from kubetorch_tpu.models.mla import (MlaMoeConfig, mla_moe_forward,
                                       mla_moe_init)
@@ -237,14 +237,14 @@ def test_the_shares_of_a_layer_add_up_to_the_whole(path, monkeypatch):
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 24, whole.dim),
                           jnp.float32)
     if path == "sorted-chunked":                # 48 rows x 3: 9 chunks of 16
-        monkeypatch.setattr(mla, "HELD_PAIRS_CHUNK", 16)
+        monkeypatch.setattr(experts, "HELD_PAIRS_CHUNK", 16)
     total, pairs = 0.0, 0
     with dense_rows_max(512 if path == "dense" else 0):
         for first in (0, 2, 4, 6):
             cfg = glm_tiny(held=(first, 2))
             share = {**lw, "banks": {k: v[first:first + 2]
                                      for k, v in lw["banks"].items()}}
-            out, tally = mla.moe_ffn_dropless(cfg, h, share)
+            out, tally = experts.moe_ffn_dropless(cfg, h, share)
             assert tally.shape == (2, 2)
             total, pairs = total + out, pairs + int(tally[0].sum())
     assert pairs == 48 * whole.experts_per_token        # each pair once

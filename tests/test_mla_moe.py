@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from tests import mla_reference as R
 from kubetorch_tpu.exceptions import UnsupportedMechanismError
-from kubetorch_tpu.models import mla
+from kubetorch_tpu.models import experts, mla
 from kubetorch_tpu.models.block import decoder_block
 from kubetorch_tpu.models.generate import ffn_block
 from kubetorch_tpu.models.llama import rope_freqs
@@ -44,14 +44,14 @@ F32 = dict(dtype=jnp.float32)
 
 @contextlib.contextmanager
 def dense_rows_max(n):
-    """``mla.DENSE_ROWS_MAX`` set to ``n`` for the programs traced inside
+    """``experts.DENSE_ROWS_MAX`` set to ``n`` for the programs traced inside
     (0: every call sorts its pairs by expert). The constant is read at trace
     time and is no part of a jit's key, so the engine's programs, the jits
     that trace the expert layer, are dropped on the way in and out."""
     from kubetorch_tpu.serve import engine as E
     programs = (E._prefill, E._decode_step, E._decode_block)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(mla, "DENSE_ROWS_MAX", n)
+        mp.setattr(experts, "DENSE_ROWS_MAX", n)
         for f in programs:
             f.clear_cache()
         yield
@@ -62,7 +62,7 @@ def dense_rows_max(n):
 @pytest.fixture(scope="module", params=["dense", "sorted"])
 def tiny(request):
     """The tiny model under both formulations of the routed experts: every
-    expert over every row (``mla.DENSE_ROWS_MAX`` rows at most), and pairs
+    expert over every row (``experts.DENSE_ROWS_MAX`` rows at most), and pairs
     sorted by expert through the grouped matmul (forced here by a maximum of
     0)."""
     cfg = MlaMoeConfig.tiny(**F32)
@@ -173,7 +173,7 @@ def _router_case(bias):
 
 def test_router_weights_are_normalised_and_scaled():
     cfg, lw, h = _router_case(np.zeros(8))
-    w, idx = mla.route(cfg, h, lw)
+    w, idx = experts.route(cfg, h, lw)
     assert sorted(idx[0].tolist()) == [0, 1, 2]
     assert sorted(idx[1].tolist()) == [3, 5, 7]
     np.testing.assert_allclose(np.asarray(w).sum(-1), 2.446, rtol=1e-6)
@@ -191,7 +191,7 @@ def test_router_bias_changes_the_choice_and_not_the_weights():
     bias = np.zeros(8)
     bias[7] = 1.0                  # lifts the last expert over the third
     cfg, lw, h = _router_case(bias)
-    w, idx = mla.route(cfg, h, lw)
+    w, idx = experts.route(cfg, h, lw)
     assert sorted(idx[0].tolist()) == [0, 1, 7]
     s = 1.0 / (1.0 + np.exp(-np.asarray([3.0, 2.0, -3.0])))    # unbiased
     got = dict(zip(idx[0].tolist(), np.asarray(w[0]).tolist()))
@@ -227,7 +227,7 @@ def test_an_overfull_expert_drops_nothing(rows_max):
     h = jax.random.normal(jax.random.PRNGKey(8), (1, 32, cfg.dim))
     lw = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
     with dense_rows_max(rows_max):
-        _, tally = mla.moe_ffn_dropless(cfg, h, lw)
+        _, tally = experts.moe_ffn_dropless(cfg, h, lw)
         got = np.asarray(mla_moe_forward(params, toks[None], cfg)[0])
     assert int(tally[0, 0]) == 32 > 1.25 * 32 * 3 / 8
     np.testing.assert_allclose(got, R.forward(params, toks, cfg), atol=2e-4)
@@ -245,7 +245,7 @@ def test_padding_claims_no_expert_and_counts_nothing(tiny):
     np.testing.assert_array_equal(tally, tally_alone)
     assert int(tally[0].sum()) == real * cfg.experts_per_token
     # a padded position gets the shared expert alone: nothing was routed
-    shared = mla.dense_ffn(h[0, real:], lw["shared"])[0]
+    shared = experts.dense_ffn(h[0, real:], lw["shared"])[0]
     np.testing.assert_allclose(padded[0, real:], shared, atol=1e-5)
 
 
@@ -343,7 +343,7 @@ def test_a_scrape_beside_the_loop_never_fetches_the_tally(tiny):
 
 @pytest.mark.parametrize("widths", ["tiny", "lane-aligned"])
 def test_the_two_formulations_of_the_experts_agree(tiny, widths):
-    """Rows past ``mla.DENSE_ROWS_MAX`` are sorted by expert, the rest go
+    """Rows past ``experts.DENSE_ROWS_MAX`` are sorted by expert, the rest go
     through every expert: one layer, the same rows, the same output. Where
     the grouped kernel runs (``ops.moe_experts``: the chip's branch, chosen
     here and interpreted; the tiny model's widths are not lane-aligned) it is
@@ -360,13 +360,13 @@ def test_the_two_formulations_of_the_experts_agree(tiny, widths):
         for dense, on_chip in ((0, False), (20, False), (512, False),
                                (20, True)):
             with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(mla, "DENSE_ROWS_MAX", dense)     # eager: no jit
+                mp.setattr(experts, "DENSE_ROWS_MAX", dense)     # eager: no jit
                 if on_chip:
-                    mp.setattr(mla, "moe_experts_auto", moe_experts_supported)
-                kernel = mla.moe_experts
-                mp.setattr(mla, "moe_experts", lambda x, *a: (
+                    mp.setattr(experts, "moe_experts_auto", moe_experts_supported)
+                kernel = experts.moe_experts
+                mp.setattr(experts, "moe_experts", lambda x, *a: (
                     kernel_rows.append(x.shape[0]), kernel(x, *a))[1])
-                outs.append(mla.moe_ffn_dropless(cfg, h, lw)[0])
+                outs.append(experts.moe_ffn_dropless(cfg, h, lw)[0])
         for out in outs[1:]:
             np.testing.assert_allclose(outs[0], out, atol=1e-5)
         took_kernel = widths == "lane-aligned" and rows <= 20

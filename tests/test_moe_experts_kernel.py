@@ -20,7 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from kubetorch_tpu.models import mla
+from kubetorch_tpu.models import experts, mla
 from kubetorch_tpu.ops import moe_experts as K
 
 pytestmark = pytest.mark.level("unit")
@@ -82,7 +82,7 @@ def test_kernel_is_the_einsum_form_in_float32(case, layer):
     x = _rows(len(CASES[case]), jnp.float32)
     got = K.moe_experts(x, gates, banks["w_gate"], banks["w_up"],
                         banks["w_down"], layer, sizes)
-    want = mla._dense_experts(x, gates, banks, layer)
+    want = experts._dense_experts(x, gates, banks, layer)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(got, want, atol=1e-5)
     if case == "none-hit":
@@ -100,9 +100,9 @@ def test_kernel_in_bfloat16_is_no_farther_from_float32_than_the_einsums(case):
     exact_banks = {k: v.astype(jnp.float32) for k, v in bf16.items()}
     gates, sizes = _routing(CASES[case])
     x = _rows(len(CASES[case]), jnp.bfloat16)
-    exact = np.asarray(mla._dense_experts(x.astype(jnp.float32), gates,
+    exact = np.asarray(experts._dense_experts(x.astype(jnp.float32), gates,
                                           exact_banks, 1))
-    einsums = np.asarray(mla._dense_experts(x, gates, bf16, 1), np.float32)
+    einsums = np.asarray(experts._dense_experts(x, gates, bf16, 1), np.float32)
     got = K.moe_experts(x, gates, bf16["w_gate"], bf16["w_up"],
                         bf16["w_down"], 1, sizes)
     assert got.dtype == jnp.bfloat16
@@ -147,7 +147,7 @@ def test_an_unhit_experts_bank_is_never_read():
         "w_gate", "w_up", "w_down")), 1, sizes)
     assert np.isfinite(np.asarray(got)).all()
     clean = _banks(jnp.float32)
-    np.testing.assert_allclose(got, mla._dense_experts(x, gates, clean, 1),
+    np.testing.assert_allclose(got, experts._dense_experts(x, gates, clean, 1),
                                atol=1e-5)
 
 
@@ -194,14 +194,14 @@ def test_the_tiled_kernel_is_the_einsum_form(case, monkeypatch):
     x = _rows(len(CASES[case]), jnp.float32)
     got = K.moe_experts(x, gates, banks["w_gate"], banks["w_up"],
                         banks["w_down"], 2, sizes)
-    np.testing.assert_allclose(got, mla._dense_experts(x, gates, banks, 2),
+    np.testing.assert_allclose(got, experts._dense_experts(x, gates, banks, 2),
                                atol=1e-5)
 
 
 @pytest.mark.parametrize("rows,d,f,backend,kernel", [
     (1, D, F, "tpu", True), (16, D, F, "tpu", True), (256, D, F, "tpu", True),
-    (mla.DENSE_ROWS_MAX, D, F, "tpu", True),
-    (mla.DENSE_ROWS_MAX + 1, D, F, "tpu", False),     # sorted runs
+    (experts.DENSE_ROWS_MAX, D, F, "tpu", True),
+    (experts.DENSE_ROWS_MAX + 1, D, F, "tpu", False),     # sorted runs
     (16, 64, 32, "tpu", False),
     # off the chip the einsums, as the attention kernels' callers choose
     (16, D, F, "cpu", False), (16, D, F, "gpu", False),
@@ -213,17 +213,17 @@ def test_the_kernel_is_chosen_from_rows_shapes_and_backend(monkeypatch, rows,
     lw = jax.tree_util.tree_map(
         lambda a: a[0], mla.mla_moe_init(jax.random.PRNGKey(0), cfg)["layers"])
     calls = []
-    monkeypatch.setattr(mla, "moe_experts", lambda x, *a, **k: calls.append(
+    monkeypatch.setattr(experts, "moe_experts", lambda x, *a, **k: calls.append(
         x.shape) or jnp.zeros_like(x))
     h = jax.random.normal(jax.random.PRNGKey(1), (1, rows, d))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    mla.moe_ffn_dropless(cfg, h, lw)
+    experts.moe_ffn_dropless(cfg, h, lw)
     assert calls == ([(rows, d)] if kernel else [])
 
 
 def test_the_gradient_is_the_einsum_forms():
     """``jax.grad`` through a call that takes the kernel: the backward pass
-    differentiates the einsum form (``mla._grouped_experts``)."""
+    differentiates the einsum form (``experts._grouped_experts``)."""
     banks = _banks(jnp.float32)
     gates, sizes = _routing(CASES["some-unhit"])
     x = _rows(5, jnp.float32)
@@ -232,10 +232,10 @@ def test_the_gradient_is_the_einsum_forms():
         return jnp.sum(jnp.sin(f(x, gates, banks)))
 
     got = jax.grad(lambda *a: loss(
-        lambda x, g, b: mla._grouped_experts(x, g, b, 2, sizes), *a),
+        lambda x, g, b: experts._grouped_experts(x, g, b, 2, sizes), *a),
         argnums=(0, 1, 2))(x, gates, banks)
     want = jax.grad(lambda *a: loss(
-        lambda x, g, b: mla._dense_experts(x, g, b, 2), *a),
+        lambda x, g, b: experts._dense_experts(x, g, b, 2), *a),
         argnums=(0, 1, 2))(x, gates, banks)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):

@@ -12,8 +12,10 @@ every expert that got a token, with a gate of zero where the row did not
 choose it (on the TPU backend ONE kernel call that streams only the banks of
 the experts that got a token: ``ops.moe_experts``; elsewhere plain einsums
 over every bank); more rows are sorted by expert and each expert multiplies
-its own run (``jax.lax.ragged_dot``). Shared experts are one dense SwiGLU
-added beside them.
+its own run (on the TPU backend ONE kernel call over the sorted rows that
+streams each hit expert's bank once: ``ops.moe_experts.moe_sorted``;
+elsewhere XLA's grouped matmul, ``jax.lax.ragged_dot``). Shared experts are
+one dense SwiGLU added beside them.
 
 A layer may hold a SHARE of its experts (``held``): the router still scores
 all ``n_experts``, the layer keeps banks for the held ones only and adds only
@@ -36,7 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.moe_experts import moe_experts, moe_experts_auto
+from ..ops.moe_experts import (moe_experts, moe_experts_auto, moe_sorted,
+                                moe_sorted_auto)
 from .block import dense_ffn
 
 # The routed experts are one algorithm in two ranges of rows (the tokens of
@@ -54,17 +57,22 @@ from .block import dense_ffn
 # 1.46, 1.51 / 1.50, 1.79 / 1.51, 2.02 / 1.54, 3.62 / 2.98: the kernel is
 # no slower at any, so no second threshold chooses between them.
 #
-# Beyond, (row, choice) pairs sorted by expert go through XLA's grouped
-# matmul. At 16 / 256 / 512 / 1,024 rows the einsums take 1.49 / 2.01 / 3.61
-# / 7.35 and the grouped matmul over the whole stack 2.70 / 5.53 / 5.84 /
-# 6.39; the kernel was not read at 1,024. The cell runs both ranges:
-# a decode step's 16 rows and the buckets 256 / 512, and the bucket 1,024.
+# Beyond, the (row, choice) pairs sorted by expert go through the sorted
+# kernel (``ops.moe_experts.moe_sorted``) where it runs (``moe_sorted_auto``:
+# the TPU backend and experts it can tile), else XLA's grouped matmul. The
+# whole sorted layer (sort, gather, products, back to rows) at 256 / 512 /
+# 1,024 rows: the sorted kernel 1.91 / 2.10 / 2.53, the grouped matmul
+# 5.80 / 6.06 / 6.77, where the grouped kernel takes 1.72 / 3.16 / (its
+# rows do not fit the VMEM beside a bank). The sorted path would be the
+# faster at 512 rows too; moving the threshold changes the chat cell's
+# bucket-512 program, a change of its own. The cell runs both ranges: a
+# decode step's 16 rows and the buckets 256 / 512, and the bucket 1,024.
 DENSE_ROWS_MAX = 512
 
 # A layer that holds a SHARE of its experts gets about held/n_experts of the
 # (row, choice) pairs, and which is not known at trace time: beyond
 # DENSE_ROWS_MAX rows the pairs sorted by held expert (the absent ones' last)
-# go through the grouped matmul this many at a time, and a chunk past the
+# go through the sorted path this many at a time, and a chunk past the
 # last held pair is skipped, so time and temporaries follow the pairs that
 # are there (8,192 rows x 8 choices, 16 of 256 held: 65,536 sorted pairs of
 # which about 4,096 are held, one chunk).
@@ -117,30 +125,66 @@ def _grouped_bwd(saved, ct):
 _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def _ragged_swiglu(xs, whole, layer, sizes):
+    """Each sorted pair's SwiGLU product through XLA's grouped matmul, expert
+    e's ``sizes[e]`` pairs in a run: the stacks as ``n_layers × E`` groups,
+    the other layers' empty. Rows behind the last run come out zero. What
+    the sorted kernel is held to, and what its backward pass
+    differentiates."""
+    n_layers, E = whole["w_gate"].shape[:2]
+    bank = {k: v.reshape(n_layers * E, *v.shape[2:])
+            for k, v in whole.items()}
+    groups = lax.dynamic_update_slice(
+        jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
+    act = (jax.nn.silu(lax.ragged_dot(xs, bank["w_gate"], groups))
+           * lax.ragged_dot(xs, bank["w_up"], groups))
+    ys = lax.ragged_dot(act, bank["w_down"], groups)
+    # rows behind the last run belong to no expert: whatever is there
+    return jnp.where((jnp.arange(ys.shape[0]) < jnp.sum(sizes))[:, None],
+                     ys, 0)
+
+
+@jax.custom_vjp
+def _sorted_swiglu(xs, whole, layer, sizes):
+    """:func:`_ragged_swiglu` as one call of the sorted kernel, which reads
+    the banks from the whole stacks (``ops.moe_experts.moe_sorted``). Its
+    gradient is the grouped matmul's."""
+    return moe_sorted(xs, whole["w_gate"], whole["w_up"], whole["w_down"],
+                      layer, sizes)
+
+
+def _sorted_swiglu_fwd(xs, whole, layer, sizes):
+    return _sorted_swiglu(xs, whole, layer, sizes), (xs, whole, layer, sizes)
+
+
+def _sorted_swiglu_bwd(saved, ct):
+    xs, whole, layer, sizes = saved
+    _, pull = jax.vjp(lambda *a: _ragged_swiglu(*a, layer, sizes), xs, whole)
+    return (*pull(ct), None, None)
+
+
+_sorted_swiglu.defvjp(_sorted_swiglu_fwd, _sorted_swiglu_bwd)
+
+
 def _sorted_experts(cfg, x, w, idx, sizes, banks):
-    """Σ_k w[m, k] · SwiGLU_{idx[m, k]}(x[m]) through XLA's grouped matmul:
-    the (row, choice) pairs sorted by expert, an expert its own run; the
-    other layers' groups of the whole stack are empty."""
+    """Σ_k w[m, k] · SwiGLU_{idx[m, k]}(x[m]) over the (row, choice) pairs
+    sorted by expert, an expert its own run: one call of the sorted kernel
+    where it runs (``moe_sorted_auto``: the TPU backend and experts it can
+    tile), else XLA's grouped matmul."""
     E, K = cfg.n_held, cfg.experts_per_token
     whole, layer = banks
     m, d = x.shape
     order = jnp.argsort(idx.reshape(-1), stable=True)
-    n_layers = whole["w_gate"].shape[0]
-    bank = {k: v.reshape(n_layers * E, *v.shape[2:])
-            for k, v in whole.items()}
+    bank = whole["w_gate"]
+    swiglu = (_sorted_swiglu if moe_sorted_auto(d, bank.shape[-1],
+                                                bank.dtype.itemsize)
+              else _ragged_swiglu)
 
     def run(sel, sizes):
         """A stretch ``sel`` of the sorted pairs, of which expert e has
-        ``sizes[e]`` in a row from its start → each pair's product."""
-        groups = lax.dynamic_update_slice(
-            jnp.zeros((n_layers * E,), jnp.int32), sizes, (layer * E,))
-        xs = x[sel // K]
-        act = (jax.nn.silu(lax.ragged_dot(xs, bank["w_gate"], groups))
-               * lax.ragged_dot(xs, bank["w_up"], groups))
-        ys = lax.ragged_dot(act, bank["w_down"], groups)
-        # rows behind the last run belong to no expert: whatever is there
-        return jnp.where((jnp.arange(ys.shape[0]) < jnp.sum(sizes))[:, None],
-                         ys, 0)
+        ``sizes[e]`` in a row from its start → each pair's product, zero
+        behind the last run."""
+        return swiglu(x[sel // K], whole, layer, sizes)
 
     if cfg.held is None:
         pairs = run(order, sizes)[jnp.argsort(order)].reshape(m, K, d)

@@ -1009,6 +1009,12 @@ class EngineStats:
     full_rows_read: Any = None
     window_pairs_prefilled: Any = None
     full_pairs_prefilled: Any = None
+    # expert layers only (None elsewhere): the (token, choice) pairs of the
+    # admitted prompts' real tokens that went through the expert layers'
+    # sorted path (a prefill of more than ``experts.DENSE_ROWS_MAX`` rows),
+    # summed over those layers; counted at admission from each prompt's
+    # length
+    moe_sorted_pairs: Any = None
 
 
 class GenerationEngine:
@@ -1105,6 +1111,9 @@ class GenerationEngine:
         # counts them (window and full layers)
         self._prompt_pairs = (np.zeros(2, np.int64)
                               if hasattr(cfg, "prompt_pairs") else None)
+        # the prompts' pairs through the expert layers' sorted path, where
+        # the family's expert layers keep the routing tally
+        self._sorted_pairs = 0 if "moe" in _tally_shapes(cfg) else None
         if self.quantize_kv:
             # int8 grid (kv_quant): halves the decode HBM stream + cache
             # footprint; prefill/prefix math stays full-precision, rows
@@ -2049,6 +2058,12 @@ class GenerationEngine:
             tokens, true_len = jnp.asarray(padded), jnp.int32(t)
             if self._prompt_pairs is not None:
                 self._prompt_pairs += self.cfg.prompt_pairs(t)
+            if self._sorted_pairs is not None:
+                # the family's own module: a dense rank never imports it
+                from ..models.experts import DENSE_ROWS_MAX
+                if bucket > DENSE_ROWS_MAX:
+                    self._sorted_pairs += (t * self.cfg.experts_per_token
+                                           * self.cfg.n_moe_layers)
             key, skey = self._request_keys(req, start)
         # the dispatch only: _finish_admission queues the splice behind it and
         # _emit_firsts waits for the first token, under the same phase, once
@@ -2407,6 +2422,8 @@ class GenerationEngine:
         if self._prompt_pairs is not None:
             out.update(window_pairs_prefilled=int(self._prompt_pairs[0]),
                        full_pairs_prefilled=int(self._prompt_pairs[1]))
+        if self._sorted_pairs is not None:
+            out["moe_sorted_pairs"] = self._sorted_pairs
         return out
 
     def stats(self) -> EngineStats:
@@ -2465,7 +2482,8 @@ class GenerationEngine:
             out["engine_ssm_state_slot_steps_total"] = float(
                 s.ssm_state_slot_steps)
         for name in ("window_rows_read", "full_rows_read",
-                     "window_pairs_prefilled", "full_pairs_prefilled"):
+                     "window_pairs_prefilled", "full_pairs_prefilled",
+                     "moe_sorted_pairs"):
             if getattr(s, name) is not None:
                 out[f"engine_{name}_total"] = float(getattr(s, name))
         spec = getattr(self, "spec_stats", None)

@@ -189,6 +189,36 @@ def test_engine_matches_the_reference_with_every_ring_wrapped(tiny):
     assert m["engine_window_rows_read_total"] == s.window_rows_read
 
 
+def test_the_prompts_sorted_pairs_are_counted_at_admission(tiny):
+    """``moe_sorted_pairs``: a prompt whose bucket has more rows than
+    ``experts.DENSE_ROWS_MAX`` (lowered to 32 here, for the programs traced
+    inside) sends its real tokens' K choices through every expert layer's
+    sorted path; one of 20 rows (bucket 32) takes the other path and counts
+    nothing."""
+    from kubetorch_tpu.models import experts
+    programs = (E._prefill, E._decode_step, E._decode_block)
+    prompts = [_tokens(30 + i, n) for i, n in enumerate((20, 33, 40))]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(experts, "DENSE_ROWS_MAX", 32)
+        for f in programs:
+            f.clear_cache()
+        eng = _engine(tiny)
+        hs = [eng.submit(p, max_new_tokens=2) for p in prompts]
+        while eng.step():
+            pass
+    for f in programs:
+        f.clear_cache()
+    _, cfg = tiny
+    for p, h in zip(prompts, hs):
+        served = h.result(timeout=0)
+        np.testing.assert_allclose(h.logprobs,
+                                   _ref_logprobs(tiny, p, served), atol=ATOL)
+    s = eng.stats()
+    assert s.moe_sorted_pairs == (33 + 40) * cfg.experts_per_token * 4
+    m = eng.__kt_metrics__()
+    assert m["engine_moe_sorted_pairs_total"] == s.moe_sorted_pairs
+
+
 def test_the_decode_kernel_reads_a_rings_live_rows():
     """Heads of 128, so the grid's attention takes the decode kernel
     (interpreted here): a slot 30 positions in reads its ring's 8 rows, one

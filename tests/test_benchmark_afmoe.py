@@ -256,3 +256,57 @@ def test_reference_refuses_what_it_would_have_to_guess(bench_path, tiny, key,
     assert dict(R.model_key(tiny))["layer_types"][4] == FULL
     with pytest.raises(ValueError, match=key):
         R.model_key({**tiny, key: value})
+
+
+# -- the reader of the prompts' sorted expert pairs --------------------------------
+
+def test_reader_prefill_experts_roofline(bench_path, tiny):
+    """The least time of the routed products of the prompts prefilled in the
+    counters' window — prompt length × K × expert layers pairs for a prompt
+    whose bucket sorts its pairs, 3 · D · F · 2 operations a pair, at the
+    bfloat16 peak — as a rate, over the sorted kernel's self time as a rate
+    over the trace's window; and the metric's entry in ``BENCHMARK.json``."""
+    mod = _load(os.path.join(BENCH, "readers",
+                             "prefill_experts_roofline_afmoe.py"), "rd_pexr")
+    cfg = {**tiny, "engine": {**tiny["engine"],
+                              "prefill_buckets": [256, 1024]}}
+    log = [{"t_first": 100.5, "t_out": 101.5, "n": 11, "prompt_len": 300},
+           {"t_first": 101.0, "t_out": 101.9, "n": 4, "prompt_len": 100},
+           {"t_first": 99.0, "t_out": 101.0, "n": 9, "prompt_len": 700},
+           {"t_first": None, "t_out": 101.0, "n": 0, "prompt_len": 900}]
+    kernel = {"moe_sorted": {"count": 8.0, "seconds": 0.05}}
+    trace = {"c0": {"now": 100.0}, "c1": {"now": 102.0}, "log": log,
+             "window_s": 4.0, "queries": kernel}
+    ctx = {"config": cfg, "chips": 1, "trace": trace,
+           "peak": {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e8}}
+    # the bucket 1,024 sorts, the bucket 256 does not; one prompt before
+    # the window and one that never got its first token count nothing
+    assert mod.sorted_pairs(cfg, 300) == 300 * 3 * 4
+    assert mod.sorted_pairs(cfg, 100) == 0
+    least = 300 * 3 * 4 * 3 * 64 * 32 * 2 / 1e9
+    assert mod.read(ctx, query="moe_sorted") == pytest.approx(
+        100 * (least / 2.0) / (0.05 / 4.0))
+    # no kernel event, no query, no prompt of the sorted path in the window,
+    # no trace: nothing to read, and no error
+    idle = {"moe_sorted": {"count": 0.0, "seconds": 0.0}}
+    assert mod.read({**ctx, "trace": {**trace, "queries": idle}},
+                    query="moe_sorted") is None
+    assert mod.read({**ctx, "trace": {**trace, "queries": {}}},
+                    query="moe_sorted") is None
+    assert mod.read({**ctx, "trace": {**trace, "log": log[1:]}},
+                    query="moe_sorted") is None
+    assert mod.read({**ctx, "trace": {}}, query="moe_sorted") is None
+    with open(os.path.join(BENCH, "metrics",
+                           "prefill_experts_roofline.serve_afmoe.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "prefill_experts_roofline_afmoe"
+    assert spec["trace_queries"] == [{"id": "moe_sorted", "line": "ops",
+                                      "pattern": "kt_moe_sorted",
+                                      "within": "_prefill"}]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    m = next(m for m in bench["per_layer"]
+             if m["name"] == "prefill_experts_roofline.serve_afmoe")
+    assert (m["layer"], m["moves"], m["unit"], m["source"],
+            m["workloads"]) == ("kernels", "ttft_p50_ms", "%",
+                                "device_trace", [CELL])

@@ -34,6 +34,10 @@ And the window configuration (``trinity-mini-l5``): its decode block
 reads each ring and the full grid where they lie through the decode kernel,
 one call a run, and its bucket-8,192 prefill is one flash call a run.
 
+And a prompt's sorted expert pairs: Trinity-Mini's bucket-8,192 prefill and
+Kimi-VL's bucket-1,024 prefill hold one ``kt_moe_sorted`` call a run of
+expert layers, over the whole bank stacks, and no ``ragged-dot``.
+
 And the hybrid configuration (``granite-4.0-h-micro``): its decode block
 advances the Mamba-2 state only through ``kt_ssm_state_step`` calls that
 alias the whole state grid to their output, and nothing else reads, copies
@@ -183,6 +187,7 @@ def steered_onto_the_chip(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(E, "_decode_kernel_wanted", lambda: True)
         mp.setattr(experts, "moe_experts_auto", moe_experts.moe_experts_supported)
+        mp.setattr(experts, "moe_sorted_auto", moe_experts.moe_sorted_supported)
         mp.setattr(mla, "flash_prefill_auto", mla.flash_prefill_supported)
         mp.setattr(state_cache, "ssm_state_step_auto",
                    ssm_state.ssm_state_supported)
@@ -575,6 +580,42 @@ def test_window_prefill_takes_the_flash_kernel_a_run(chip_programs):
              and name.startswith("kt_flash_attention_fwd")]
     assert len(calls) == len(cfg.runs) == 3
     assert all('custom_call_target="tpu_custom_call"' in c for c in calls)
+
+
+@pytest.mark.parametrize("model,bucket", [("trinity-mini", 8192),
+                                          ("kimi-vl-a3b", 1024)])
+def test_a_prompts_sorted_pairs_take_one_sorted_kernel_call_a_run(
+        chip_programs, model, bucket):
+    """A prefill of more rows than ``experts.DENSE_ROWS_MAX`` sorts its
+    (row, choice) pairs by expert: each run of expert layers is ONE
+    ``kt_moe_sorted`` call in the scan's body, Mosaic's, whose bank operands
+    are the run's three whole stacks as the program was given them, and no
+    grouped matmul of XLA's is left (Trinity-Mini's bucket of 8,192: a run
+    of three window expert layers and the full one; Kimi-VL's bucket of
+    1,024: one run of three)."""
+    cfg, init = _trinity() if model == "trinity-mini" else _kimi()
+    text = chip_programs.prefill(cfg, init, bucket)
+    assert "ragged-dot" not in text
+    by_name = {name: (dims, op) for name, dims, op, _ in _instructions(text)}
+    calls = [rest for name, op, rest in _any_instructions(text)
+             if op == "custom-call" and name.startswith("kt_moe_sorted")]
+    runs = ([n for _, ffn, _, n in cfg.runs if ffn == "moe"]
+            if model == "trinity-mini" else [cfg.n_moe_layers])
+    assert len(calls) == len(runs), len(calls)
+    # a stack's shape as the text's types read (a unit axis left out)
+    shapes = sorted(sorted(d for d in (n, cfg.n_experts, cfg.dim,
+                                       cfg.moe_ffn_dim) if d != 1)
+                    for n in runs)
+    stacks = []
+    for rest in calls:
+        assert 'custom_call_target="tpu_custom_call"' in rest
+        whole = [o for o in _operands(rest)
+                 if by_name.get(o, ([], ""))[0] in shapes]
+        assert len(whole) == 3, _operands(rest)
+        assert all(by_name[o][1] in ("parameter", "get-tuple-element")
+                   for o in whole)
+        stacks.append(by_name[whole[0]][0])
+    assert sorted(stacks) == shapes
 
 
 # -- the census of the older cells' programs -----------------------------------

@@ -212,13 +212,18 @@ def test_the_kernel_is_chosen_from_rows_shapes_and_backend(monkeypatch, rows,
     cfg = mla.MlaMoeConfig.tiny(dim=d, moe_ffn_dim=f, dtype=jnp.float32)
     lw = jax.tree_util.tree_map(
         lambda a: a[0], mla.mla_moe_init(jax.random.PRNGKey(0), cfg)["layers"])
-    calls = []
+    calls, sorted_calls = [], []
     monkeypatch.setattr(experts, "moe_experts", lambda x, *a, **k: calls.append(
         x.shape) or jnp.zeros_like(x))
+    # more rows sort their pairs, and on the chip take the sorted kernel
+    monkeypatch.setattr(experts, "moe_sorted", lambda xs, *a, **k: (
+        sorted_calls.append(xs.shape) or jnp.zeros_like(xs)))
     h = jax.random.normal(jax.random.PRNGKey(1), (1, rows, d))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     experts.moe_ffn_dropless(cfg, h, lw)
     assert calls == ([(rows, d)] if kernel else [])
+    assert sorted_calls == ([(rows * cfg.experts_per_token, d)]
+                            if rows > experts.DENSE_ROWS_MAX else [])
 
 
 def test_the_gradient_is_the_einsum_forms():
